@@ -382,16 +382,26 @@ class Scheduler:
         plan.decode = list(self.running)
         decode_need = len(plan.decode)
 
-        # 2. eviction under memory pressure (vLLM-style recompute preempt:
-        #    latest-arrival running requests are discarded to the wait queue).
-        while decode_need > self.gpu_free() + 0 and self.running:
-            victim = max(self.running, key=lambda r: (r.arrival_key, r.rid))
-            self.running.remove(victim)
-            plan.decode.remove(victim)
-            self._discard(victim, now)
+        # 2. eviction under memory pressure (vLLM-style recompute preempt):
+        #    the latest-arrival request holding device context is discarded
+        #    to the wait queue — a running one, or a waiting one holding a
+        #    partial chunked prefill. The partial prefills must be eligible:
+        #    sparing them lets a pool they fill starve the running request
+        #    forever (it is evicted, recomputes into the free remainder,
+        #    and is evicted again, every iteration).
+        while decode_need > self.gpu_free() and self.running:
+            holders = self.running + [r for r in self.waiting
+                                      if r.device_tokens > 0]
+            victim = max(holders, key=lambda r: (r.arrival_key, r.rid))
+            if victim in self.running:
+                self.running.remove(victim)
+                plan.decode.remove(victim)
+                self._discard(victim, now)
+                victim.phase = Phase.WAITING
+                self._insert_waiting(victim)
+            else:
+                self._discard(victim, now)
             victim.decision = ""
-            victim.phase = Phase.WAITING
-            self._insert_waiting(victim)
             self.stats.evictions += 1
             decode_need = len(plan.decode)
 

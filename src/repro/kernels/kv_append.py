@@ -49,14 +49,11 @@ def _append_kernel(page_ids, offsets, valid,            # scalar prefetch
         v_out[...] = v_pool_ref[...]
 
 
-# donation pairs with the pallas_call's input_output_aliases below: on
-# accelerators the pools are donated so the in-place alias never forces
-# a defensive copy; XLA-CPU cannot donate, hence the backend gate
-_DONATE_POOLS = () if jax.default_backend() == "cpu" else (0, 1)
-
-
+# donation pairs with the pallas_call's input_output_aliases below: the
+# pools are donated so the in-place alias never forces a defensive copy
+# (the caller must not touch the arrays it passed in again)
 @functools.partial(jax.jit, static_argnames=("interpret",),
-                   donate_argnums=_DONATE_POOLS)
+                   donate_argnums=(0, 1))
 def kv_append(k_pool, v_pool, k_new, v_new, page_ids, offsets, valid, *,
               interpret=None):
     """Scatter new K/V rows into their pool page slots.
@@ -67,7 +64,7 @@ def kv_append(k_pool, v_pool, k_new, v_new, page_ids, offsets, valid, *,
     have their K/V discarded, but their (page_ids[i], offsets[i]) is still
     the DMA target and MUST name a write-discard page no valid row of the
     same call writes (see module docstring). Returns the updated
-    (k_pool, v_pool); the inputs are aliased to the outputs.
+    (k_pool, v_pool); the inputs are donated and aliased to the outputs.
     """
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
@@ -100,4 +97,5 @@ def kv_append(k_pool, v_pool, k_new, v_new, page_ids, offsets, valid, *,
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)),
         input_output_aliases={3: 0, 4: 1},   # pools flow through in place
         interpret=interpret,
+        name="kv_append",
     )(page_ids, offsets, valid, k_pool, v_pool, k_new, v_new)

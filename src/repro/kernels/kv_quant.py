@@ -123,7 +123,7 @@ def _requant_kernel(rpids, k_pool_ref, v_pool_ref, k_ratio_ref, v_ratio_ref,
     del rpids
 
     def scale_page(pool_ref, ratio_ref, out_ref):
-        y = pool_ref[0].astype(jnp.float32) * ratio_ref[0][None, :, None]
+        y = pool_ref[0].astype(jnp.float32) * ratio_ref[0][None]
         if integer:
             y = jnp.rint(y)
         out_ref[0] = jnp.clip(y, -qmax, qmax).astype(out_ref.dtype)
@@ -132,16 +132,13 @@ def _requant_kernel(rpids, k_pool_ref, v_pool_ref, k_ratio_ref, v_ratio_ref,
     scale_page(v_pool_ref, v_ratio_ref, v_out)
 
 
-_DONATE_POOLS = () if jax.default_backend() == "cpu" else (0, 1)
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",),
-                   donate_argnums=_DONATE_POOLS)
+                   donate_argnums=(0, 1))
 def page_requant(k_pool, v_pool, k_ratio, v_ratio, rpids, *, interpret=None):
     """Rescale whole pages in place: page rpids[i] gets payload *=
     ratio[i] (re-rounded / re-cast). Rows routed to a write-discard page
     (duplicate occurrences, invalid rows) clobber only that page.
-    k_pool/v_pool: (n_pages, page, Hkv, hd) quantized;
+    k_pool/v_pool: (n_pages, page, Hkv, hd) quantized, donated;
     k_ratio/v_ratio: (N, Hkv) f32; rpids: (N,) int32."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
@@ -155,7 +152,7 @@ def page_requant(k_pool, v_pool, k_ratio, v_ratio, rpids, *, interpret=None):
 
     def row(n, ids):
         del ids
-        return (n, 0)
+        return (n, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -163,8 +160,9 @@ def page_requant(k_pool, v_pool, k_ratio, v_ratio, rpids, *, interpret=None):
         in_specs=[
             pl.BlockSpec((1, page, Hkv, hd), slot),   # k_pool (read-back)
             pl.BlockSpec((1, page, Hkv, hd), slot),   # v_pool (read-back)
-            pl.BlockSpec((1, Hkv), row),              # k_ratio
-            pl.BlockSpec((1, Hkv), row),              # v_ratio
+            # ratios as (N, Hkv, 1): one legal (Hkv, 1) block per row
+            pl.BlockSpec((1, Hkv, 1), row),           # k_ratio
+            pl.BlockSpec((1, Hkv, 1), row),           # v_ratio
         ],
         out_specs=[pl.BlockSpec((1, page, Hkv, hd), slot),
                    pl.BlockSpec((1, page, Hkv, hd), slot)],
@@ -176,7 +174,8 @@ def page_requant(k_pool, v_pool, k_ratio, v_ratio, rpids, *, interpret=None):
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)),
         input_output_aliases={1: 0, 2: 1},   # pools flow through in place
         interpret=interpret,
-    )(rpids, k_pool, v_pool, k_ratio, v_ratio)
+        name="page_requant",
+    )(rpids, k_pool, v_pool, k_ratio[..., None], v_ratio[..., None])
 
 
 def kv_append_quant(k_pool, v_pool, k_scale, v_scale, k_new, v_new,

@@ -1,90 +1,24 @@
-"""Paged-attention decode Pallas TPU kernel.
+"""Paged-attention decode: one new query token per sequence attends to its
+KV cache scattered across fixed-size pages of a global pool (vLLM-style
+PagedAttention, re-thought for TPU).
 
-One new query token per sequence attends to its KV cache scattered across
-fixed-size pages of a global pool (vLLM-style PagedAttention, re-thought for
-TPU): the block table is a *scalar-prefetch* operand, so the Pallas pipeline
-issues the HBM->VMEM DMA for page ``block_tables[b, i]`` while the MXU works
-on page i-1 — the TPU analogue of the paper's concern that scattered pages
-cost per-page kernel launches on GPU (here the indirection is folded into
-the standing pipeline instead).
+It is the ragged kernel's special case — token b is sequence b at position
+ctx_lens[b] - 1 — so both share one Pallas kernel
+(``ragged_paged_attention``): the block table is a scalar-prefetch operand,
+and the HBM->VMEM DMA for page ``block_tables[b, i]`` issues while page i-1
+is computed.
 
 Layout: q (B, Hkv, G, hd); pools (n_pages, page, Hkv, hd);
 block_tables (B, max_pages) int32; ctx_lens (B,) int32.
-Grid: (B, Hkv, max_pages), pages innermost; online softmax in VMEM scratch.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
-
-
-def _paged_kernel(block_tables, ctx_lens,          # scalar-prefetch operands
-                  q_ref, k_ref, v_ref, *rest,
-                  page: int, softcap, scale, window, quant: bool = False):
-    # quantized pools (DESIGN.md §17) carry one f32 scale per (page, kv
-    # head); its (1, 1) block rides the same scalar-prefetch indirection
-    # as the payload page, and K/V are dequantized in-register — the fp32
-    # pool never materializes
-    if quant:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-    b = pl.program_id(0)
-    i = pl.program_id(2)
-    n = pl.num_programs(2)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    ctx = ctx_lens[b]
-
-    live = i * page < ctx
-    if window is not None:
-        # the query sits at position ctx-1; pages entirely below the
-        # window's left edge contribute nothing — skip them
-        live = live & ((i + 1) * page > ctx - window)
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)               # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (page, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if quant:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + i * page
-        s = jnp.where(pos < ctx, s, NEG_INF)
-        if window is not None:
-            s = jnp.where(pos > ctx - 1 - window, s, NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(i == n - 1)
-    def _flush():
-        l = jnp.maximum(l_ref[...], 1e-37)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+from repro.kernels.ragged_paged_attention import ragged_paged_attention
 
 
 @functools.partial(jax.jit,
@@ -96,47 +30,11 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens, *,
     """q: (B, Hkv, G, hd); pools: (n_pages, page, Hkv, hd);
     block_tables: (B, max_pages); ctx_lens: (B,). ``window`` (static) keeps
     only the last ``window`` positions of each context (sliding-window
-    attention); rows with ctx_lens == 0 produce garbage (padding rows).
+    attention); rows with ctx_lens == 0 are padding (output zeros).
     ``k_scale``/``v_scale`` (n_pages, Hkv) f32 dequantize low-bit pools
     in-register (both set or both None). Returns (B, Hkv, G, hd)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    B, Hkv, G, hd = q.shape
-    n_pages, page, _, _ = k_pool.shape
-    max_pages = block_tables.shape[1]
-    scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    quant = k_scale is not None
-
-    kernel = functools.partial(_paged_kernel, page=page, softcap=softcap,
-                               scale=scale, window=window, quant=quant)
-    pool_spec = pl.BlockSpec((1, page, 1, hd),
-                             lambda b, h, i, bt, cl: (bt[b, i], 0, h, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, G, hd),
-                     lambda b, h, i, bt, cl: (b, h, 0, 0)),
-        pool_spec,
-        pool_spec,
-    ]
-    operands = [q, k_pool, v_pool]
-    if quant:
-        scale_spec = pl.BlockSpec((1, 1),
-                                  lambda b, h, i, bt, cl: (bt[b, i], h))
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hkv, max_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, h, i, bt, cl: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, hd), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(block_tables, ctx_lens, *operands)
+    B = q.shape[0]
+    return ragged_paged_attention(
+        q, k_pool, v_pool, block_tables, jnp.arange(B, dtype=jnp.int32),
+        ctx_lens.astype(jnp.int32) - 1, k_scale=k_scale, v_scale=v_scale,
+        softcap=softcap, scale=scale, window=window, interpret=interpret)

@@ -65,12 +65,9 @@ def _unpack_kernel(page_ids, pool_in_ref, staging_ref, pool_ref):
 
 
 # donation pairs with swap_unpack's input_output_aliases: the pool is
-# rewritten in place on accelerators; XLA-CPU cannot donate
-_DONATE_POOL = () if jax.default_backend() == "cpu" else (0,)
-
-
+# rewritten in place (the caller must not touch the array it passed in)
 @functools.partial(jax.jit, static_argnames=("interpret",),
-                   donate_argnums=_DONATE_POOL)
+                   donate_argnums=(0,))
 def swap_unpack(pool, staging, page_ids, *, interpret=None):
     """Scatter a staged buffer back into pool pages (returns updated pool).
 

@@ -26,8 +26,7 @@ from repro.configs import ARCH_REGISTRY, INPUT_SHAPES, get_config  # noqa: E402
 from repro.launch import roofline  # noqa: E402
 from repro.launch.input_specs import (cache_struct, input_specs,  # noqa: E402
                                       params_struct, window_override_for)
-from repro.launch.mesh import (make_production_mesh,  # noqa: E402
-                               set_mesh)
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.sharding import (batch_sharding, cache_shardings,  # noqa: E402
                                    param_shardings)
 from repro.launch.steps import (build_prefill_step, build_serve_step,  # noqa: E402
@@ -116,7 +115,7 @@ def lower_one(arch: str, shape_name: str, multi_pod: bool, *,
         args = (params_s, cache_s, specs["tokens"], specs["pos"])
         tokens_processed = shape.global_batch
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.time()
         lowered = jfn.lower(*args)
         t_lower = time.time() - t0

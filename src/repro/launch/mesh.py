@@ -9,17 +9,6 @@ from __future__ import annotations
 import jax
 
 
-def set_mesh(mesh):
-    """Context manager making ``mesh`` the ambient mesh for jit.
-
-    ``jax.set_mesh`` only exists on newer jax; on 0.4.x entering the Mesh
-    itself sets the global mesh, which is all these call sites need (their
-    shardings are explicit NamedShardings that carry the mesh anyway)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """TPU v5e-256 pod: (data=16, model=16); two pods add a leading
     'pod' axis (data-parallel across the DCN/ICI-linked pods)."""

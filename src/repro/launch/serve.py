@@ -14,13 +14,18 @@ Two clients share one engine, demonstrating the API/executor boundary:
     Python "tool", its measured wall-clock latency becoming the
     interception's virtual duration.
 
-CPU demo path: real model, paged KV, virtual clock. The full-scale sharded
-serve_step is exercised by launch.dryrun.
+Real model, paged KV, virtual clock; on a TPU the paged path runs the
+Pallas kernels, on the CPU their jnp mirrors. ``serve()`` is the part
+``chip_smoke.py`` reuses at full width on the chip.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import List
+
+import jax
 
 from repro.configs import get_config
 from repro.core import POLICIES
@@ -33,6 +38,7 @@ from repro.serving.engine import Engine
 from repro.serving.session import (InterceptEvent, SamplingParams,
                                    ScriptedClient)
 from repro.serving.workloads import make_workload
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def scale_to_budget(reqs, max_len: int, *, prompt_cap: int = 0,
@@ -61,6 +67,61 @@ def scale_to_budget(reqs, max_len: int, *, prompt_cap: int = 0,
         elif r.segments[-1].interception is not None:
             r.segments[-1].interception = None
     return reqs
+
+
+@dataclasses.dataclass
+class ServeResult:
+    handles: List[object]      # the scripted sessions
+    live: object               # the live caller-driven session
+    events: List[object]       # every event the run emitted
+    wall_s: float              # host wall clock of the poll loop
+
+    @property
+    def sessions(self) -> List[object]:
+        return self.handles + [self.live]
+
+
+def serve(eng: Engine, reqs, sampling: SamplingParams, *,
+          live_prompt_len: int = 32, live_new_tokens: int = 24,
+          stats_every: int = 0) -> ServeResult:
+    """Replay ``reqs`` through a ScriptedClient and run one live session
+    beside them, on ``eng``, until the engine drains. The live session
+    (``sampling``, a prompt of ``live_prompt_len`` ids) is intercepted by
+    a detector at its 8th generated token, and a WallClockToolExecutor
+    round-trips a Python "tool" whose ids resume it."""
+    scripted = ScriptedClient(eng, retain_events=True)
+    handles = scripted.submit(reqs)
+    client = scripted.client
+    vocab = eng.cfg.vocab_size
+
+    def detector(req, tid, now):
+        if req.output_tokens == 8 and req.seg_idx == 0:
+            return InterceptDirective(kind="tool", duration_hint=0.1,
+                                      reason="detector")
+        return None
+
+    def calculator(call):
+        return [(call.trigger_token_id or 0) % vocab, 7, 42]
+
+    live = client.submit(
+        [i % vocab for i in range(live_prompt_len)], sampling,
+        detector=detector, max_new_tokens=live_new_tokens,
+        tools=WallClockToolExecutor(calculator))
+
+    t0 = time.time()
+    if stats_every > 0:
+        # bounded poll slices with a periodic one-line stats update; the
+        # batch's drained flag says when the engine actually finished
+        events = []
+        while True:
+            batch = client.poll(stats_every)
+            events.extend(batch)
+            print(format_stats_line(eng))
+            if batch.drained:
+                break
+    else:
+        events = client.poll()
+    return ServeResult(handles, live, list(events), time.time() - t0)
 
 
 def main():
@@ -98,60 +159,33 @@ def main():
         make_workload(seed=0, n_requests=args.requests, rate_rps=args.rate,
                       max_ctx=args.max_len), args.max_len)
 
+    cache_dir, _ = enable_compile_cache()
     eng = Engine(cfg, POLICIES[args.policy], page_size=args.page_size,
                  n_pages=args.pages, max_model_len=args.max_len,
                  overlap=not args.no_overlap,
                  tracer=SpanTracer() if args.trace else None)
     if args.tool_workers > 0:
         eng.async_tools = AsyncToolRuntime(max_workers=args.tool_workers)
-    scripted = ScriptedClient(eng, retain_events=True)
-    handles = scripted.submit(reqs)
-    client = scripted.client
-
-    # one live session: the caller intercepts at the 8th generated token
-    # and a real Python tool supplies the returned ids
-    def detector(req, tid, now):
-        if req.output_tokens == 8 and req.seg_idx == 0:
-            return InterceptDirective(kind="tool", duration_hint=0.1,
-                                      reason="detector")
-        return None
-
-    def calculator(call):
-        return [(call.trigger_token_id or 0) % cfg.vocab_size, 7, 42]
-
-    live = client.submit(
-        list(range(32)),
-        SamplingParams(temperature=args.temperature, top_k=16,
-                       top_p=args.top_p, seed=1),
-        detector=detector, max_new_tokens=24,
-        tools=WallClockToolExecutor(calculator))
-
-    t0 = time.time()
-    if args.stats_every > 0:
-        # bounded poll slices with a periodic one-line stats update; the
-        # batch's drained flag says when the engine actually finished
-        events = []
-        while True:
-            batch = client.poll(args.stats_every)
-            events.extend(batch)
-            print(format_stats_line(eng))
-            if batch.drained:
-                break
-    else:
-        events = client.poll()
-    wall = time.time() - t0
-    finished = [h for h in handles + [live] if h.finished]
+    res = serve(eng, reqs,
+                SamplingParams(temperature=args.temperature, top_k=16,
+                               top_p=args.top_p, seed=1),
+                stats_every=args.stats_every)
+    live, events = res.live, res.events
+    finished = [h for h in res.sessions if h.finished]
     intercepts = sum(isinstance(e, InterceptEvent) for e in events)
-    print(f"policy={args.policy} finished={len(finished)}/{len(handles) + 1} "
-          f"events={len(events)} intercepts={intercepts} "
-          f"virtual_time={eng.now:.2f}s wall={wall:.1f}s")
+    print(f"device={jax.devices()[0].device_kind} "
+          f"compile_cache={cache_dir}")
+    print(f"policy={args.policy} finished={len(finished)}/"
+          f"{len(res.sessions)} events={len(events)} "
+          f"intercepts={intercepts} virtual_time={eng.now:.2f}s "
+          f"wall={res.wall_s:.1f}s")
     st = eng.sched.stats
     print(f"decode_tokens={st.decode_tokens} recompute={st.recompute_tokens} "
           f"fresh={st.fresh_tokens} swapped_out={st.swapped_out_tokens} "
           f"preserves={st.preserves} discards={st.discards}")
     print(format_summary(eng))
     print(f"live session: state={live.state} "
-          f"stream_len={len(client.token_ids(live))} "
+          f"stream_len={len(live.client.token_ids(live))} "
           f"out={live.request.output_tokens}tok "
           f"paused={live.request.paused_time * 1e3:.2f}ms")
     for h in finished[:4]:

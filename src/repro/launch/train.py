@@ -18,7 +18,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
-from repro.launch.mesh import set_mesh
 from repro.launch.sharding import param_shardings
 from repro.launch.steps import build_train_step
 from repro.training.checkpoint import save_checkpoint
@@ -57,7 +56,7 @@ def main():
         oshard = param_shardings(mesh, jax.eval_shape(lambda: opt),
                                  fsdp=True)
         dsh = NamedSharding(mesh, P("data"))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jstep = jax.jit(step_fn,
                             in_shardings=(pshard, oshard, dsh, dsh, dsh),
                             out_shardings=(pshard, oshard, None))

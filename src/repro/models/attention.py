@@ -31,6 +31,16 @@ def _paged_use_pallas() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _require_discard(discard_pid) -> None:
+    """The GQA paged paths append through kv_append, whose contract routes
+    invalid rows to a write-discard page; without one there is no correct
+    Pallas path, so a missing page is an error, never a quiet jnp
+    fallback."""
+    if discard_pid is None:
+        raise ValueError("paged GQA attention needs discard_pid, the "
+                         "caller's write-discard page")
+
+
 def effective_window(a: AttentionCfg, override) -> Optional[int]:
     """Resolve a window override against the block's configured window.
 
@@ -132,11 +142,14 @@ def blocked_attention(q, k, v, q_positions, kv_positions, *, causal=True,
 def init_gqa(key, d_model: int, a: AttentionCfg, dtype):
     ks = jax.random.split(key, 4)
     p = {
-        "wq": dense_init(ks[0], (d_model, a.n_heads, a.head_dim), dtype=dtype),
-        "wk": dense_init(ks[1], (d_model, a.n_kv_heads, a.head_dim), dtype=dtype),
-        "wv": dense_init(ks[2], (d_model, a.n_kv_heads, a.head_dim), dtype=dtype),
-        "wo": dense_init(ks[3], (a.n_heads, a.head_dim, d_model), in_axis=0,
+        "wq": dense_init(ks[0], (d_model, a.n_heads, a.head_dim), in_axis=0,
                          dtype=dtype),
+        "wk": dense_init(ks[1], (d_model, a.n_kv_heads, a.head_dim),
+                         in_axis=0, dtype=dtype),
+        "wv": dense_init(ks[2], (d_model, a.n_kv_heads, a.head_dim),
+                         in_axis=0, dtype=dtype),
+        "wo": dense_init(ks[3], (a.n_heads, a.head_dim, d_model),
+                         in_axis=(0, 1), dtype=dtype),
     }
     if a.qkv_bias:
         p["bq"] = jnp.zeros((a.n_heads, a.head_dim), dtype)
@@ -289,16 +302,17 @@ def init_mla(key, d_model: int, a: AttentionCfg, dtype):
     return {
         "w_dq": dense_init(ks[0], (d_model, a.q_lora_rank), dtype=dtype),
         "q_norm": jnp.zeros((a.q_lora_rank,), dtype),
-        "w_uq": dense_init(ks[1], (a.q_lora_rank, a.n_heads, qk), dtype=dtype),
+        "w_uq": dense_init(ks[1], (a.q_lora_rank, a.n_heads, qk), in_axis=0,
+                           dtype=dtype),
         "w_dkv": dense_init(ks[2], (d_model, a.kv_lora_rank + a.qk_rope_head_dim),
                             dtype=dtype),
         "kv_norm": jnp.zeros((a.kv_lora_rank,), dtype),
         "w_uk": dense_init(ks[3], (a.kv_lora_rank, a.n_heads, a.qk_nope_head_dim),
-                           dtype=dtype),
+                           in_axis=0, dtype=dtype),
         "w_uv": dense_init(ks[4], (a.kv_lora_rank, a.n_heads, a.v_head_dim),
-                           dtype=dtype),
-        "wo": dense_init(ks[5], (a.n_heads, a.v_head_dim, d_model), in_axis=0,
-                         dtype=dtype),
+                           in_axis=0, dtype=dtype),
+        "wo": dense_init(ks[5], (a.n_heads, a.v_head_dim, d_model),
+                         in_axis=(0, 1), dtype=dtype),
     }
 
 
@@ -402,10 +416,9 @@ def gqa_decode_paged(p, a: AttentionCfg, x, pool, block_tables, ctx_lens, *,
     batch; block_tables: (B, max_pages) int32 page ids; ctx_lens: (B,)
     int32 context length INCLUDING the new token. ctx_lens == 0 marks a
     padded row: its K/V write is dropped and its output is garbage.
-    ``discard_pid`` is the caller's write-discard page (the engine's
-    scratch page) — invalid rows' appends are routed there on the Pallas
-    path, which the kv_append kernel contract requires; when None the
-    scatter falls back to the drop-mode XLA path on every backend.
+    ``discard_pid`` (required) is the caller's write-discard page (the
+    engine's scratch page) — invalid rows' appends are routed there on the
+    Pallas path, which the kv_append kernel contract requires.
     Returns (out (B, d), updated pool).
     """
     from repro.kernels.ops import kv_append_op, paged_attention_op
@@ -428,7 +441,8 @@ def gqa_decode_paged(p, a: AttentionCfg, x, pool, block_tables, ctx_lens, *,
     offs = pos % page
     G = a.n_heads // Hkv
     quant = "k_scale" in pool            # quantized pool (DESIGN.md §17)
-    use_pallas = _paged_use_pallas() and discard_pid is not None
+    use_pallas = _paged_use_pallas()
+    _require_discard(discard_pid)
     if quant:
         from repro.kernels.ops import kv_append_quant_op
         k_pool, v_pool, k_scale, v_scale = kv_append_quant_op(
@@ -504,7 +518,8 @@ def gqa_extend_paged(p, a: AttentionCfg, x, pool, block_tables, start,
     pids = jnp.take_along_axis(block_tables, positions // page, axis=1)
     offs = positions % page
     quant = "k_scale" in pool            # quantized pool (DESIGN.md §17)
-    use_pallas = _paged_use_pallas() and discard_pid is not None
+    use_pallas = _paged_use_pallas()
+    _require_discard(discard_pid)
     if quant:
         from repro.kernels.ops import kv_append_quant_op
         from repro.kernels.ref import dequant_gathered
@@ -688,7 +703,8 @@ def gqa_mixed_paged(p, a: AttentionCfg, x, pool, block_tables, tok_seq,
     offs = pos % page
     G = a.n_heads // Hkv
     quant = "k_scale" in pool            # quantized pool (DESIGN.md §17)
-    use_pallas = _paged_use_pallas() and discard_pid is not None
+    use_pallas = _paged_use_pallas()
+    _require_discard(discard_pid)
     if quant:
         from repro.kernels.ops import kv_append_quant_op
         k_pool, v_pool, k_scale, v_scale = kv_append_quant_op(

@@ -12,8 +12,12 @@ import jax.numpy as jnp
 # --------------------------------------------------------------------------
 
 def dense_init(key, shape, in_axis=-2, dtype=jnp.float32):
-    """Truncated-normal fan-in init (lecun-style)."""
-    fan_in = shape[in_axis] if len(shape) > 1 else shape[0]
+    """Truncated-normal fan-in init (lecun-style). ``in_axis`` names the
+    contracted input axis, or a tuple of them (e.g. the (heads, head_dim)
+    axes of an attention output projection)."""
+    axes = in_axis if isinstance(in_axis, tuple) else (in_axis,)
+    fan_in = math.prod(shape[a] for a in axes) if len(shape) > 1 \
+        else shape[0]
     std = 1.0 / math.sqrt(max(1, fan_in))
     return (std * jax.random.truncated_normal(key, -2.0, 2.0, shape)).astype(dtype)
 

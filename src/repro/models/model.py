@@ -319,9 +319,9 @@ class LM:
         ctx_lens: (B,) int32 context length INCLUDING the new token (0
         marks a padding row — nothing is written, logits are garbage);
         pools: the pytree from init_cache(n_pages, page_size);
-        block_tables: (B, max_pages) int32; discard_pid names the caller's
-        write-discard page for masked appends on the Pallas path (None
-        falls back to drop-mode XLA scatters everywhere).
+        block_tables: (B, max_pages) int32; discard_pid (required for GQA
+        blocks) names the caller's write-discard page for masked appends
+        on the Pallas path.
         Returns (logits, new_pools).
         """
         cfg = self.cfg

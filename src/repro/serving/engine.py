@@ -29,7 +29,7 @@ model with paged KV storage:
                     computed instead of round-tripping the whole table.
   * swap_out/in   — page-granular HBM<->host movement staged through ONE
                     contiguous slab per request (the §4.1 coalesced
-                    transfer), numpy backing on this CPU demo path;
+                    transfer) into a host numpy slab;
                     with overlap=True (default) the slab DMA is issued
                     alongside the model dispatch through a double-buffered
                     SwapStager and reconciled at commit, so the transfer
@@ -72,9 +72,10 @@ and the overlap accounting differ. Caller-side ToolExecutors can run
 off-thread through an ``AsyncToolRuntime`` whose completions are injected
 at the next plan phase via the same resume queue the caller uses.
 
-Time is virtual (the same cost model as the simulator) so interception
-durations and swap budgets are exact and runs are reproducible; tensor math
-is real. On TPU the paged path runs the Pallas paged-attention / kv_append
+Time is virtual (the same cost model as the simulator, priced with the
+peaks of the device the engine runs on — ``utils.hw.chip_of``) so
+interception durations and swap budgets are exact and runs are
+reproducible; tensor math is real. On TPU the paged path runs the Pallas paged-attention / kv_append
 kernels (repro.kernels); on CPU it runs a jnp mirror of the contiguous
 math, so paged and gather execution produce bit-identical greedy streams —
 the differential property tests/test_paged_engine.py pins down. The
@@ -122,7 +123,7 @@ from repro.serving.api_executor import (AsyncToolRuntime,
                                         prompt_token_ids)
 from repro.serving.session import (CancelledEvent, FailedEvent, FinishEvent,
                                    InterceptEvent, RejectedEvent, TokenEvent)
-from repro.utils.hw import TPU_V5E
+from repro.utils.hw import chip_of
 
 
 @dataclasses.dataclass
@@ -275,8 +276,8 @@ class Engine:
             from repro.analysis.ownership import KVSanitizer
             self.sanitizer = KVSanitizer(self)
             self._lifecycle_checker = LifecycleChecker()
-        self.cost = CostModel(cfg=cfg, chip=TPU_V5E, n_chips=1,
-                              kv_dtype=kv_dtype)
+        self.cost = CostModel(cfg=cfg, chip=chip_of(jax.devices()[0]),
+                              n_chips=1, kv_dtype=kv_dtype)
         cap = max(page_size, (n_pages - 8) * page_size)
         # telemetry (DESIGN.md §13): one registry spans engine + scheduler
         # + ledger; the tracer defaults to the allocation-free NullTracer
@@ -444,8 +445,8 @@ class Engine:
                         jax.tree.leaves(self.pools[gi][f"b{j}"])))
                         // page_slots)
         # jitted entry points (stable shapes via bucketing); pools are
-        # donated on accelerators so the paged update is truly in place
-        donate = () if jax.default_backend() == "cpu" else (3,)
+        # donated so the paged update is truly in place — every caller
+        # rebinds self.pools to the result and never reads the old tree
         self._decode_jit = jax.jit(
             lambda p, t, pos, c: self.model.decode_step(p, t, pos, c))
         self._extend_jit = jax.jit(
@@ -456,12 +457,12 @@ class Engine:
         self._decode_paged_jit = jax.jit(
             lambda p, t, cl, pools, bt: self.model.decode_step_paged(
                 p, t, cl, pools, bt, discard_pid=self.scratch_page),
-            donate_argnums=donate)
+            donate_argnums=(3,))
         self._extend_paged_jit = jax.jit(
             lambda p, t, s, nn, pools, bt, li: self.model.extend_step_paged(
                 p, t, s, nn, pools, bt, logits_index=li,
                 discard_pid=self.scratch_page),
-            donate_argnums=(4,) if donate else ())
+            donate_argnums=(4,))
         # the whole mixed iteration — every chunk, every decode, and
         # sampling (greedy or per-request SamplingParams) — in one
         # dispatch (DESIGN.md §10/§11)
@@ -470,7 +471,7 @@ class Engine:
                 self.model.forward_mixed_paged(
                     p, t, ts, tp, ql, pools, bt, samp,
                     discard_pid=self.scratch_page),
-            donate_argnums=(5,) if donate else ())
+            donate_argnums=(5,))
 
     @staticmethod
     def _bucket(n: int) -> int:

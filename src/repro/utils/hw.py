@@ -1,10 +1,13 @@
 """Hardware constants for the TPU v5e target and roofline helpers.
 
-The container is CPU-only; these constants parameterize
+These constants parameterize
   * the roofline analysis over the compiled dry-run artifacts, and
   * the analytic T_fwd / T_swap cost model that the InferCept scheduler and
     the discrete-event simulator share (the paper obtains the same mappings
     by offline profiling on A100).
+
+``chip_of(device)`` maps a JAX device to its entry by ``device_kind``, the
+name JAX reports; a TPU kind missing from ``DEVICE_KINDS`` is an error.
 """
 from __future__ import annotations
 
@@ -45,6 +48,24 @@ A100 = ChipSpec(
 )
 
 CHIPS = {c.name: c for c in (TPU_V5E, A100)}
+
+# JAX ``device_kind`` -> published peaks (Google Cloud docs, "TPU v5e")
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
+
+def chip_of(device) -> ChipSpec:
+    """The peaks of a JAX device, looked up by its ``device_kind``. Off
+    the TPU (CPU tests and demos) the v5e the cost model targets stands
+    in, so the virtual clock means the same thing on both."""
+    if device.platform != "tpu":
+        return TPU_V5E
+    try:
+        return DEVICE_KINDS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks for device_kind {device.device_kind!r} "
+            f"(platform {device.platform}); known: "
+            f"{sorted(DEVICE_KINDS)}") from None
 
 
 def dtype_bytes(dtype: str) -> int:

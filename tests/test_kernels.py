@@ -245,7 +245,8 @@ def test_kv_append_matches_ref(dtype, N):
     ids = jnp.asarray(slots // page, jnp.int32)
     offs = jnp.asarray(slots % page, jnp.int32)
     valid = jnp.asarray(rng.integers(0, 2, N), jnp.int32)
-    got_k, got_v = kv_append(kp, vp, kn, vn, ids, offs, valid,
+    # the kernel donates its pools: hand it copies, keep the originals
+    got_k, got_v = kv_append(kp.copy(), vp.copy(), kn, vn, ids, offs, valid,
                              interpret=True)
     want_k, want_v = ref.kv_append_ref(kp, vp, kn, vn, ids, offs, valid)
     assert jnp.array_equal(got_k, want_k) and jnp.array_equal(got_v, want_v)
@@ -262,7 +263,7 @@ def test_kv_append_invalid_rows_leave_pool_untouched():
     ids = jnp.asarray([2, 2, 2, 5, 0], jnp.int32)
     offs = jnp.asarray([1, 1, 3, 0, 0], jnp.int32)
     valid = jnp.zeros(5, jnp.int32)
-    got_k, got_v = kv_append(kp, vp, kn, vn, ids, offs, valid,
+    got_k, got_v = kv_append(kp.copy(), vp.copy(), kn, vn, ids, offs, valid,
                              interpret=True)
     assert jnp.array_equal(got_k, kp) and jnp.array_equal(got_v, vp)
 
@@ -276,8 +277,10 @@ def test_swap_pack_unpack_roundtrip(dtype, n_move):
     ids = jnp.asarray(rng.choice(32, n_move, replace=False), jnp.int32)
     staged = swap_pack(pool, ids, interpret=True)
     assert jnp.array_equal(staged, ref.swap_pack_ref(pool, ids))
-    # overwrite, then restore: exact roundtrip
-    zeroed = swap_unpack(pool, jnp.zeros_like(staged), ids, interpret=True)
+    # overwrite, then restore: exact roundtrip (swap_unpack donates the
+    # pool it is given, so it gets a copy)
+    zeroed = swap_unpack(pool.copy(), jnp.zeros_like(staged), ids,
+                         interpret=True)
     assert jnp.array_equal(zeroed, ref.swap_unpack_ref(
         pool, jnp.zeros_like(staged), ids))
     restored = swap_unpack(zeroed, staged, ids, interpret=True)
@@ -300,7 +303,7 @@ def test_swap_roundtrip_quantized_slab(kv_dtype):
     for pool in (payload, scales):
         staged = swap_pack(pool, ids, interpret=True)
         assert jnp.array_equal(staged, pool[ids])
-        clobbered = swap_unpack(pool, jnp.zeros_like(staged), ids,
+        clobbered = swap_unpack(pool.copy(), jnp.zeros_like(staged), ids,
                                 interpret=True)
         restored = swap_unpack(clobbered, staged, ids, interpret=True)
         assert jnp.array_equal(restored, pool)
@@ -367,8 +370,8 @@ if HAVE_HYPOTHESIS:
         for pool in leaves:
             staged = swap_pack(pool, ids, interpret=True)
             assert _bits_equal(staged, pool[ids])
-            clobbered = swap_unpack(pool, jnp.zeros_like(staged), ids,
-                                    interpret=True)
+            clobbered = swap_unpack(pool.copy(), jnp.zeros_like(staged),
+                                    ids, interpret=True)
             assert _bits_equal(clobbered[untouched], pool[untouched])
             assert _bits_equal(clobbered[ids], jnp.zeros_like(staged))
             restored = swap_unpack(clobbered, staged, ids, interpret=True)

@@ -65,7 +65,6 @@ from repro.configs import get_config
 from repro.launch.sharding import param_shardings, cache_shardings
 from repro.launch.steps import build_serve_step
 from repro.launch.input_specs import params_struct
-from repro.launch.mesh import set_mesh
 from repro.models import LM
 mesh = jax.make_mesh((2, 4), ("data", "model"))
 cfg = get_config("llama3.2-1b", tiny=True)
@@ -77,7 +76,7 @@ cshard = cache_shardings(mesh, cfg, cache_s)
 toks = jax.ShapeDtypeStruct((8,), jax.numpy.int32)
 pos = jax.ShapeDtypeStruct((8,), jax.numpy.int32)
 tshard = NamedSharding(mesh, P("data"))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     compiled = jax.jit(fn, in_shardings=(pshard, cshard, tshard, tshard),
                        out_shardings=(None, None, cshard)).lower(
         params_s, cache_s, toks, pos).compile()
@@ -95,3 +94,35 @@ print(json.dumps({"flops": float(cost.get("flops", 0))}))
     assert out.returncode == 0, out.stderr[-2000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["flops"] > 0
+
+
+def test_compile_cache_dir_env_wins_else_fixed_in_checkout(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache goes to the fixed <repo>/.jax_cache (never a temp or pid path)."""
+    from repro.utils import compile_cache as cc
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jc")
+        path, _ = cc.enable_compile_cache()
+        assert path == "/elsewhere/jc"
+        assert jax.config.jax_compilation_cache_dir == saved[keys[0]]
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path, stats = cc.enable_compile_cache()
+        assert path == str(cc.REPO_ROOT / ".jax_cache")
+        assert (cc.REPO_ROOT / "chip_smoke.py").exists()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert (stats.hits, stats.misses) == (0, 0)
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+
+
+def test_chip_of_keys_tpus_by_device_kind():
+    from types import SimpleNamespace as Dev
+    from repro.utils.hw import TPU_V5E, chip_of
+    assert chip_of(Dev(platform="tpu", device_kind="TPU v5 lite")) is TPU_V5E
+    assert chip_of(Dev(platform="cpu", device_kind="cpu")) is TPU_V5E
+    with pytest.raises(ValueError, match="no peaks for device_kind"):
+        chip_of(Dev(platform="tpu", device_kind="TPU v9 imaginary"))

@@ -411,7 +411,8 @@ def test_paged_decode_pad_rows_never_touch_pages():
     cl = jnp.asarray([9, 0, 0, 1], jnp.int32)
     toks = jnp.asarray([5, 6, 7, 8], jnp.int32)
     _, new_pools = m.decode_step_paged(params, toks, cl, pools,
-                                       jnp.asarray(bt, jnp.int32))
+                                       jnp.asarray(bt, jnp.int32),
+                                       discard_pid=n_pages - 1)
     live = np.zeros((n_pages, page), bool)
     live[4, 0] = live[7, 0] = True
     for leaf in jax.tree.leaves(new_pools):
@@ -465,7 +466,7 @@ def test_mixed_pad_rows_never_touch_pages():
     qlast = jnp.asarray([2, 3], jnp.int32)
     _, _, new_pools = m.forward_mixed_paged(
         params, toks, tseq, tpos, qlast, pools,
-        jnp.asarray(bt, jnp.int32))
+        jnp.asarray(bt, jnp.int32), discard_pid=n_pages - 1)
     live = np.zeros((n_pages, page), bool)
     live[3, 5:] = True          # positions 5..7 of seq 0 (all in page 3)
     live[7, 1] = True           # position 1 of seq 1

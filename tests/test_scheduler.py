@@ -310,3 +310,24 @@ def test_eviction_under_memory_pressure():
     # both decoding toward 150-token cap forces an eviction of the later one
     assert sched.stats.evictions >= 1
     assert sched.gpu_used() <= 150
+
+
+def test_eviction_reclaims_partial_prefills_before_the_running_request():
+    """A later-arrival waiting request holding a partial chunked prefill is
+    evicted before the running request: sparing it once starved the
+    running request forever (evicted, recomputed into the remainder of the
+    pool, evicted again — every iteration)."""
+    sched = Scheduler(POLICIES["infercept"], _cost(),
+                      gpu_capacity_tokens=120)
+    run = _req(1, prompt=60, arrival=0.0)
+    partial = _req(2, prompt=90, arrival=1.0)
+    sched.submit(run)
+    sched.submit(partial)
+    sched.waiting.remove(run)
+    run.device_tokens, run.phase = 60, Phase.RUNNING
+    sched.running.append(run)
+    partial.device_tokens = 60             # half its prompt, pool now full
+    plan = sched.next_iteration(2.0)
+    assert plan.decode == [run]
+    assert partial.device_tokens == 0 and partial in sched.waiting
+    assert sched.stats.evictions == 1
