@@ -17,7 +17,10 @@ One process, in this order:
 5. the engine through the session API (``repro.launch.serve.serve``):
    scripted intercept sessions plus one live tool session, InferCept
    policy, bf16 weights and KV, a KV pool pinched against the workload so
-   that the policy swaps and discards.
+   that the policy swaps and discards;
+6. a second engine, built after the first is freed, whose pool holds
+   4 GiB of KV — the size a deployment of this model would keep on one
+   chip — serving a short burst; prints the device memory in use.
 
 Weights are random, made from ``--seed``. Every phase raises on a failed
 check, so the script exits 0 only when all of them passed; its last line
@@ -27,6 +30,7 @@ way (seconds, tokens) are bring-up facts, not benchmark results.
 from __future__ import annotations
 
 import argparse
+import gc
 import json
 import sys
 import time
@@ -70,6 +74,10 @@ N_SESSIONS = 16
 BURST_RPS = 1000.0         # sessions arrive together: their contexts
                            # contend for the pinched pool at once
 PROMPT_RANGE = (512, 2048)
+# the deployment-sized pool: 16 layers x K,V x 16 slots x 8 heads x 64 x
+# 2 B = 512 KiB a page, so 8192 pages hold 4 GiB beside ~2.5 GB of weights
+BIG_N_PAGES = 8192         # 131072 token slots
+BIG_SESSIONS = 8
 
 
 class SmokeFailure(AssertionError):
@@ -181,8 +189,9 @@ def smoke_workload(seed: int, n: int, max_len: int, prompt_range):
 
 
 def check_serve(eng: Engine, seed: int, *, n_sessions=N_SESSIONS,
-                prompt_range=PROMPT_RANGE):
-    """Phase 5: the engine through the session API."""
+                prompt_range=PROMPT_RANGE, pinched=True):
+    """Phases 5 and 6: the engine through the session API. Only a pinched
+    pool must make the policy swap out and back in."""
     reqs = smoke_workload(seed, n_sessions, eng.max_pages * eng.page,
                           prompt_range)
     res = serve(eng, reqs, SamplingParams(),
@@ -206,8 +215,38 @@ def check_serve(eng: Engine, seed: int, *, n_sessions=N_SESSIONS,
     check(all(s == "finished" for s in states),
           f"sessions not all finished: {states}")
     check(intercepts > 0, "no interception fired")
-    check(st.swapped_out_tokens > 0, "nothing was swapped out")
-    check(st.swapped_in_tokens > 0, "nothing was swapped in")
+    if pinched:
+        check(st.swapped_out_tokens > 0, "nothing was swapped out")
+        check(st.swapped_in_tokens > 0, "nothing was swapped in")
+
+
+def tree_bytes(tree) -> int:
+    return sum(int(leaf.nbytes) for leaf in jax.tree.leaves(tree))
+
+
+def memory_line(dev, what: str) -> dict:
+    """Print the device's memory counters (``Device.memory_stats``)."""
+    ms = dev.memory_stats() or {}
+    print(f"memory {what}: " + (" ".join(
+        f"{k}={ms[k]}" for k in ("bytes_in_use", "peak_bytes_in_use",
+                                 "bytes_limit") if k in ms)
+        or "not reported"))
+    return ms
+
+
+def check_big_pool(eng: Engine, dev, seed: int):
+    """Phase 6: serve a burst on an engine with a deployment-sized pool;
+    the pool and the weights must be resident on the device."""
+    weights, pool = tree_bytes(eng.params), tree_bytes(eng.pools)
+    jax.block_until_ready((eng.params, eng.pools))
+    print(f"big pool: pages={eng.blocks.n_pages} pool_bytes={pool} "
+          f"weight_bytes={weights}")
+    ms = memory_line(dev, "after engine init")
+    if "bytes_in_use" in ms:
+        check(ms["bytes_in_use"] >= pool + weights,
+              f"{ms['bytes_in_use']} B in use, below pool + weights")
+    check_serve(eng, seed, n_sessions=BIG_SESSIONS, pinched=False)
+    memory_line(dev, "after serve")
 
 
 def main(argv=None) -> int:
@@ -245,6 +284,15 @@ def main(argv=None) -> int:
     phase("model", check_model, eng, args.seed)
     phase("serve", check_serve, eng, args.seed)
     eng.close()
+    del eng                 # free its weights and pool before the next one
+    gc.collect()
+    memory_line(dev, "after the pinched engine is freed")
+    big = phase("big_pool_init", Engine, cfg, POLICIES["infercept"],
+                page_size=PAGE, n_pages=BIG_N_PAGES,
+                max_model_len=MAX_MODEL_LEN, seed=args.seed,
+                dtype=jnp.bfloat16)
+    phase("big_pool_serve", check_big_pool, big, dev, args.seed)
+    big.close()
     print(f"compile total: compile_s={compiles.compile_s:.3f} "
           f"cache_hits={compiles.hits} cache_misses={compiles.misses}")
     print(json.dumps({"ok": True, "device": {

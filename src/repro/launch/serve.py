@@ -82,8 +82,7 @@ class ServeResult:
 
 
 def serve(eng: Engine, reqs, sampling: SamplingParams, *,
-          live_prompt_len: int = 32, live_new_tokens: int = 24,
-          stats_every: int = 0) -> ServeResult:
+          live_prompt_len: int = 32, stats_every: int = 0) -> ServeResult:
     """Replay ``reqs`` through a ScriptedClient and run one live session
     beside them, on ``eng``, until the engine drains. The live session
     (``sampling``, a prompt of ``live_prompt_len`` ids) is intercepted by
@@ -105,7 +104,7 @@ def serve(eng: Engine, reqs, sampling: SamplingParams, *,
 
     live = client.submit(
         [i % vocab for i in range(live_prompt_len)], sampling,
-        detector=detector, max_new_tokens=live_new_tokens,
+        detector=detector, max_new_tokens=24,
         tools=WallClockToolExecutor(calculator))
 
     t0 = time.time()

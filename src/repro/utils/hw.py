@@ -7,7 +7,7 @@ These constants parameterize
     by offline profiling on A100).
 
 ``chip_of(device)`` maps a JAX device to its entry by ``device_kind``, the
-name JAX reports; a TPU kind missing from ``DEVICE_KINDS`` is an error.
+name JAX reports; a non-CPU kind missing from ``DEVICE_KINDS`` is an error.
 """
 from __future__ import annotations
 
@@ -54,10 +54,11 @@ DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
 
 
 def chip_of(device) -> ChipSpec:
-    """The peaks of a JAX device, looked up by its ``device_kind``. Off
-    the TPU (CPU tests and demos) the v5e the cost model targets stands
-    in, so the virtual clock means the same thing on both."""
-    if device.platform != "tpu":
+    """The peaks of a JAX device, looked up by its ``device_kind``. On the
+    CPU (tests and demos) the v5e the cost model targets stands in, so
+    the virtual clock means the same thing on both; any other device must
+    have an entry."""
+    if device.platform == "cpu":
         return TPU_V5E
     try:
         return DEVICE_KINDS[device.device_kind]

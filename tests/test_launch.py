@@ -126,3 +126,5 @@ def test_chip_of_keys_tpus_by_device_kind():
     assert chip_of(Dev(platform="cpu", device_kind="cpu")) is TPU_V5E
     with pytest.raises(ValueError, match="no peaks for device_kind"):
         chip_of(Dev(platform="tpu", device_kind="TPU v9 imaginary"))
+    with pytest.raises(ValueError, match="no peaks for device_kind"):
+        chip_of(Dev(platform="gpu", device_kind="NVIDIA A100-SXM4-80GB"))
