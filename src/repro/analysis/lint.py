@@ -40,6 +40,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 from repro.obs.metrics import (
     ENGINE_COUNTER_SCHEMA,
     EXTRA_COUNTER_SCHEMA,
+    HOST_COUNTER_SCHEMA,
     SCHED_COUNTER_SCHEMA,
     WASTE_CAUSE_SCHEMA,
 )
@@ -65,8 +66,10 @@ COUNTER_KEYS = (
     set(ENGINE_COUNTER_SCHEMA)
     | set(SCHED_COUNTER_SCHEMA)
     | set(EXTRA_COUNTER_SCHEMA)
+    | set(HOST_COUNTER_SCHEMA)
     | {f"engine_{k}" for k in ENGINE_COUNTER_SCHEMA}
     | {f"sched_{k}" for k in SCHED_COUNTER_SCHEMA}
+    | {f"host_{k}" for k in HOST_COUNTER_SCHEMA}
 )
 CAUSE_KEYS = set(WASTE_CAUSE_SCHEMA)
 

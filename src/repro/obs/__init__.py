@@ -1,12 +1,20 @@
 """Waste-attribution telemetry (DESIGN.md §13).
 
-Four pieces, threaded through the serving stack:
+Five pieces, threaded through the serving stack:
 
   * ``MetricsRegistry`` (metrics.py) — counters / gauges / fixed-bucket
-    virtual-time histograms. The engine's ad-hoc ``counters`` dict and the
+    histograms in seconds (latencies on the host clock, estimator errors
+    on the virtual one). The engine's ad-hoc ``counters`` dict and the
     scheduler's ``SchedulerStats`` are thin compatibility views over one
     shared registry, so every legacy read keeps working while a single
     ``to_prometheus()`` dump exposes the whole stack.
+  * ``HostClock`` (trace.py) — the host's own clock inside
+    ``Engine.step``, always on: phase spans (``engine.plan`` /
+    ``dispatch`` / ``swap_*`` / ``sync`` / ``commit``) that land in a
+    ``jax.profiler`` trace beside the device ops and add their self time
+    to ``host_``-prefixed counters, plus host-clock queue waits and
+    session latencies. It is the only clock read of the serving path,
+    and no decision reads it.
   * ``SpanTracer`` / ``NullTracer`` (trace.py) — per-request virtual-clock
     lifecycle spans (queued, prefill chunk, decode, swap, swapped-wait)
     plus engine-track pipeline/DMA/idle spans and tool-call async spans.
@@ -38,9 +46,11 @@ _EXPORTS = {
     "ENGINE_COUNTER_SCHEMA": "repro.obs.metrics",
     "SCHED_COUNTER_SCHEMA": "repro.obs.metrics",
     "EXTRA_COUNTER_SCHEMA": "repro.obs.metrics",
+    "HOST_COUNTER_SCHEMA": "repro.obs.metrics",
     "WASTE_CAUSE_SCHEMA": "repro.obs.metrics",
     "SpanTracer": "repro.obs.trace",
     "NullTracer": "repro.obs.trace",
+    "HostClock": "repro.obs.trace",
     "WasteLedger": "repro.obs.ledger",
     "InterceptRecord": "repro.obs.ledger",
     "WASTE_CAUSES": "repro.obs.ledger",

@@ -10,11 +10,16 @@ views over it:
     and dict semantics — nothing is copied, nothing is rounded.
   * ``SchedulerStats`` (core/scheduler.py) routes its attributes to
     ``sched_``-prefixed registry counters the same way.
+  * ``HostClock`` (obs/trace.py) keeps the host's own time inside
+    ``Engine.step`` under a ``host_`` prefix: never in the ``engine_``
+    view, and read by no scheduling decision.
 
 Histograms use FIXED bucket edges declared up front (Prometheus-style
 cumulative ``le`` semantics) so merging/diffing dumps across runs is
 well-defined; all time-valued observations share ``DEFAULT_TIME_EDGES``
-(virtual seconds, log-spaced sub-ms .. minutes).
+(seconds, log-spaced sub-ms .. minutes). The latency histograms — queue
+and swapped waits, session TTFT and token gaps — are taken on the host
+clock; the estimator's error histograms are on the virtual clock.
 """
 from __future__ import annotations
 
@@ -22,8 +27,8 @@ import bisect
 from collections.abc import MutableMapping
 from typing import Dict, Iterable, Optional, Tuple
 
-# virtual-second buckets: sub-millisecond decode iterations up to
-# minute-long tool pauses / queue waits
+# second buckets: sub-millisecond decode iterations up to minute-long
+# tool pauses / queue waits
 DEFAULT_TIME_EDGES: Tuple[float, ...] = (
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
     1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
@@ -55,6 +60,21 @@ ENGINE_COUNTER_SCHEMA: Dict[str, float] = {
     # at free time (scale lifetime == page lifetime) and shared pages
     # whose scales were copied by a COW fork alongside the payload
     "kv_quant_scale_reset_pages": 0, "kv_quant_scale_cow_pages": 0,
+    # the virtual seconds the cost model charges each iteration
+    # (t_model + stall): the scheduler's priced step, deterministic
+    "cost_step_s": 0.0,
+}
+
+# the host clock inside Engine.step (obs.trace.HostClock), "host_"
+# prefix: each phase span's self time in seconds, the inclusive step time
+# and the count of steps whose plan was not empty, and the host-clock
+# wait of each turn from where it entered the engine (or was put back in
+# the queue) to where its first model work was issued
+HOST_COUNTER_SCHEMA: Dict[str, float] = {
+    "step_s": 0.0, "steps": 0,
+    "plan_s": 0.0, "dispatch_s": 0.0, "swap_s": 0.0, "sync_s": 0.0,
+    "commit_s": 0.0,
+    "queue_wait_s": 0.0, "queue_waits": 0,
 }
 
 SCHED_COUNTER_SCHEMA: Tuple[str, ...] = (
@@ -154,8 +174,8 @@ class MetricsRegistry:
         }
 
     def to_prometheus(self) -> str:
-        """Prometheus text exposition format (one flat dump; virtual-time
-        quantities are plain seconds)."""
+        """Prometheus text exposition format (one flat dump; time-valued
+        quantities, virtual or host clock, are plain seconds)."""
         lines = []
         for name in sorted(self.counters):
             lines.append(f"# TYPE {_prom_name(name)} counter")

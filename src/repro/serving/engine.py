@@ -116,7 +116,7 @@ from repro.memory.block_manager import BlockManager
 from repro.models import LM, sample_tokens
 from repro.obs.ledger import WasteLedger
 from repro.obs.metrics import ENGINE_COUNTER_SCHEMA, MetricsRegistry
-from repro.obs.trace import NullTracer, SpanTracer
+from repro.obs.trace import HostClock, NullTracer, SpanTracer
 from repro.serving.api_executor import (AsyncToolRuntime,
                                         ScriptedToolRuntime, ToolError,
                                         ToolResultPredictor,
@@ -286,6 +286,11 @@ class Engine:
         self.metrics = MetricsRegistry()
         self.tracer: SpanTracer = tracer if tracer is not None \
             else NullTracer()
+        # the host's own clock, always on: phase spans inside step() on
+        # the profiler's clock, their self times as host_ counters, and
+        # host-clock queue waits. The engine hands it names and keys and
+        # never reads a time back (no decision depends on it)
+        self.host_clock = HostClock(self.metrics)
         self.ledger = WasteLedger(self.cost, cap, registry=self.metrics)
         self.sched = Scheduler(policy, self.cost, estimator=estimator,
                                gpu_capacity_tokens=cap,
@@ -504,6 +509,7 @@ class Engine:
         bisect.insort_left(self._pending_arrivals, req,
                            key=lambda r: -r.arrival)
         self._pending_rids.add(req.rid)
+        self.host_clock.wait_begin(req.rid)
         return True
 
     def _admit(self):
@@ -550,6 +556,7 @@ class Engine:
         if rid in self._resume_pending:
             raise ValueError(f"request {rid} already has a resume queued")
         self._resume_pending.add(rid)
+        self.host_clock.wait_begin(rid)
         due = self.now + max(0.0, delay)
         win = self._tool_windows.get(rid)
         if win is not None and win[1] == float("inf"):
@@ -837,6 +844,7 @@ class Engine:
             self.tracer.async_end("tool", rid, rec.kind, t,
                                   {"branch": rec.branch, "outcome": cause})
         self._close_wait_mark(req, t)
+        self.host_clock.wait_drop(rid)
         # a live speculative fork dies with the session; its accrued
         # occupancy joins the teardown charge (not speculation_wasted —
         # the fork didn't mispredict, its session went away)
@@ -939,17 +947,15 @@ class Engine:
                  "decision": req.decision or "pending"})
 
     def _close_wait_mark(self, req: Request, t1: float):
-        """Close an open queued/swapped_wait window: observe it into the
-        wait histograms and emit its span ending at ``t1`` (the start of
-        the iteration that finally computes for the request), so wait
-        spans never overlap the compute spans that follow them."""
+        """Close an open queued/swapped_wait window: emit its span ending
+        at ``t1`` (the start of the iteration that finally computes for
+        the request), so wait spans never overlap the compute spans that
+        follow them. The wait histograms are on the host clock
+        (HostClock.wait_end)."""
         mark = self._wait_marks.pop(req.rid, None)
         if mark is None:
             return
         t0, kind = mark
-        self.metrics.observe(
-            "engine_queue_wait_s" if kind == "queued"
-            else "engine_swapped_wait_s", max(0.0, t1 - t0))
         if self.tracer.enabled and t1 > t0:
             self.tracer.span(("req", req.rid), kind, t0, t1)
 
@@ -1358,13 +1364,14 @@ class Engine:
         idxs = self._swap_out_pages.get(req.rid, [])
         if not idxs:
             return None
-        pids = []
-        for p in idxs:
-            kind, pid = st.pages[p]
-            assert kind == "dev"
-            pids.append(pid)
-        ticket = self.stager.pack(self.pools, pids)
-        self.blocks.free(pids)
+        with self.host_clock.span("engine.swap_out"):
+            pids = []
+            for p in idxs:
+                kind, pid = st.pages[p]
+                assert kind == "dev"
+                pids.append(pid)
+            ticket = self.stager.pack(self.pools, pids)
+            self.blocks.free(pids)
         return ticket
 
     def _complete_swap_out(self, req: Request, ticket: Optional[int]):
@@ -1375,10 +1382,11 @@ class Engine:
             return
         st = self.kv[req.rid]
         idxs = self._swap_out_pages.get(req.rid, [])
-        slab = self.stager.collect(ticket)
-        for i, p in enumerate(idxs):
-            st.pages[p] = ("host", jax.tree.map(lambda leaf: leaf[:, i],
-                                                slab))
+        with self.host_clock.span("engine.swap_collect"):
+            slab = self.stager.collect(ticket)
+            for i, p in enumerate(idxs):
+                st.pages[p] = ("host", jax.tree.map(
+                    lambda leaf: leaf[:, i], slab))
         self.counters["swap_bytes"] += \
             len(idxs) * self.page * self.kv_token_bytes
 
@@ -1397,14 +1405,15 @@ class Engine:
         got = self._allocate_pages(len(idxs))
         if got is None:
             return False
-        payloads = []
-        for p in idxs:
-            kind, payload = st.pages[p]
-            assert kind == "host"
-            payloads.append(payload)
-        slab = jax.tree.map(lambda *leaves: np.stack(leaves, axis=1),
-                            *payloads)
-        self.pools = self.stager.unpack(self.pools, got, slab)
+        with self.host_clock.span("engine.swap_in"):
+            payloads = []
+            for p in idxs:
+                kind, payload = st.pages[p]
+                assert kind == "host"
+                payloads.append(payload)
+            slab = jax.tree.map(lambda *leaves: np.stack(leaves, axis=1),
+                                *payloads)
+            self.pools = self.stager.unpack(self.pools, got, slab)
         for i, p in enumerate(idxs):
             st.pages[p] = ("dev", got[i])
         self.counters["swap_bytes"] += \
@@ -1421,6 +1430,7 @@ class Engine:
         chunk/decode write."""
         self._close_wait_mark(req, self.now)
         self._wait_marks[req.rid] = (self.now, "queued")
+        self.host_clock.wait_begin(req.rid)
         if self.tracer.enabled:
             self.tracer.instant(("req", req.rid), "pool_preempt", self.now)
         self.sched.notify_pool_exhausted(req, self.now)
@@ -1473,6 +1483,7 @@ class Engine:
         # the request goes back to FCFS with its context as recompute debt
         self._close_wait_mark(req, self.now)
         self._wait_marks[req.rid] = (self.now, "queued")
+        self.host_clock.wait_begin(req.rid)
         if self.tracer.enabled:
             self.tracer.instant(("req", req.rid), "swap_in_failed",
                                 self.now)
@@ -1681,7 +1692,8 @@ class Engine:
         """Commit half of the fused iteration: fetch the sampled ids (the
         one device->host sync of the step) and reconcile bookkeeping —
         computed counts, prefill first-token emits, decode ids."""
-        ids = np.asarray(jax.device_get(sampled))
+        with self.host_clock.span("engine.sync"):
+            ids = np.asarray(jax.device_get(sampled))
         self._decode_ids = []
         for b, (req, st, start, n, is_chunk) in enumerate(entries):
             if is_chunk:
@@ -1998,13 +2010,31 @@ class Engine:
         (DESIGN.md §12): plan -> dispatch -> commit. Returns False when no
         further progress is possible without external input (fully
         drained, or every remaining session is blocked on a caller-side
-        resume)."""
-        plan = self._plan_phase()
-        if plan.empty:
-            return self._advance_idle()
-        inflight = self._dispatch_phase(plan)
-        self._commit_phase(plan, inflight)
-        return True
+        resume).
+
+        Each phase runs inside a host-clock span (``self.host_clock``):
+        ``engine.step`` around the three, ``engine.plan``,
+        ``engine.dispatch`` and ``engine.commit``, with ``engine.swap_*``
+        and ``engine.sync`` inside the last two. A step whose plan is
+        empty adds to no counter."""
+        hc = self.host_clock
+        with hc.step() as hs:
+            with hc.span("engine.plan"):
+                plan = self._plan_phase()
+            # dispatch may drop entries the pool cannot back: decide once
+            idle = plan.empty
+            if not idle:
+                hs.count(query_tokens=plan.query_tokens,
+                         decode=len(plan.decode), chunks=len(plan.chunks),
+                         swap_out_pages=sum(map(
+                             len, self._swap_out_pages.values())),
+                         swap_in_pages=sum(map(
+                             len, self._swap_in_pages.values())))
+                with hc.span("engine.dispatch"):
+                    inflight = self._dispatch_phase(plan)
+                with hc.span("engine.commit"):
+                    self._commit_phase(plan, inflight)
+        return self._advance_idle() if idle else True
 
     def _plan_phase(self):
         """PLAN: admission, async-tool / resume injection, prefix-cache
@@ -2065,9 +2095,9 @@ class Engine:
                 # request decodes again: wait-state clock restarts at the
                 # boundary (self.now >= t_done; the due time itself can
                 # fall inside an already-committed iteration's spans)
-                self._wait_marks[req.rid] = (
-                    self.now,
-                    "swapped_wait" if req.host_tokens > 0 else "queued")
+                kind = "swapped_wait" if req.host_tokens > 0 else "queued"
+                self._wait_marks[req.rid] = (self.now, kind)
+                self.host_clock.wait_begin(req.rid, kind)
         if self.cache is not None:
             # single match point: covers fresh admissions, discarded
             # contexts re-entering after an interception, and eviction
@@ -2163,6 +2193,12 @@ class Engine:
         if self.sanitizer is not None:
             # every page this plan writes must now be live + exclusive
             self.sanitizer.check_plan(plan)
+        # the model work below is the first compute of any turn still
+        # waiting: its host-clock wait ends here
+        for req, _ in plan.chunks:
+            self.host_clock.wait_end(req.rid)
+        for req in plan.decode:
+            self.host_clock.wait_end(req.rid)
         if plan.chunks or plan.decode:
             self.counters["mixed_iterations"] += 1
         if self.fused:
@@ -2189,7 +2225,8 @@ class Engine:
         for entry in pending:
             if entry[0] == "chunk":
                 _, req, st, logits = entry
-                row = np.asarray(jax.device_get(logits[0]))
+                with self.host_clock.span("engine.sync"):
+                    row = np.asarray(jax.device_get(logits[0]))
                 self.counters["logit_bytes"] += row.nbytes
                 tid = self._sample_row(
                     req, row.reshape(-1, self.cfg.vocab_size)[-1],
@@ -2198,7 +2235,8 @@ class Engine:
                 self._prefill_emits.append((req, tid))
             else:
                 _, reqs, logits, pos = entry
-                arr = np.asarray(jax.device_get(logits))
+                with self.host_clock.span("engine.sync"):
+                    arr = np.asarray(jax.device_get(logits))
                 self.counters["logit_bytes"] += arr.nbytes
                 self._decode_ids = [
                     self._sample_row(
@@ -2238,6 +2276,7 @@ class Engine:
         else:
             stall = plan.stall_s
         iter_time = t_model + stall
+        self.counters["cost_step_s"] += iter_time
         start = self.now
         end = start + iter_time
         # tool-overlap integral: this iteration's exact intersection with
@@ -2332,6 +2371,7 @@ class Engine:
         the speculative graft's inline seed-token consult."""
         self.finished.append(req)
         self._wait_marks.pop(req.rid, None)
+        self.host_clock.wait_drop(req.rid)
         self._accrued_bs.pop(req.rid, None)   # produced output: not waste
         self._fault_state.pop(req.rid, None)
         if self.tracer.enabled:

@@ -228,9 +228,10 @@ class SessionHandle:
     # False = state/tool dispatch only, no per-handle event retention
     # (batch replay paths that never read handle.events)
     buffer_events: bool = True
-    # virtual time of the last TokenEvent — feeds the session-level
-    # TTFT / inter-token-gap histograms in the engine's registry
-    _last_token_t: Optional[float] = None
+    # a TokenEvent has been seen: the next one's host-clock lap is a
+    # token gap, not the TTFT (session-level histograms in the engine's
+    # registry)
+    _got_token: bool = False
     # speculative-resume outcomes (DESIGN.md §14): one dict per validated
     # intercept ({"kind", "accepted", "outcome", "predicted_tokens",
     # "emitted_tokens", "grafted_tokens", "time"}), appended live by the
@@ -352,6 +353,7 @@ class InferCeptClient:
         # does not speculate)
         handle.speculation = self.engine.spec_log.setdefault(rid, [])
         self.handles[rid] = handle
+        self.engine.host_clock.lap(("session", rid))
         if not self.engine.add_request(req):
             # admission backpressure: the RejectedEvent already routed
             # through the sink (handle.state == "rejected"); nothing was
@@ -373,17 +375,13 @@ class InferCeptClient:
             h.events.append(ev)
         if isinstance(ev, TokenEvent):
             h.state = "active"
-            # session latency metrics (DESIGN.md §13), on the virtual
-            # clock: first token = TTFT from submission arrival, then
-            # inter-token gaps (pauses included — the user-visible gap)
-            reg = self.engine.metrics
-            if h._last_token_t is None:
-                reg.observe("session_ttft_s",
-                            max(0.0, ev.time - h.request.arrival))
-            else:
-                reg.observe("session_token_gap_s",
-                            max(0.0, ev.time - h._last_token_t))
-            h._last_token_t = ev.time
+            # session latency metrics (DESIGN.md §13), on the host clock:
+            # first token = TTFT from submit, then inter-token gaps
+            # (pauses included — the user-visible gap)
+            self.engine.host_clock.lap(
+                ("session", ev.rid), "session_token_gap_s" if h._got_token
+                else "session_ttft_s")
+            h._got_token = True
         elif isinstance(ev, FinishEvent):
             h.state = "finished"
         elif isinstance(ev, FailedEvent):
@@ -397,6 +395,8 @@ class InferCeptClient:
             h.state = "intercepted"
             if ev.caller_owned and h.tools is not None:
                 self._dispatch_tool(h, ev)
+        if h.done:
+            self.engine.host_clock.lap_drop(("session", ev.rid))
 
     def poll(self, max_steps: int = 100_000, *, strict: bool = False):
         """Advance the engine until it drains or every remaining session

@@ -377,10 +377,17 @@ def test_trace_file_roundtrip_check(tmp_path, traced_runs):
     assert check_main([str(path)]) == 0
 
 
-def test_session_latency_histograms():
+def test_session_latency_histograms(monkeypatch):
     """TTFT and inter-token gaps observed by the session client land in
-    the engine's registry (virtual clock)."""
+    the engine's registry, on the host clock: with a fake clock that
+    advances one second a read, every observation is a whole number of
+    seconds, which the virtual clock never gives."""
+    import itertools
+    from repro.obs import trace as obs_trace
     from repro.serving.session import ScriptedClient
+    ticks = itertools.count()
+    monkeypatch.setattr(obs_trace, "host_ns",
+                        lambda: next(ticks) * 1_000_000_000)
     cfg = get_config("llama3.2-1b", tiny=True)
     eng = Engine(cfg, POLICIES["infercept"], page_size=16, n_pages=64,
                  max_model_len=192, seed=0)
@@ -389,7 +396,122 @@ def test_session_latency_histograms():
     batch = scripted.client.poll()
     assert batch.drained
     ttft = eng.metrics.histograms["session_ttft_s"]
+    gaps = eng.metrics.histograms["session_token_gap_s"]
     assert ttft.n == len(handles)
-    assert ttft.total >= 0.0
-    assert eng.metrics.histograms["session_token_gap_s"].n > 0
+    assert gaps.n > 0
+    # the wait histograms observe the same host-clock waits as the
+    # host_queue_wait counters
+    waits = [eng.metrics.histograms[k] for k in
+             ("engine_queue_wait_s", "engine_swapped_wait_s")
+             if k in eng.metrics.histograms]
+    assert sum(h.n for h in waits) == eng.metrics.counters[
+        "host_queue_waits"] > 0
+    assert sum(h.total for h in waits) == pytest.approx(
+        eng.metrics.counters["host_queue_wait_s"])
+    for h in [ttft, gaps] + waits:
+        assert h.total >= h.n            # each at least one tick
+        assert h.total == pytest.approx(round(h.total))
+    assert not eng.host_clock._laps      # finished sessions drop stamps
     eng.close()
+
+
+# ---------------------------------------------------------------------------
+# the host clock inside Engine.step (obs.trace.HostClock)
+# ---------------------------------------------------------------------------
+
+PHASE_COUNTERS = ("host_plan_s", "host_dispatch_s", "host_swap_s",
+                  "host_sync_s", "host_commit_s")
+
+
+def _host(eng):
+    return {k: v for k, v in eng.metrics.counters.items()
+            if k.startswith("host_")}
+
+
+@pytest.mark.parametrize("name", [c[0] for c in CONFIGS])
+def test_host_clock_counts_engine_phases(traced_runs, name):
+    """Every host_ counter the run's path reached is > 0; the phases'
+    self times make up 95-100% of the step time; one counted step per
+    iteration the engine charged."""
+    for key in ("on", "off"):
+        _, eng = traced_runs[name][key]
+        h = _host(eng)
+        reached = {"host_step_s", "host_steps", "host_plan_s",
+                   "host_dispatch_s", "host_sync_s", "host_commit_s",
+                   "host_queue_wait_s", "host_queue_waits"}
+        if eng.counters["swap_bytes"]:
+            reached.add("host_swap_s")
+        else:
+            assert h["host_swap_s"] == 0.0, name
+        assert all(h[k] > 0 for k in reached), (name, h)
+        phases = sum(h[k] for k in PHASE_COUNTERS)
+        assert 0.95 * h["host_step_s"] <= phases <= h["host_step_s"], \
+            (name, phases, h)
+        assert h["host_steps"] == eng.ledger.iterations, name
+        assert eng.counters["cost_step_s"] == pytest.approx(
+            eng.ledger.busy_time), name
+    assert any(traced_runs[n]["off"][1].counters["swap_bytes"]
+               for n, _ in CONFIGS)
+
+
+@pytest.mark.parametrize("name,kw", CONFIGS, ids=[c[0] for c in CONFIGS])
+def test_host_clock_feeds_no_decision(traced_runs, monkeypatch, name, kw):
+    """With the host clock frozen, streams, engine counters and the ledger
+    are bit-identical to the real-clock run: no decision reads it."""
+    from repro.obs import trace as obs_trace
+    monkeypatch.setattr(obs_trace, "host_ns", lambda: 0)
+    cfg = get_config("llama3.2-1b", tiny=True)
+    eng = Engine(cfg, POLICIES[name], page_size=16, n_pages=64,
+                 max_model_len=192, seed=0, **kw)
+    for r in copy.deepcopy(_small_workload()):
+        eng.add_request(r)
+    fin = eng.run()
+    streams = {r.rid: eng.generated_text(r) for r in fin}
+    s_real, eng_real = traced_runs[name]["off"]
+    assert streams == s_real, name
+    assert dict(eng.counters) == dict(eng_real.counters), name
+    assert eng.ledger.causes == eng_real.ledger.causes, name
+    assert eng.ledger.total_check == eng_real.ledger.total_check, name
+    assert eng.ledger.gpu_byte_seconds == eng_real.ledger.gpu_byte_seconds
+    frozen, real = _host(eng), _host(eng_real)
+    for k in ("host_steps", "host_queue_waits"):
+        assert frozen[k] == real[k] > 0, (name, k)
+    assert all(v == 0 for k, v in frozen.items()
+               if k not in ("host_steps", "host_queue_waits")), frozen
+
+
+def test_engine_spans_in_profiler_trace(tmp_path):
+    """A profile of a few fused steps holds the engine's phase spans on
+    the host plane, each inside an engine.step event, read the way the
+    benchmark reads a trace (jax.profiler.ProfileData over .xplane.pb)."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+    cfg = get_config("llama3.2-1b", tiny=True)
+    eng = Engine(cfg, POLICIES["infercept"], page_size=16, n_pages=64,
+                 max_model_len=192, seed=0)
+    for r in copy.deepcopy(_small_workload()):
+        eng.add_request(r)
+    eng.run(max_steps=4)                 # compiles out of the profile
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run(max_steps=3)
+    path = sorted(glob.glob(os.path.join(str(tmp_path), "**",
+                                         "*.xplane.pb"), recursive=True),
+                  key=os.path.getmtime)[-1]
+    events = [(plane.name, line.name, e.name, e.start_ns,
+               e.start_ns + e.duration_ns, dict(e.stats))
+              for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events
+              if e.name.startswith("engine.")]
+    steps = [e for e in events if e[2] == "engine.step"]
+    assert len(steps) == 3
+    assert all({"step_num", "query_tokens", "decode", "chunks"}
+               <= set(e[5]) for e in steps)
+    phases = [e for e in events if e[2] != "engine.step"]
+    assert {"engine.plan", "engine.dispatch", "engine.sync",
+            "engine.commit"} <= {e[2] for e in phases}
+    for plane, line, name, a, b, _ in phases:
+        assert any(s[0] == plane and s[1] == line and s[3] <= a
+                   and b <= s[4] for s in steps), name
