@@ -4,9 +4,9 @@ PagedAttention, re-thought for TPU).
 
 It is the ragged kernel's special case — token b is sequence b at position
 ctx_lens[b] - 1 — so both share one Pallas kernel
-(``ragged_paged_attention``): the block table is a scalar-prefetch operand,
-and the HBM->VMEM DMA for page ``block_tables[b, i]`` issues while page i-1
-is computed.
+(``ragged_paged_attention``): each sequence is one query tile, and its page
+loop walks KV blocks of the block table, which is a scalar-prefetch operand,
+up to its last live page only.
 
 Layout: q (B, Hkv, G, hd); pools (n_pages, page, Hkv, hd);
 block_tables (B, max_pages) int32; ctx_lens (B,) int32.

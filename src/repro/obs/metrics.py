@@ -63,6 +63,10 @@ ENGINE_COUNTER_SCHEMA: Dict[str, float] = {
     # the virtual seconds the cost model charges each iteration
     # (t_model + stall): the scheduler's priced step, deterministic
     "cost_step_s": 0.0,
+    # the ragged attention kernel's launch in each fused step, for one
+    # full-attention layer: its grid steps and those that move a live KV
+    # block (kernels/ragged_paged_attention.py grid_steps)
+    "attn_grid_steps": 0, "attn_live_steps": 0,
 }
 
 # the host clock inside Engine.step (obs.trace.HostClock), "host_"

@@ -111,6 +111,7 @@ from repro.core.estimator import DurationEstimator
 from repro.core.policy import PolicyConfig
 from repro.core.request import Interception, Phase, Request
 from repro.core.scheduler import Scheduler
+from repro.kernels.ragged_paged_attention import grid_steps
 from repro.kernels.swap_pack import SwapStager
 from repro.memory.block_manager import BlockManager
 from repro.models import LM, sample_tokens
@@ -449,6 +450,19 @@ class Engine:
                         int(leaf.nbytes) for leaf in
                         jax.tree.leaves(self.pools[gi][f"b{j}"])))
                         // page_slots)
+        # one full-attention GQA layer's ragged-kernel launch shape (None
+        # where no layer runs the kernel): the fused step counts its grid
+        self._attn_launch = None
+        for gi, g in enumerate(cfg.groups):
+            for j, blk in enumerate(g.period):
+                a, pool = blk.attn, self.pools[gi].get(f"b{j}")
+                if (self._attn_launch is None and a is not None
+                        and a.kind != "mla" and isinstance(pool, dict)
+                        and "k" in pool):
+                    self._attn_launch = dict(
+                        Hkv=a.n_kv_heads, G=a.n_heads // a.n_kv_heads,
+                        hd=a.head_dim, q_bytes=jnp.dtype(dtype).itemsize,
+                        kv_bytes=pool["k"].dtype.itemsize)
         # jitted entry points (stable shapes via bucketing); pools are
         # donated so the paged update is truly in place — every caller
         # rebinds self.pools to the result and never reads the old tree
@@ -1683,6 +1697,13 @@ class Engine:
             + (n_dec + (pad_rows if n_dec else 0)) * mla_gather
         self.counters["decode_tokens"] += n_dec
         self.counters["device_dispatches"] += 1
+        if self._attn_launch is not None:
+            grid, live = grid_steps(
+                [(start, n) for _, _, start, n, _ in entries], N_pad, B_pad,
+                page=self.page, max_pages=self.max_pages,
+                **self._attn_launch)
+            self.counters["attn_grid_steps"] += grid
+            self.counters["attn_live_steps"] += live
         # B_pad int32 ids, O(B) — size known without fetching
         self.counters["logit_bytes"] += \
             int(sampled.size) * sampled.dtype.itemsize

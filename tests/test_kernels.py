@@ -139,48 +139,109 @@ def test_paged_attention_sliding_window(window):
 # ---------------------------------------------------------------------------
 # ragged-query paged attention (the fused mixed-batch core, DESIGN.md §10)
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("N,Hkv,G,hd,page,max_pages,n_pages,B", [
-    (1, 2, 4, 64, 16, 8, 32, 1),     # a single decode token
-    (9, 2, 4, 64, 16, 8, 32, 3),     # mixed ragged batch
-    (6, 1, 8, 128, 8, 16, 64, 2),
-    (5, 4, 1, 32, 32, 4, 16, 5),
+def _span_rows(spans, N):
+    """tok_seq / tok_pos for rows laid out as the fused step lays them:
+    each (sequence, first position, rows) span in order, padded rows
+    (position -1) after."""
+    seq = [b for b, _, n in spans for _ in range(n)]
+    pos = [p0 + i for _, p0, n in spans for i in range(n)]
+    pad = N - len(seq)
+    return (jnp.asarray(seq + [0] * pad, jnp.int32),
+            jnp.asarray(pos + [-1] * pad, jnp.int32))
+
+
+# query tiles (kernels/ragged_paged_attention.py): T·G query rows per tile
+# reach 128, so a chunk of more than 128 / G rows spans several tiles
+TILED = {
+    # MHA: a 150-row chunk from mid-page position 13 over two tiles of 128,
+    # decodes (one at full context, 32 pages x 8), padded rows
+    "mha-chunk-midpage": ((2, 13, 150), (0, 200, 1), (3, 37, 1),
+                          (1, 255, 1)),
+    # GQA 8: tiles of 16 rows; a 40-row chunk from position 0, a decode at
+    # full context (8 pages x 16), a short decode, padded rows
+    "gqa-chunk-at-0": ((1, 0, 40), (0, 127, 1), (2, 5, 1)),
+    # head size 64: a chunk that ends at full context (6 pages x 8) beside
+    # a decode of another sequence at full context
+    "chunk-to-full-context": ((0, 20, 28), (1, 47, 1)),
+}
+
+
+@pytest.mark.parametrize("N,Hkv,G,hd,page,max_pages,n_pages,B,spans", [
+    pytest.param(1, 2, 4, 64, 16, 8, 32, 1, None,      # a single decode
+                 id="1-2-4-64-16-8-32-1"),
+    pytest.param(9, 2, 4, 64, 16, 8, 32, 3, None,      # mixed ragged batch
+                 id="9-2-4-64-16-8-32-3"),
+    pytest.param(6, 1, 8, 128, 8, 16, 64, 2, None, id="6-1-8-128-8-16-64-2"),
+    pytest.param(5, 4, 1, 32, 32, 4, 16, 5, None, id="5-4-1-32-32-4-16-5"),
+    pytest.param(192, 4, 1, 128, 8, 32, 96, 4, TILED["mha-chunk-midpage"],
+                 id="mha-chunk-midpage"),
+    pytest.param(64, 2, 8, 128, 16, 8, 32, 3, TILED["gqa-chunk-at-0"],
+                 id="gqa-chunk-at-0"),
+    pytest.param(32, 2, 4, 64, 8, 6, 24, 2, TILED["chunk-to-full-context"],
+                 id="chunk-to-full-context"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_ragged_paged_attention(N, Hkv, G, hd, page, max_pages, n_pages, B,
-                                dtype):
+                                spans, dtype):
     rng = np.random.default_rng(N * 13 + page)
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (N, Hkv, G, hd)).astype(dtype)
     kp = jax.random.normal(ks[1], (n_pages, page, Hkv, hd)).astype(dtype)
     vp = jax.random.normal(ks[2], (n_pages, page, Hkv, hd)).astype(dtype)
     bt = jnp.asarray(rng.integers(0, n_pages, (B, max_pages)), jnp.int32)
-    tok_seq = jnp.asarray(rng.integers(0, B, (N,)), jnp.int32)
-    tok_pos = jnp.asarray(rng.integers(0, page * max_pages, (N,)), jnp.int32)
+    if spans is None:
+        tok_seq = jnp.asarray(rng.integers(0, B, (N,)), jnp.int32)
+        tok_pos = jnp.asarray(rng.integers(0, page * max_pages, (N,)),
+                              jnp.int32)
+    else:
+        tok_seq, tok_pos = _span_rows(spans, N)
     out = ragged_paged_attention(q, kp, vp, bt, tok_seq, tok_pos,
                                  interpret=True)
     want = ref.ragged_paged_attention_ref(q, kp, vp, bt, tok_seq, tok_pos)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32), atol=tol)
+    real = np.asarray(tok_pos) >= 0
+    np.testing.assert_allclose(np.asarray(out, np.float32)[real],
+                               np.asarray(want, np.float32)[real], atol=tol)
+    assert not np.asarray(out, np.float32)[~real].any(), \
+        "padded rows must come out zero"
 
 
-@pytest.mark.parametrize("window,softcap", [(7, None), (16, None),
-                                            (None, 30.0), (9, 25.0)])
-def test_ragged_paged_attention_window_softcap(window, softcap):
+@pytest.mark.parametrize("window,softcap,tiled", [
+    pytest.param(7, None, False, id="7-None"),
+    pytest.param(16, None, False, id="16-None"),
+    pytest.param(None, 30.0, False, id="None-30.0"),
+    pytest.param(9, 25.0, False, id="9-25.0"),
+    # a chunk over several query tiles (GQA 8: 16 rows a tile) whose window
+    # starts its page loop past block 0, with decodes and padded rows
+    pytest.param(20, 30.0, True, id="tiled-20-30.0"),
+    pytest.param(200, None, True, id="tiled-200-None"),
+])
+def test_ragged_paged_attention_window_softcap(window, softcap, tiled):
     rng = np.random.default_rng(0 if window is None else window)
     ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (6, 2, 2, 32))
-    kp = jax.random.normal(ks[1], (16, 8, 2, 32))
-    vp = jax.random.normal(ks[2], (16, 8, 2, 32))
-    bt = jnp.asarray(rng.integers(0, 16, (2, 4)), jnp.int32)
-    tok_seq = jnp.asarray([0, 0, 0, 1, 1, 0], jnp.int32)
-    tok_pos = jnp.asarray([0, 12, 31, 7, 8, 29], jnp.int32)
+    if tiled:
+        Hkv, G, hd, page, max_pages, n_pages = 2, 8, 128, 8, 40, 96
+        q = jax.random.normal(ks[0], (64, Hkv, G, hd))
+        kp = jax.random.normal(ks[1], (n_pages, page, Hkv, hd))
+        vp = jax.random.normal(ks[2], (n_pages, page, Hkv, hd))
+        bt = jnp.asarray(rng.integers(0, n_pages, (3, max_pages)), jnp.int32)
+        tok_seq, tok_pos = _span_rows(
+            ((0, 250, 45), (1, 319, 1), (2, 3, 1)), 64)
+    else:
+        q = jax.random.normal(ks[0], (6, 2, 2, 32))
+        kp = jax.random.normal(ks[1], (16, 8, 2, 32))
+        vp = jax.random.normal(ks[2], (16, 8, 2, 32))
+        bt = jnp.asarray(rng.integers(0, 16, (2, 4)), jnp.int32)
+        tok_seq = jnp.asarray([0, 0, 0, 1, 1, 0], jnp.int32)
+        tok_pos = jnp.asarray([0, 12, 31, 7, 8, 29], jnp.int32)
     out = ragged_paged_attention(q, kp, vp, bt, tok_seq, tok_pos,
                                  window=window, softcap=softcap,
                                  interpret=True)
     want = ref.ragged_paged_attention_ref(q, kp, vp, bt, tok_seq, tok_pos,
                                           window=window, softcap=softcap)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+    real = np.asarray(tok_pos) >= 0
+    np.testing.assert_allclose(np.asarray(out)[real], np.asarray(want)[real],
+                               atol=2e-5)
 
 
 def test_ragged_degenerates_to_paged_attention():
@@ -386,3 +447,48 @@ else:                                                # pragma: no cover
     @pytest.mark.skip(reason="hypothesis not installed")
     def test_swap_roundtrip_property():
         pass
+
+
+# ---------------------------------------------------------------------------
+# the ragged kernel's grid and live steps (the engine's attn_* counters)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("window", [None, 24])
+def test_grid_steps_match_brute_force(window):
+    """``grid_steps`` (host, from each sequence's span) equals a brute-force
+    count over a toy plan: a KV block is live for a tile when some row of
+    the tile sees some position in it. The device-side tile plan the kernel
+    launches from gives the same count."""
+    from repro.kernels.ragged_paged_attention import (_tile_plan,
+                                                      grid_shape,
+                                                      grid_steps,
+                                                      tile_sizes)
+    dims = dict(Hkv=2, G=8, hd=128, page=8, max_pages=40, q_bytes=4,
+                kv_bytes=4)
+    N, B = 128, 5
+    spans = [(0, 29, 70), (1, 300, 1), (2, 0, 1), (3, 150, 9), (4, 319, 1)]
+    grid, live = grid_steps([(p0, n) for _, p0, n in spans], N, B,
+                            window=window, **dims)
+
+    T, P = tile_sizes(N, dims["Hkv"], dims["G"], dims["hd"], dims["page"],
+                      dims["max_pages"], 4, 4)
+    nt, nb = grid_shape(N, B, T, P, dims["max_pages"])
+    rows = [(b, p0 + i) for b, p0, n in spans for i in range(n)]
+    tiles = {}
+    for r, (b, p) in enumerate(rows):
+        tiles.setdefault((r // T, b), []).append(p)
+    S = P * dims["page"]
+    brute = 0
+    for ps in tiles.values():
+        for i in range(nb):
+            xs = range(i * S, min((i + 1) * S, dims["max_pages"] * 8))
+            brute += any(x <= p and (window is None or x > p - window)
+                         for p in ps for x in xs)
+    assert live == brute
+    assert grid == nt * nb and len(tiles) <= nt
+
+    tok_seq, tok_pos = _span_rows(spans, N)
+    key = jnp.where(tok_pos >= 0, tok_seq, B)
+    meta = np.asarray(_tile_plan(key, tok_pos, B=B, T=T, P=P, page=8,
+                                 window=window, n_tiles=nt)).reshape(6, nt)
+    _, _, lo, hi, first, last = meta
+    assert int(np.sum((hi > lo) * (last - first + 1))) == live

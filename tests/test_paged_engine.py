@@ -474,3 +474,40 @@ def test_mixed_pad_rows_never_touch_pages():
         arr = np.asarray(leaf)              # (periods, n_pages, page, ...)
         assert np.all(arr[:, ~live] == 7.5), "pad row wrote a pool page"
         assert not np.any(arr[:, live] == 7.5), "live row write missing"
+
+
+def test_fused_step_counts_attention_grid(monkeypatch):
+    """Every fused step adds the ragged kernel's grid steps and live steps
+    for its launch (kernels/ragged_paged_attention.py grid_steps, one
+    full-attention layer) to attn_grid_steps / attn_live_steps."""
+    from repro.kernels.ragged_paged_attention import grid_steps
+    cfg = get_config("llama3.2-1b", tiny=True)
+    eng = Engine(cfg, POLICIES["infercept"], page_size=16, n_pages=128,
+                 max_model_len=256, seed=0, paged=True, fused=True)
+    a = cfg.groups[0].period[0].attn
+    seen = []
+    real = eng._dispatch_mixed
+
+    def counted(plan):
+        g0 = eng.counters["attn_grid_steps"]
+        l0 = eng.counters["attn_live_steps"]
+        out = real(plan)
+        if out is not None:
+            spans = [(start, n) for _, _, start, n, _ in out[0]]
+            want = grid_steps(
+                spans, Engine._bucket(sum(n for _, n in spans)),
+                Engine._bucket(len(spans)), Hkv=a.n_kv_heads,
+                G=a.n_heads // a.n_kv_heads, hd=a.head_dim, page=16,
+                max_pages=eng.max_pages, q_bytes=4, kv_bytes=4)
+            assert (eng.counters["attn_grid_steps"] - g0,
+                    eng.counters["attn_live_steps"] - l0) == want
+            seen.append(want)
+        return out
+
+    monkeypatch.setattr(eng, "_dispatch_mixed", counted)
+    for r in _agent_workload(cfg):
+        eng.add_request(r)
+    eng.run()
+    assert seen and all(0 < live <= grid for grid, live in seen)
+    assert eng.counters["attn_grid_steps"] == sum(g for g, _ in seen)
+    assert eng.counters["attn_live_steps"] == sum(lv for _, lv in seen)

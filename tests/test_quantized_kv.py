@@ -210,6 +210,40 @@ def test_ragged_quant_attention_matches_ref():
     np.testing.assert_allclose(np.asarray(pal), np.asarray(rf), atol=1e-5)
 
 
+@pytest.mark.parametrize("name", ["int8", "float8_e4m3"])
+def test_ragged_quant_attention_tiled(name):
+    """The tiled grid over quantized pools: a chunk over several query
+    tiles (GQA 4: 32 rows a tile) from mid-page, decodes (one at full
+    context), padded rows; per-page per-head scales ride each page's
+    block."""
+    from repro.kernels.ops import ragged_paged_attention_op
+    qdtype = kv_quant_jnp_dtype(name)
+    N, Hkv, G, hd, page, n_pages, max_pages = 96, 2, 4, 16, 8, 64, 16
+    ks = jax.random.split(jax.random.fold_in(KEY, 11), 3)
+    q = jax.random.normal(ks[0], (N, Hkv, G, hd), jnp.float32)
+    kf = jax.random.normal(ks[1], (n_pages, page, Hkv, hd), jnp.float32)
+    vf = jax.random.normal(ks[2], (n_pages, page, Hkv, hd), jnp.float32)
+    kscale = jnp.max(jnp.abs(kf), axis=(1, 3)) / kv_quant_qmax(qdtype)
+    vscale = jnp.max(jnp.abs(vf), axis=(1, 3)) / kv_quant_qmax(qdtype)
+    kq = quantize_rows(kf, kscale[:, None], qdtype)
+    vq = quantize_rows(vf, vscale[:, None], qdtype)
+    bt = jnp.asarray(np.random.default_rng(4).permutation(n_pages)[:3 * 16]
+                     .reshape(3, max_pages), jnp.int32)
+    seq = [1] * 70 + [0, 2]
+    pos = list(range(11, 81)) + [max_pages * page - 1, 40]
+    tok_seq = jnp.asarray(seq + [0] * (N - 72), jnp.int32)
+    tok_pos = jnp.asarray(pos + [-1] * (N - 72), jnp.int32)
+    pal = ragged_paged_attention_op(q, kq, vq, bt, tok_seq, tok_pos,
+                                    k_scale=kscale, v_scale=vscale,
+                                    use_pallas=True, interpret=True)
+    rf = ref.ragged_paged_attention_quant_ref(
+        q, kq, vq, kscale, vscale, bt, tok_seq, tok_pos, softcap=None,
+        scale=None, window=None)
+    np.testing.assert_allclose(np.asarray(pal)[:72], np.asarray(rf)[:72],
+                               atol=1e-5)
+    assert not np.asarray(pal)[72:].any()
+
+
 # ---------------------------------------------------------------------------
 # structural pins: init_cache, engine validation, byte accounting
 # ---------------------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""The served Pallas kernels compile for a TPU v5e at Llama-3.2-1B widths.
+"""The served Pallas kernels compile for a TPU v5e at Llama-3.2-1B widths,
+and the ragged attention at the benchmark stages' widths too.
 
 Interpret mode (every other kernel test) cannot see the TPU compiler's
 tiling rules, so a block shape the chip refuses passes there. These tests
@@ -58,23 +59,54 @@ def _assert_kernel(hlo, name):
         f"{name} did not lower to a Mosaic kernel"
 
 
-@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8])
-def test_ragged_paged_attention_compiles(one_chip, pool_dtype):
+# the attention's launch widths: N, Hkv, G, hd, max_pages, n_pages, B —
+# Llama-3.2-1B's, and the benchmark's stages at their largest fused-step
+# bucket (bench/configs)
+WIDTHS = {
+    "llama-3.2-1b": (N_TOK, HKV, G, HD, MAX_PAGES, N_PAGES, N_SEQ),
+    "deepseek-7b-pp2": (512, 32, 1, 128, 256, 960, 32),
+    "qwen2-72b-pp20": (512, 8, 8, 128, 512, 10240, 256),
+}
+
+
+def _custom_call_names(hlo):
+    """Instruction names of the compiled program's Mosaic kernels."""
+    return [line.split("=")[0].strip().lstrip("%")
+            for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("widths,pool_dtype", [
+    pytest.param("llama-3.2-1b", jnp.bfloat16, id="bfloat16"),
+    pytest.param("llama-3.2-1b", jnp.int8, id="int8"),
+    pytest.param("deepseek-7b-pp2", jnp.bfloat16, id="deepseek-7b-pp2-bf16"),
+    pytest.param("deepseek-7b-pp2", jnp.int8, id="deepseek-7b-pp2-int8"),
+    pytest.param("qwen2-72b-pp20", jnp.bfloat16, id="qwen2-72b-pp20-bf16"),
+    pytest.param("qwen2-72b-pp20", jnp.int8, id="qwen2-72b-pp20-int8"),
+])
+def test_ragged_paged_attention_compiles(one_chip, widths, pool_dtype):
+    """The tiled launch compiles, bf16 and int8 pools; every Mosaic kernel
+    of the program keeps the name the benchmark's trace reducer finds it
+    by."""
+    N, Hkv, G_, hd, max_pages, n_pages, B = WIDTHS[widths]
     s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
     i32 = jnp.int32
-    pool = s((N_PAGES, PAGE, HKV, HD), pool_dtype)
+    pool = s((n_pages, PAGE, Hkv, hd), pool_dtype)
     quant = pool_dtype == jnp.int8
-    scales = [s((N_PAGES, HKV), jnp.float32)] * 2 if quant else []
+    scales = [s((n_pages, Hkv), jnp.float32)] * 2 if quant else []
 
     def fn(q, k, v, bt, ts, tp, *sc):
         kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
         return ragged_paged_attention(q, k, v, bt, ts, tp, interpret=False,
                                       **kw)
 
-    hlo = _compile(fn, s((N_TOK, HKV, G, HD), jnp.bfloat16), pool, pool,
-                   s((N_SEQ, MAX_PAGES), i32), s((N_TOK,), i32),
-                   s((N_TOK,), i32), *scales)
+    hlo = _compile(fn, s((N, Hkv, G_, hd), jnp.bfloat16), pool, pool,
+                   s((B, max_pages), i32), s((N,), i32), s((N,), i32),
+                   *scales)
     _assert_kernel(hlo, "ragged_paged_attention")
+    names = _custom_call_names(hlo)
+    assert all(n.split(".")[0] == "ragged_paged_attention" for n in names), \
+        names
 
 
 def test_paged_attention_compiles(one_chip):
