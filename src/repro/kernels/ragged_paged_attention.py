@@ -46,12 +46,20 @@ step, T·G up to 128 query rows, halved until the blocks and scratch fit a
 VMEM budget. ``grid_steps`` counts one launch's grid steps and live steps
 with the same arithmetic, on the host.
 
-A head's (P·page, hd) K or V rows sit Hkv rows apart in the page. For a
-bfloat16 pool with hd one lane width (128) and Hkv even, strided 32-bit
-loads take them out two heads at a time (each word packs the same
-position's rows of heads 2i and 2i+1). Other pools (quantized, float32,
-other head sizes) convert the block to float32 and slice it, as the
-per-page kernel did.
+A head's (P·page, hd) K or V rows sit Hkv rows apart in the page; a loop
+over the heads takes them out, one of two ways chosen from the pool's
+dtype and shape. A bfloat16 pool with hd one lane width (128) and Hkv even
+(every benchmark cell) takes two heads a step by strided 32-bit loads
+straight from the pages, each word holding the same position's rows of
+heads 2i and 2i+1. Other pools (quantized, float32, other head sizes)
+convert the block to float32 once into VMEM scratch (a quantized pool's
+per-page per-head scales applied there) and take one head a step by a
+strided load of it; the copy costs 16–87% more kernel time at the
+benchmark stages' widths, which is why bf16 pools skip it. The matmuls run on
+the pool's own dtype where it is bf16 or f32 (the probabilities are cast
+to it for P·V) and in float32 for a quantized pool. The loop is not
+unrolled: Hkv copies of its body cost seconds of tracing and lowering per
+compiled fused step.
 """
 from __future__ import annotations
 
@@ -67,9 +75,16 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 KV_ROWS = 128                 # KV positions a grid step serves: MXU width
 Q_ROWS = 128                  # query rows (tokens x group) a tile holds
-VMEM_BUDGET = 12 * 2 ** 20    # blocks + scratch, under v5e's 16 MiB scope
+VMEM_BUDGET = 12 * 2 ** 20    # blocks + scratch; VMEM_LIMIT leaves Mosaic room
+VMEM_LIMIT = 32 * 2 ** 20     # the kernel's scoped VMEM (v5e holds 128 MiB)
 # tile metadata rows, each n_tiles long in the flat scalar-prefetch array
 _SEQ, _QB, _LO, _HI, _FIRST, _LAST = range(6)
+
+
+def _pairs(Hkv, hd, kv_bytes):
+    """Heads come out of the pages two at a time by strided 32-bit loads:
+    a 2-byte (bfloat16) pool whose rows are one lane width, Hkv even."""
+    return kv_bytes == 2 and hd == 128 and Hkv % 2 == 0
 
 
 def _vmem_bytes(T, P, *, Hkv, G, hd, page, q_bytes, kv_bytes):
@@ -77,8 +92,11 @@ def _vmem_bytes(T, P, *, Hkv, G, hd, page, q_bytes, kv_bytes):
     q_blk = Hkv * rows * hd * q_bytes
     kv_blk = P * page * Hkv * hd * kv_bytes
     lanes = rows * 128 * 4            # a (rows, 1) f32 column pads to 128
+    staged = (0 if _pairs(Hkv, hd, kv_bytes)
+              else P * page * Hkv * -(-hd // 128) * 128 * 4)
     return (4 * q_blk                 # q and out, double-buffered
             + 4 * kv_blk              # K and V, double-buffered
+            + 2 * staged              # K and V in float32
             + Hkv * rows * hd * 4     # acc
             + 2 * Hkv * lanes         # m, l
             + 2 * lanes)              # row positions, double-buffered
@@ -171,17 +189,43 @@ def _head_pair_rows(refs, hp, Hkv, page):
     return lo, hi
 
 
+def _stage(pages, scales, dst, *, page, Hkv):
+    """A KV block given as one ref per page, each (1, page * Hkv, hd), in
+    float32 into ``dst``: row s·Hkv + h holds position s of head h, and a
+    quantized pool's per-page per-head scales are applied. Lanes go in
+    chunks of dst's width, chunk c from row c·S·Hkv, since a strided load
+    wants rows of at most 128 lanes."""
+    rows = page * Hkv
+    R = len(pages) * rows
+    w = dst.shape[1]
+    for j, r in enumerate(pages):
+        x = r[0].astype(jnp.float32)
+        if scales is not None:
+            x = (x.reshape(page, Hkv, -1)
+                 * scales[j][0][None]).reshape(rows, -1)
+        for c in range(dst.shape[0] // R):
+            dst[pl.ds(c * R + j * rows, rows), :] = x[:, c * w:(c + 1) * w]
+
+
+def _staged_rows(ref, h, S, Hkv):
+    """Head h's (S, hd) rows of a block that ``_stage`` wrote."""
+    parts = [ref[pl.ds(c * S * Hkv + h, S, stride=Hkv), :]
+             for c in range(ref.shape[0] // (S * Hkv))]
+    return jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
+
+
 def _ragged_kernel(bt_ref, meta_ref,        # scalar-prefetch operands
                    q_ref, pos_ref, *refs, P, page, Hkv, G, n_tiles,
-                   softcap, scale, window, pairs, quant):
+                   softcap, scale, window, quant, dot_dtype):
     del bt_ref
     kr, vr = refs[:P], refs[P:2 * P]
     if quant:
         ksr, vsr = refs[2 * P:3 * P], refs[3 * P:4 * P]
         refs = refs[4 * P:]
     else:
+        ksr = vsr = None
         refs = refs[2 * P:]
-    o_ref, acc_ref, m_ref, l_ref = refs
+    o_ref, acc_ref, m_ref, l_ref, *staged = refs
     t = pl.program_id(0)
     i = pl.program_id(1)
     nb = pl.num_programs(1)
@@ -206,7 +250,7 @@ def _ragged_kernel(bt_ref, meta_ref,        # scalar-prefetch operands
             visible &= col > row_pos - window
 
         def attend(h, k, v):
-            q = q_ref[h].astype(k.dtype)                 # (T·G, hd)
+            q = q_ref[h].astype(dot_dtype)               # (T·G, hd)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = s * scale
@@ -220,34 +264,31 @@ def _ragged_kernel(bt_ref, meta_ref,        # scalar-prefetch operands
             l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
             m_ref[h] = m_new
             acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                p.astype(dot_dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-        if pairs:
-            # a loop, not an unrolled body: tracing and lowering 2·Hkv
-            # copies of it would cost seconds per compiled fused step
-            @pl.loop(0, Hkv // 2)
-            def _(hp):
-                k0, k1 = _head_pair_rows(kr, hp, Hkv, page)
-                v0, v1 = _head_pair_rows(vr, hp, Hkv, page)
-                attend(2 * hp, k0, v0)
-                attend(2 * hp + 1, k1, v1)
+        if staged:
+            kf_ref, vf_ref = staged
+            _stage(kr, ksr, kf_ref, page=page, Hkv=Hkv)
+            _stage(vr, vsr, vf_ref, page=page, Hkv=Hkv)
+            n = 1
+
+            def heads(g):
+                return [(_staged_rows(kf_ref, g, S, Hkv).astype(dot_dtype),
+                         _staged_rows(vf_ref, g, S, Hkv).astype(dot_dtype))]
         else:
-            # the block in float32 once; a quantized pool's per-page
-            # per-head scales broadcast over the page (the fp32 pool never
-            # exists)
-            def block(pages, scales):
-                out = []
-                for j, r in enumerate(pages):
-                    x = r[0].astype(jnp.float32).reshape(page, Hkv, -1)
-                    if scales is not None:
-                        x = x * scales[j][0][None]
-                    out.append(x)
-                return jnp.concatenate(out, axis=0)      # (S, Hkv, hd)
-            kb = block(kr, ksr if quant else None)
-            vb = block(vr, vsr if quant else None)
-            for h in range(Hkv):
-                attend(h, kb[:, h, :], vb[:, h, :])
+            n = 2
+
+            def heads(g):
+                return zip(_head_pair_rows(kr, g, Hkv, page),
+                           _head_pair_rows(vr, g, Hkv, page))
+
+        # a loop, not an unrolled body: tracing and lowering Hkv copies of
+        # it would cost seconds per compiled fused step
+        @pl.loop(0, Hkv // n)
+        def _(g):
+            for j, (k, v) in enumerate(heads(g)):
+                attend(g * n + j, k, v)
 
     @pl.when((i == nb - 1) & (hi > lo))
     def _flush():
@@ -279,9 +320,10 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, tok_seq,
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     quant = k_scale is not None
     kv_dtype = np.dtype(k_pool.dtype)
-    # strided loads need 32-bit rows of exactly one lane width
-    pairs = (kv_dtype == jnp.bfloat16 and not quant and hd == 128
-             and Hkv % 2 == 0)
+    pairs = _pairs(Hkv, hd, kv_dtype.itemsize)
+    # the MXU takes a bf16 or f32 pool's own values; dequantized K/V stay f32
+    dot_dtype = (jnp.float32 if quant or kv_dtype.itemsize < 2
+                 else kv_dtype)
     T, P = tile_sizes(N, Hkv, G, hd, page, max_pages,
                       np.dtype(q.dtype).itemsize, kv_dtype.itemsize)
     n_tiles, nb = grid_shape(N, B, T, P, max_pages)
@@ -330,23 +372,28 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, tok_seq,
         operands += [k_scale[..., None]] * P + [v_scale[..., None]] * P
     kernel = functools.partial(
         _ragged_kernel, P=P, page=page, Hkv=Hkv, G=G, n_tiles=n_tiles,
-        softcap=softcap, scale=scale, window=window, pairs=pairs, quant=quant)
+        softcap=softcap, scale=scale, window=window, quant=quant,
+        dot_dtype=dot_dtype)
+    scratch = [pltpu.VMEM((Hkv, T * G, hd), jnp.float32),
+               pltpu.VMEM((Hkv, T * G, 1), jnp.float32),
+               pltpu.VMEM((Hkv, T * G, 1), jnp.float32)]
+    if not pairs:
+        w = 128 if hd % 128 == 0 else hd             # float32 copy's lanes
+        scratch += [pltpu.VMEM((hd // w * P * page * Hkv, w),
+                               jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_tiles, nb),
         in_specs=in_specs,
         out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, T * G, hd), jnp.float32),
-            pltpu.VMEM((Hkv, T * G, 1), jnp.float32),
-            pltpu.VMEM((Hkv, T * G, 1), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Hkv, Nq * G, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="ragged_paged_attention",
     )(block_tables.astype(i32), meta, *operands)

@@ -64,8 +64,9 @@ ENGINE_COUNTER_SCHEMA: Dict[str, float] = {
     # (t_model + stall): the scheduler's priced step, deterministic
     "cost_step_s": 0.0,
     # the ragged attention kernel's launch in each fused step, for one
-    # full-attention layer: its grid steps and those that move a live KV
-    # block (kernels/ragged_paged_attention.py grid_steps)
+    # layer (a full-attention one where the model has one): its grid
+    # steps and those that move a live KV block
+    # (kernels/ragged_paged_attention.py grid_steps)
     "attn_grid_steps": 0, "attn_live_steps": 0,
 }
 

@@ -450,19 +450,22 @@ class Engine:
                         int(leaf.nbytes) for leaf in
                         jax.tree.leaves(self.pools[gi][f"b{j}"])))
                         // page_slots)
-        # one full-attention GQA layer's ragged-kernel launch shape (None
-        # where no layer runs the kernel): the fused step counts its grid
-        self._attn_launch = None
+        # the ragged-kernel launch of one GQA layer (None where no layer
+        # runs the kernel), a full-attention one where the model has one,
+        # else its first windowed one: the fused step counts its grid
+        launches = []
         for gi, g in enumerate(cfg.groups):
             for j, blk in enumerate(g.period):
                 a, pool = blk.attn, self.pools[gi].get(f"b{j}")
-                if (self._attn_launch is None and a is not None
-                        and a.kind != "mla" and isinstance(pool, dict)
-                        and "k" in pool):
-                    self._attn_launch = dict(
+                if (a is not None and a.kind != "mla"
+                        and isinstance(pool, dict) and "k" in pool):
+                    launches.append(dict(
                         Hkv=a.n_kv_heads, G=a.n_heads // a.n_kv_heads,
                         hd=a.head_dim, q_bytes=jnp.dtype(dtype).itemsize,
-                        kv_bytes=pool["k"].dtype.itemsize)
+                        kv_bytes=pool["k"].dtype.itemsize,
+                        window=a.sliding_window))
+        launches.sort(key=lambda la: la["window"] is not None)
+        self._attn_launch = launches[0] if launches else None
         # jitted entry points (stable shapes via bucketing); pools are
         # donated so the paged update is truly in place — every caller
         # rebinds self.pools to the result and never reads the old tree

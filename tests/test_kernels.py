@@ -163,6 +163,9 @@ TILED = {
     # head size 64: a chunk that ends at full context (6 pages x 8) beside
     # a decode of another sequence at full context
     "chunk-to-full-context": ((0, 20, 28), (1, 47, 1)),
+    # an odd group (G 3): q blocks of 64 rows, a 90-row chunk over two of
+    # them, decodes, padded rows
+    "odd-group": ((0, 5, 90), (2, 127, 1), (1, 60, 1)),
 }
 
 
@@ -179,6 +182,8 @@ TILED = {
                  id="gqa-chunk-at-0"),
     pytest.param(32, 2, 4, 64, 8, 6, 24, 2, TILED["chunk-to-full-context"],
                  id="chunk-to-full-context"),
+    pytest.param(128, 2, 3, 128, 8, 16, 48, 3, TILED["odd-group"],
+                 id="odd-group"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_ragged_paged_attention(N, Hkv, G, hd, page, max_pages, n_pages, B,
@@ -452,12 +457,23 @@ else:                                                # pragma: no cover
 # ---------------------------------------------------------------------------
 # the ragged kernel's grid and live steps (the engine's attn_* counters)
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("G", [1, 2, 3, 5, 8])
+def test_tile_sizes_keep_q_rows_on_sublane_tiling(G):
+    """Wide heads make the VMEM budget halve T down to its floor; a q block
+    short of the batch keeps T·G a multiple of 16 (bf16's sublane tiling),
+    odd groups included."""
+    from repro.kernels.ragged_paged_attention import tile_sizes
+    T, P = tile_sizes(512, 32, G, 256, 16, 256, 2, 2)
+    assert T < 512 and (T * G) % 16 == 0 and P == 8
+
+
 @pytest.mark.parametrize("window", [None, 24])
 def test_grid_steps_match_brute_force(window):
     """``grid_steps`` (host, from each sequence's span) equals a brute-force
     count over a toy plan: a KV block is live for a tile when some row of
     the tile sees some position in it. The device-side tile plan the kernel
     launches from gives the same count."""
+    from attn_grid import brute_force_steps
     from repro.kernels.ragged_paged_attention import (_tile_plan,
                                                       grid_shape,
                                                       grid_steps,
@@ -468,24 +484,12 @@ def test_grid_steps_match_brute_force(window):
     spans = [(0, 29, 70), (1, 300, 1), (2, 0, 1), (3, 150, 9), (4, 319, 1)]
     grid, live = grid_steps([(p0, n) for _, p0, n in spans], N, B,
                             window=window, **dims)
+    assert (grid, live) == brute_force_steps(spans, N, B, window=window,
+                                             **dims)
 
     T, P = tile_sizes(N, dims["Hkv"], dims["G"], dims["hd"], dims["page"],
                       dims["max_pages"], 4, 4)
-    nt, nb = grid_shape(N, B, T, P, dims["max_pages"])
-    rows = [(b, p0 + i) for b, p0, n in spans for i in range(n)]
-    tiles = {}
-    for r, (b, p) in enumerate(rows):
-        tiles.setdefault((r // T, b), []).append(p)
-    S = P * dims["page"]
-    brute = 0
-    for ps in tiles.values():
-        for i in range(nb):
-            xs = range(i * S, min((i + 1) * S, dims["max_pages"] * 8))
-            brute += any(x <= p and (window is None or x > p - window)
-                         for p in ps for x in xs)
-    assert live == brute
-    assert grid == nt * nb and len(tiles) <= nt
-
+    nt, _ = grid_shape(N, B, T, P, dims["max_pages"])
     tok_seq, tok_pos = _span_rows(spans, N)
     key = jnp.where(tok_pos >= 0, tok_seq, B)
     meta = np.asarray(_tile_plan(key, tok_pos, B=B, T=T, P=P, page=8,

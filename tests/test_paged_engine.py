@@ -476,15 +476,17 @@ def test_mixed_pad_rows_never_touch_pages():
         assert not np.any(arr[:, live] == 7.5), "live row write missing"
 
 
-def test_fused_step_counts_attention_grid(monkeypatch):
-    """Every fused step adds the ragged kernel's grid steps and live steps
-    for its launch (kernels/ragged_paged_attention.py grid_steps, one
-    full-attention layer) to attn_grid_steps / attn_live_steps."""
-    from repro.kernels.ragged_paged_attention import grid_steps
-    cfg = get_config("llama3.2-1b", tiny=True)
+def _check_attention_grid_counts(monkeypatch, arch):
+    """Runs the agent workload on ``arch`` and checks that every fused
+    step adds its launch's brute-force grid and live steps; returns the
+    attention config of the layer counted."""
+    from attn_grid import brute_force_steps
+    cfg = get_config(arch, tiny=True)
     eng = Engine(cfg, POLICIES["infercept"], page_size=16, n_pages=128,
                  max_model_len=256, seed=0, paged=True, fused=True)
-    a = cfg.groups[0].period[0].attn
+    layers = [b.attn for g in cfg.groups for b in g.period if b.attn]
+    full = [a for a in layers if a.sliding_window is None]
+    a = (full or layers)[0]
     seen = []
     real = eng._dispatch_mixed
 
@@ -493,12 +495,14 @@ def test_fused_step_counts_attention_grid(monkeypatch):
         l0 = eng.counters["attn_live_steps"]
         out = real(plan)
         if out is not None:
-            spans = [(start, n) for _, _, start, n, _ in out[0]]
-            want = grid_steps(
-                spans, Engine._bucket(sum(n for _, n in spans)),
+            spans = [(b, start, n)
+                     for b, (_, _, start, n, _) in enumerate(out[0])]
+            want = brute_force_steps(
+                spans, Engine._bucket(sum(n for *_, n in spans)),
                 Engine._bucket(len(spans)), Hkv=a.n_kv_heads,
                 G=a.n_heads // a.n_kv_heads, hd=a.head_dim, page=16,
-                max_pages=eng.max_pages, q_bytes=4, kv_bytes=4)
+                max_pages=eng.max_pages, q_bytes=4, kv_bytes=4,
+                window=a.sliding_window)
             assert (eng.counters["attn_grid_steps"] - g0,
                     eng.counters["attn_live_steps"] - l0) == want
             seen.append(want)
@@ -511,3 +515,18 @@ def test_fused_step_counts_attention_grid(monkeypatch):
     assert seen and all(0 < live <= grid for grid, live in seen)
     assert eng.counters["attn_grid_steps"] == sum(g for g, _ in seen)
     assert eng.counters["attn_live_steps"] == sum(lv for _, lv in seen)
+    return a
+
+
+def test_fused_step_counts_attention_grid(monkeypatch):
+    """Every fused step adds the ragged kernel's grid steps and live steps
+    for its launch to attn_grid_steps / attn_live_steps: a brute-force
+    count over the step's rows (tests/attn_grid.py)."""
+    _check_attention_grid_counts(monkeypatch, "llama3.2-1b")
+
+
+def test_fused_step_counts_full_attention_layer(monkeypatch):
+    """Gemma2 alternates windowed and full layers, windowed first: the
+    counters count a full-attention layer's launch."""
+    a = _check_attention_grid_counts(monkeypatch, "gemma2-9b")
+    assert a.sliding_window is None

@@ -66,7 +66,14 @@ WIDTHS = {
     "llama-3.2-1b": (N_TOK, HKV, G, HD, MAX_PAGES, N_PAGES, N_SEQ),
     "deepseek-7b-pp2": (512, 32, 1, 128, 256, 960, 32),
     "qwen2-72b-pp20": (512, 8, 8, 128, 512, 10240, 256),
+    # Gemma2-9B: heads wider than one lane width, a window and a softcap
+    "gemma2-9b": (256, 8, 2, 256, 256, 512, 32),
+    # an odd group with wide heads: the VMEM budget holds T at its floor
+    # of 16 rows, T·G = 48 on bf16's sublane tiling
+    "odd-group": (512, 32, 3, 256, 256, 960, 32),
 }
+# (window, softcap) where the widths' model has them
+WINDOWS = {"gemma2-9b": (4096, 50.0)}
 
 
 def _custom_call_names(hlo):
@@ -83,12 +90,16 @@ def _custom_call_names(hlo):
     pytest.param("deepseek-7b-pp2", jnp.int8, id="deepseek-7b-pp2-int8"),
     pytest.param("qwen2-72b-pp20", jnp.bfloat16, id="qwen2-72b-pp20-bf16"),
     pytest.param("qwen2-72b-pp20", jnp.int8, id="qwen2-72b-pp20-int8"),
+    pytest.param("gemma2-9b", jnp.bfloat16, id="gemma2-9b-bf16"),
+    pytest.param("gemma2-9b", jnp.int8, id="gemma2-9b-int8"),
+    pytest.param("odd-group", jnp.bfloat16, id="odd-group-bf16"),
 ])
 def test_ragged_paged_attention_compiles(one_chip, widths, pool_dtype):
     """The tiled launch compiles, bf16 and int8 pools; every Mosaic kernel
     of the program keeps the name the benchmark's trace reducer finds it
     by."""
     N, Hkv, G_, hd, max_pages, n_pages, B = WIDTHS[widths]
+    window, softcap = WINDOWS.get(widths, (None, None))
     s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
     i32 = jnp.int32
     pool = s((n_pages, PAGE, Hkv, hd), pool_dtype)
@@ -97,8 +108,8 @@ def test_ragged_paged_attention_compiles(one_chip, widths, pool_dtype):
 
     def fn(q, k, v, bt, ts, tp, *sc):
         kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
-        return ragged_paged_attention(q, k, v, bt, ts, tp, interpret=False,
-                                      **kw)
+        return ragged_paged_attention(q, k, v, bt, ts, tp, window=window,
+                                      softcap=softcap, interpret=False, **kw)
 
     hlo = _compile(fn, s((N, Hkv, G_, hd), jnp.bfloat16), pool, pool,
                    s((B, max_pages), i32), s((N,), i32), s((N,), i32),
