@@ -55,6 +55,8 @@ class SchedulerStats:
       recompute_tokens / fresh_tokens / decode_tokens — query-token mix
       swapped_out_tokens / swapped_in_tokens — swap traffic
       discards / preserves / swaps / evictions — pause decisions
+      stall_evictions — waiting requests behind a stalled FCFS head
+        (nothing runs, the head cannot fit) discarded to let it in
       cache_hit_tokens — tokens restored from the prefix cache instead of
         being recomputed (credited via notify_cache_hit; they reduce
         recompute debt)
@@ -407,7 +409,9 @@ class Scheduler:
 
         free = self.gpu_free() - decode_need
 
-        # 3. admission from the waiting queue, FCFS by arrival key.
+        # 3. admission from the waiting queue, FCFS by arrival key. With
+        #    nothing running, step 2 cannot free memory for the head, so
+        #    later waiting holders are evicted instead (stall_evictions).
         sat = self.cost.saturation_tokens
         chunk_budget = max(0, sat - decode_need) if pol.chunked_recompute \
             else None
@@ -425,6 +429,8 @@ class Scheduler:
                 n = min(n, chunk_budget)
             if n > free and self.cache_probe is not None:
                 free += self._reclaim_waiting_credit(req, n - free, now)
+            if not self.running and not plan.chunks:
+                free += self._evict_for_stalled_head(req, n, free, now)
             if n > free:
                 if pol.chunked_recompute and free > 0:
                     n = free
@@ -468,6 +474,32 @@ class Scheduler:
             self._plan_swap_in(plan, budget, free)
 
         return plan
+
+    def _head_fits(self, n: int, free: int) -> bool:
+        """Admission can give the head some of its ``n`` tokens: a chunk
+        of whatever is free under chunked recompute, else all of them."""
+        return free > 0 if self.policy.chunked_recompute else n <= free
+
+    def _evict_for_stalled_head(self, head: Request, n: int, free: int,
+                                now: float) -> int:
+        """Nothing runs and the FCFS head cannot fit: no step would run
+        again, since step 2's eviction needs a running request. Waiting
+        requests behind the head that hold device context (partial
+        chunked prefills, resumed preserved contexts) are discarded to
+        recompute debt, latest arrival first, until the head fits or none
+        is left. Returns the device tokens freed."""
+        freed = 0
+        idx = self.waiting.index(head)
+        for victim in reversed(self.waiting[idx + 1:]):
+            if self._head_fits(n, free + freed):
+                break
+            if victim.device_tokens <= 0:
+                continue
+            freed += victim.device_tokens
+            self._discard(victim, now)
+            victim.decision = ""
+            self.stats.stall_evictions += 1
+        return freed
 
     def _reclaim_waiting_credit(self, head: Request, needed: int,
                                 now: float) -> int:

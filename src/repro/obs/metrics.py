@@ -68,6 +68,12 @@ ENGINE_COUNTER_SCHEMA: Dict[str, float] = {
     # steps and those that move a live KV block
     # (kernels/ragged_paged_attention.py grid_steps)
     "attn_grid_steps": 0, "attn_live_steps": 0,
+    # bucket padding of each model dispatch: the token rows computed,
+    # those that pad the power-of-two bucket, and padded sequence rows
+    "step_tokens": 0, "pad_tokens": 0, "pad_rows": 0,
+    # requests refused at intake because the prompt alone exceeds
+    # max_model_len (also counted in sessions_rejected)
+    "rejected_too_long": 0,
 }
 
 # the host clock inside Engine.step (obs.trace.HostClock), "host_"
@@ -85,7 +91,7 @@ HOST_COUNTER_SCHEMA: Dict[str, float] = {
 SCHED_COUNTER_SCHEMA: Tuple[str, ...] = (
     "recompute_tokens", "fresh_tokens", "decode_tokens",
     "swapped_out_tokens", "swapped_in_tokens",
-    "discards", "preserves", "swaps", "evictions",
+    "discards", "preserves", "swaps", "evictions", "stall_evictions",
     "cache_hit_tokens", "swap_in_failures", "pool_preempts",
     "cancellations", "tool_failures",
 )

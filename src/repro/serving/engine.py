@@ -250,6 +250,7 @@ class Engine:
         self.params = self.model.init(jax.random.PRNGKey(seed), dtype=dtype)
         self.page = page_size
         # fixed per-request page-table width -> stable jit shapes
+        self.max_model_len = int(max_model_len)
         self.max_pages = -(-max_model_len // page_size)
         self.pools = self.model.init_cache(n_pages, page_size, dtype=dtype,
                                            kv_dtype=kv_dtype)
@@ -507,16 +508,24 @@ class Engine:
     # ------------------------------------------------------------------
     def add_request(self, req: Request) -> bool:
         """Submit a request. Returns False (emitting a RejectedEvent,
-        state untouched) when ``max_queued`` is set and the intake —
-        pending arrivals plus the scheduler's waiting queue — is already
-        at the bound: bounded backpressure instead of unbounded queue
-        growth (DESIGN.md §15). max_queued=None keeps the legacy
+        state untouched) when its prompt alone needs more than
+        ``max_model_len`` positions (reason "too_long": its block-table
+        row could not hold it), or when ``max_queued`` is set and the
+        intake — pending arrivals plus the scheduler's waiting queue — is
+        already at the bound: bounded backpressure instead of unbounded
+        queue growth (DESIGN.md §15). max_queued=None keeps the legacy
         always-accept behavior."""
-        if self.max_queued is not None and \
+        reason = None
+        if req.prompt_len > self.max_model_len:
+            reason = "too_long"
+            self.counters["rejected_too_long"] += 1
+        elif self.max_queued is not None and \
                 (len(self._pending_arrivals) + len(self.sched.waiting)
                  >= self.max_queued):
+            reason = "queue_full"
+        if reason is not None:
             self.counters["sessions_rejected"] += 1
-            self._emit(RejectedEvent(rid=req.rid, reason="queue_full",
+            self._emit(RejectedEvent(rid=req.rid, reason=reason,
                                      time=self.now))
             return False
         # O(log n) search + O(n) shift instead of re-sorting the whole
@@ -1554,6 +1563,7 @@ class Engine:
                 (self.max_pages * self.page + n) * self.kv_token_bytes
         self.counters["prefill_tokens"] += n
         self.counters["device_dispatches"] += 1
+        self._count_padding(n, n_pad, 1, 1)
         st.computed = start + n
         # final chunk of a fresh prefill emits the first generated token —
         # but the logits fetch + host-side sampling are DEFERRED to the
@@ -1613,6 +1623,7 @@ class Engine:
                 * self.kv_token_bytes
         self.counters["decode_tokens"] += B
         self.counters["device_dispatches"] += 1
+        self._count_padding(B, B_pad, B, B_pad)
         # the full B_pad x vocab logits still cross the host boundary (the
         # per-step cost the fused path's on-device sampling removes), but
         # the fetch + sampling are DEFERRED to commit so dispatch stays
@@ -1622,6 +1633,15 @@ class Engine:
             ("decode", list(reqs), logits, [int(p) for p in pos[:B]]))
         for st, p in zip(sts, pos[:B]):
             st.computed = int(p) + 1
+
+    def _count_padding(self, tokens: int, tokens_pad: int, rows: int,
+                       rows_pad: int):
+        """A dispatch's bucket padding: the token rows it computes
+        (``step_tokens``), those that are padding (``pad_tokens``), and
+        its padded sequence rows (``pad_rows``). Host arithmetic only."""
+        self.counters["step_tokens"] += tokens_pad
+        self.counters["pad_tokens"] += tokens_pad - tokens
+        self.counters["pad_rows"] += rows_pad - rows
 
     def _dispatch_mixed(self, plan):
         """Fused mixed-batch iteration (DESIGN.md §10): flatten every chunk
@@ -1691,15 +1711,16 @@ class Engine:
         # follows the decode bucket when one exists (the unfused decode
         # counts its padded batch the same way), else prefill.
         mla_gather = self.max_pages * self.page * self.kv_mla_token_bytes
-        pad_rows = N_pad - total
+        pad_tokens = N_pad - total
         self.counters["prefill_bytes"] += n_chunk * self.kv_token_bytes \
-            + (n_chunk + (0 if n_dec else pad_rows)) * mla_gather
+            + (n_chunk + (0 if n_dec else pad_tokens)) * mla_gather
         self.counters["prefill_tokens"] += n_chunk
         # O(1) appends per generated token otherwise
         self.counters["decode_bytes"] += n_dec * self.kv_token_bytes \
-            + (n_dec + (pad_rows if n_dec else 0)) * mla_gather
+            + (n_dec + (pad_tokens if n_dec else 0)) * mla_gather
         self.counters["decode_tokens"] += n_dec
         self.counters["device_dispatches"] += 1
+        self._count_padding(total, N_pad, B, B_pad)
         if self._attn_launch is not None:
             grid, live = grid_steps(
                 [(start, n) for _, _, start, n, _ in entries], N_pad, B_pad,

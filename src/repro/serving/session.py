@@ -110,9 +110,10 @@ class CancelledEvent:
 @dataclasses.dataclass(frozen=True)
 class RejectedEvent:
     """Admission control refused the session at submit: bounded intake is
-    full (backpressure). Nothing was allocated; resubmit later."""
+    full (backpressure; resubmit later), or the prompt alone exceeds the
+    engine's max_model_len. Nothing was allocated."""
     rid: int
-    reason: str           # e.g. "queue_full"
+    reason: str           # "queue_full" | "too_long"
     time: float
 
 
@@ -355,7 +356,7 @@ class InferCeptClient:
         self.handles[rid] = handle
         self.engine.host_clock.lap(("session", rid))
         if not self.engine.add_request(req):
-            # admission backpressure: the RejectedEvent already routed
+            # refused at admission: the RejectedEvent already routed
             # through the sink (handle.state == "rejected"); nothing was
             # allocated engine-side, so drop the dead handle mapping
             self.engine.spec_log.pop(rid, None)
