@@ -91,13 +91,15 @@ def check(ok: bool, what: str):
 
 def check_kernels(seed: int, *, Hkv=8, G=4, hd=64, page=PAGE,
                   max_pages=64, interpret=False):
-    """Phase 3: the two served kernels against their references."""
+    """Phase 3: the two served kernels against their references, on
+    layer 1 of pools stacked over two layers (the model's layout)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    B, N = 4, 64
+    B, N, L, layer = 4, 64, 2, 1
     n_pages = B * max_pages + 1                     # page 0: discard page
     bf = jnp.bfloat16
-    k_pool = jax.random.normal(ks[0], (n_pages, page, Hkv, hd)).astype(bf)
-    v_pool = jax.random.normal(ks[1], (n_pages, page, Hkv, hd)).astype(bf)
+    shape = (L, n_pages, page, Hkv, hd)
+    k_pool = jax.random.normal(ks[0], shape).astype(bf)
+    v_pool = jax.random.normal(ks[1], shape).astype(bf)
     q = jax.random.normal(ks[2], (N, Hkv, G, hd)).astype(bf)
     bt = 1 + np.random.default_rng(seed).permutation(
         B * max_pages).reshape(B, max_pages)
@@ -115,9 +117,10 @@ def check_kernels(seed: int, *, Hkv=8, G=4, hd=64, page=PAGE,
     seq += [0] * (N - real)
     pos += [-1] * (N - real)
     bt, ts, tp = (jnp.asarray(a, jnp.int32) for a in (bt, seq, pos))
-    got = ragged_paged_attention(q, k_pool, v_pool, bt, ts, tp,
+    got = ragged_paged_attention(q, k_pool, v_pool, layer, bt, ts, tp,
                                  interpret=interpret)
-    want = ref.ragged_paged_attention_ref(q, k_pool, v_pool, bt, ts, tp)
+    want = ref.ragged_paged_attention_ref(q, k_pool, v_pool, layer, bt, ts,
+                                          tp)
     err = float(jnp.max(jnp.abs(got[:real].astype(jnp.float32)
                                 - want[:real].astype(jnp.float32))))
     print(f"kernel ragged_paged_attention: max|delta|={err:.6g} "
@@ -133,15 +136,17 @@ def check_kernels(seed: int, *, Hkv=8, G=4, hd=64, page=PAGE,
     offs = (slots % page).astype(np.int32)
     k_new = jax.random.normal(ks[3], (N, Hkv, hd)).astype(bf)
     v_new = jax.random.normal(ks[4], (N, Hkv, hd)).astype(bf)
-    args = (k_new, v_new, jnp.asarray(pids), jnp.asarray(offs),
+    args = (layer, k_new, v_new, jnp.asarray(pids), jnp.asarray(offs),
             jnp.asarray(valid))
     want_k, want_v = ref.kv_append_ref(k_pool, v_pool, *args)
     # kv_append donates its pools: give it copies, keep the originals
     got_k, got_v = kv_append(k_pool.copy(), v_pool.copy(), *args,
                              interpret=interpret)
-    err = max(float(jnp.max(jnp.abs(g[1:].astype(jnp.float32)
-                                    - w[1:].astype(jnp.float32))))
-              for g, w in ((got_k, want_k), (got_v, want_v)))
+    # every page but layer 1's discard page, layer 0 whole
+    err = max(float(jnp.max(jnp.abs(g[i, lo:].astype(jnp.float32)
+                                    - w[i, lo:].astype(jnp.float32))))
+              for g, w in ((got_k, want_k), (got_v, want_v))
+              for i, lo in ((0, 0), (layer, 1)))
     print(f"kernel kv_append: max|delta|={err:.6g} (tol 0, exact) over "
           f"{int(valid.sum())} live rows")
     check(err == 0.0, f"kv_append differs from its reference by {err}")

@@ -20,8 +20,15 @@ dedicated discard page makes the stale rewrite harmless by construction
 (the discard page holds garbage; several invalid rows aliasing it are fine
 — the TPU grid is sequential).
 
-Grid: (n_rows,); page id / offset / valid flag are scalar-prefetch operands
-so the DMA destination of row i is known while row i-1 is in flight.
+The pools are the model's stacked ones, (L, n_pages, page, Hkv, hd), one
+slab per layer of a layer group, and a call writes layer ``layer`` of them:
+the layer index is a scalar-prefetch operand of every slot's index map, so
+the layer scan carries the whole stack through each layer's call in place
+and no layer's pool is ever sliced out or copied (DESIGN.md §9).
+
+Grid: (n_rows,); the layer, page id / offset / valid flag are
+scalar-prefetch operands so the DMA destination of row i is known while row
+i-1 is in flight.
 """
 from __future__ import annotations
 
@@ -33,9 +40,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _append_kernel(page_ids, offsets, valid,            # scalar prefetch
+def _append_kernel(layer, page_ids, offsets, valid,     # scalar prefetch
                    k_pool_ref, v_pool_ref, k_ref, v_ref, k_out, v_out):
-    del page_ids, offsets
+    del layer, page_ids, offsets
     n = pl.program_id(0)
 
     @pl.when(valid[n] != 0)
@@ -54,13 +61,14 @@ def _append_kernel(page_ids, offsets, valid,            # scalar prefetch
 # (the caller must not touch the arrays it passed in again)
 @functools.partial(jax.jit, static_argnames=("interpret",),
                    donate_argnums=(0, 1))
-def kv_append(k_pool, v_pool, k_new, v_new, page_ids, offsets, valid, *,
-              interpret=None):
-    """Scatter new K/V rows into their pool page slots.
+def kv_append(k_pool, v_pool, layer, k_new, v_new, page_ids, offsets, valid,
+              *, interpret=None):
+    """Scatter new K/V rows into their pool page slots of one layer.
 
-    k_pool/v_pool: (n_pages, page, Hkv, hd); k_new/v_new: (N, Hkv, hd);
-    page_ids/offsets/valid: (N,) int32. Row i writes k_new[i]/v_new[i] into
-    pool slot (page_ids[i], offsets[i]) iff valid[i] != 0; invalid rows
+    k_pool/v_pool: (L, n_pages, page, Hkv, hd) stacked over layers; layer:
+    int32 scalar; k_new/v_new: (N, Hkv, hd); page_ids/offsets/valid: (N,)
+    int32. Row i writes k_new[i]/v_new[i] into pool slot (layer,
+    page_ids[i], offsets[i]) iff valid[i] != 0; invalid rows
     have their K/V discarded, but their (page_ids[i], offsets[i]) is still
     the DMA target and MUST name a write-discard page no valid row of the
     same call writes (see module docstring). Returns the updated
@@ -69,33 +77,34 @@ def kv_append(k_pool, v_pool, k_new, v_new, page_ids, offsets, valid, *,
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     N = k_new.shape[0]
-    _, _, Hkv, hd = k_pool.shape
+    *_, Hkv, hd = k_pool.shape
 
-    def slot(n, ids, offs, val):
+    def slot(n, lyr, ids, offs, val):
         del val
-        return (ids[n], offs[n], 0, 0)
+        return (lyr[0], ids[n], offs[n], 0, 0)
 
-    def row(n, ids, offs, val):
-        del ids, offs, val
+    def row(n, lyr, ids, offs, val):
+        del lyr, ids, offs, val
         return (n, 0, 0)
 
+    pool_block = pl.BlockSpec((None, 1, 1, Hkv, hd), slot)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(N,),
         in_specs=[
-            pl.BlockSpec((1, 1, Hkv, hd), slot),     # k_pool (read-back)
-            pl.BlockSpec((1, 1, Hkv, hd), slot),     # v_pool (read-back)
+            pool_block,                              # k_pool (read-back)
+            pool_block,                              # v_pool (read-back)
             pl.BlockSpec((1, Hkv, hd), row),         # k_new
             pl.BlockSpec((1, Hkv, hd), row),         # v_new
         ],
-        out_specs=[pl.BlockSpec((1, 1, Hkv, hd), slot),
-                   pl.BlockSpec((1, 1, Hkv, hd), slot)],
+        out_specs=[pool_block, pool_block],
     )
     return pl.pallas_call(
         _append_kernel, grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)),
-        input_output_aliases={3: 0, 4: 1},   # pools flow through in place
+        input_output_aliases={4: 0, 5: 1},   # pools flow through in place
         interpret=interpret,
         name="kv_append",
-    )(page_ids, offsets, valid, k_pool, v_pool, k_new, v_new)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), page_ids, offsets, valid,
+      k_pool, v_pool, k_new, v_new)

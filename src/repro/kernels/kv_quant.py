@@ -22,6 +22,10 @@ existing slot-granular ``kv_append`` kernel is reused unchanged:
   C. row write: the new rows, quantized with the updated scales, go
      through the ordinary ``kv_append`` scatter (it is dtype-generic).
 
+Pools and scales are the model's stacked ones (a leading layer axis) and a
+call touches one layer of them, named by a scalar-prefetch layer index as
+in ``kv_append``; only that layer's (n_pages, Hkv) scales are sliced out.
+
 fp8 casts in XLA saturate to NaN on overflow, so every quantize/requant
 clips to ±qmax BEFORE the cast; int8 rounds with ``jnp.rint`` (ties to
 even) then clips. A zero scale means "page holds nothing" — safe-divide
@@ -118,9 +122,10 @@ def first_occurrence(pids_drop):
 # --------------------------------------------------------------------------
 # Phase B Pallas kernel: whole-page requant, page id as scalar prefetch
 # --------------------------------------------------------------------------
-def _requant_kernel(rpids, k_pool_ref, v_pool_ref, k_ratio_ref, v_ratio_ref,
-                    k_out, v_out, *, qmax: float, integer: bool):
-    del rpids
+def _requant_kernel(layer, rpids, k_pool_ref, v_pool_ref, k_ratio_ref,
+                    v_ratio_ref, k_out, v_out, *, qmax: float,
+                    integer: bool):
+    del layer, rpids
 
     def scale_page(pool_ref, ratio_ref, out_ref):
         y = pool_ref[0].astype(jnp.float32) * ratio_ref[0][None]
@@ -134,88 +139,93 @@ def _requant_kernel(rpids, k_pool_ref, v_pool_ref, k_ratio_ref, v_ratio_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",),
                    donate_argnums=(0, 1))
-def page_requant(k_pool, v_pool, k_ratio, v_ratio, rpids, *, interpret=None):
-    """Rescale whole pages in place: page rpids[i] gets payload *=
-    ratio[i] (re-rounded / re-cast). Rows routed to a write-discard page
-    (duplicate occurrences, invalid rows) clobber only that page.
-    k_pool/v_pool: (n_pages, page, Hkv, hd) quantized, donated;
-    k_ratio/v_ratio: (N, Hkv) f32; rpids: (N,) int32."""
+def page_requant(k_pool, v_pool, layer, k_ratio, v_ratio, rpids, *,
+                 interpret=None):
+    """Rescale whole pages of one layer in place: page rpids[i] gets
+    payload *= ratio[i] (re-rounded / re-cast). Rows routed to a
+    write-discard page (duplicate occurrences, invalid rows) clobber only
+    that page. k_pool/v_pool: (L, n_pages, page, Hkv, hd) quantized,
+    donated; layer: int32 scalar; k_ratio/v_ratio: (N, Hkv) f32; rpids:
+    (N,) int32."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     N = rpids.shape[0]
-    _, page, Hkv, hd = k_pool.shape
+    *_, page, Hkv, hd = k_pool.shape
     qmax = kv_quant_qmax(k_pool.dtype)
     integer = jnp.issubdtype(k_pool.dtype, jnp.integer)
 
-    def slot(n, ids):
-        return (ids[n], 0, 0, 0)
+    def slot(n, lyr, ids):
+        return (lyr[0], ids[n], 0, 0, 0)
 
-    def row(n, ids):
-        del ids
+    def row(n, lyr, ids):
+        del lyr, ids
         return (n, 0, 0)
 
+    page_block = pl.BlockSpec((None, 1, page, Hkv, hd), slot)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(N,),
         in_specs=[
-            pl.BlockSpec((1, page, Hkv, hd), slot),   # k_pool (read-back)
-            pl.BlockSpec((1, page, Hkv, hd), slot),   # v_pool (read-back)
+            page_block,                               # k_pool (read-back)
+            page_block,                               # v_pool (read-back)
             # ratios as (N, Hkv, 1): one legal (Hkv, 1) block per row
             pl.BlockSpec((1, Hkv, 1), row),           # k_ratio
             pl.BlockSpec((1, Hkv, 1), row),           # v_ratio
         ],
-        out_specs=[pl.BlockSpec((1, page, Hkv, hd), slot),
-                   pl.BlockSpec((1, page, Hkv, hd), slot)],
+        out_specs=[page_block, page_block],
     )
     kernel = functools.partial(_requant_kernel, qmax=qmax, integer=integer)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)),
-        input_output_aliases={1: 0, 2: 1},   # pools flow through in place
+        input_output_aliases={2: 0, 3: 1},   # pools flow through in place
         interpret=interpret,
         name="page_requant",
-    )(rpids, k_pool, v_pool, k_ratio[..., None], v_ratio[..., None])
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), rpids, k_pool, v_pool,
+      k_ratio[..., None], v_ratio[..., None])
 
 
-def kv_append_quant(k_pool, v_pool, k_scale, v_scale, k_new, v_new,
+def kv_append_quant(k_pool, v_pool, k_scale, v_scale, layer, k_new, v_new,
                     page_ids, offsets, valid, discard_pid, *,
                     interpret=None):
-    """Quantized scatter of new K/V rows (Pallas path).
+    """Quantized scatter of new K/V rows into one layer (Pallas path).
 
-    Pools: (n_pages, page, Hkv, hd) quantized; scales: (n_pages, Hkv)
-    f32; rows as in kv_append. ``discard_pid`` MUST name a write-discard
-    page (kv_append contract). Returns (k_pool, v_pool, k_scale,
-    v_scale)."""
+    Pools: (L, n_pages, page, Hkv, hd) quantized; scales: (L, n_pages,
+    Hkv) f32; layer: int32 scalar; rows as in kv_append. ``discard_pid``
+    MUST name a write-discard page (kv_append contract). Returns (k_pool,
+    v_pool, k_scale, v_scale)."""
     from repro.kernels.kv_append import kv_append
-    n_pages = k_pool.shape[0]
+    n_pages = k_pool.shape[1]
     qmax = kv_quant_qmax(k_pool.dtype)
     live = valid != 0
     pids_drop = jnp.where(live, page_ids, n_pages)       # OOB -> dropped
+    k_scale_l, v_scale_l = k_scale[layer], v_scale[layer]
     new_k_scale, new_v_scale = updated_page_scales(
-        k_scale, v_scale, k_new, v_new, pids_drop, qmax)
+        k_scale_l, v_scale_l, k_new, v_new, pids_drop, qmax)
 
     # phase B: one requant per touched page; duplicates/invalids -> discard
     first = first_occurrence(pids_drop)
     rpids = jnp.where(live & first, page_ids, discard_pid).astype(jnp.int32)
     gidx = jnp.clip(pids_drop, 0, n_pages - 1)
     k_ratio = jnp.where(new_k_scale[gidx] > 0,
-                        k_scale[gidx] / jnp.where(new_k_scale[gidx] > 0,
-                                                  new_k_scale[gidx], 1.0),
+                        k_scale_l[gidx] / jnp.where(new_k_scale[gidx] > 0,
+                                                    new_k_scale[gidx], 1.0),
                         0.0)
     v_ratio = jnp.where(new_v_scale[gidx] > 0,
-                        v_scale[gidx] / jnp.where(new_v_scale[gidx] > 0,
-                                                  new_v_scale[gidx], 1.0),
+                        v_scale_l[gidx] / jnp.where(new_v_scale[gidx] > 0,
+                                                    new_v_scale[gidx], 1.0),
                         0.0)
-    k_pool, v_pool = page_requant(k_pool, v_pool, k_ratio, v_ratio, rpids,
-                                  interpret=interpret)
+    k_pool, v_pool = page_requant(k_pool, v_pool, layer, k_ratio, v_ratio,
+                                  rpids, interpret=interpret)
 
     # phase C: quantize the rows with the post-update scales and reuse the
     # slot-granular append kernel (dtype-generic; invalid rows discard)
     qk = quantize_rows(k_new, new_k_scale[gidx], k_pool.dtype)
     qv = quantize_rows(v_new, new_v_scale[gidx], v_pool.dtype)
     wpids = jnp.where(live, page_ids, discard_pid).astype(jnp.int32)
-    k_pool, v_pool = kv_append(k_pool, v_pool, qk, qv, wpids,
+    k_pool, v_pool = kv_append(k_pool, v_pool, layer, qk, qv, wpids,
                                offsets.astype(jnp.int32),
                                valid.astype(jnp.int32), interpret=interpret)
-    return k_pool, v_pool, new_k_scale, new_v_scale
+    return (k_pool, v_pool, k_scale.at[layer].set(new_k_scale),
+            v_scale.at[layer].set(new_v_scale))

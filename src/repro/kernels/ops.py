@@ -51,73 +51,76 @@ def flash_attention_op(q, k, v, *, causal=True, window=None, softcap=None,
                                    softcap=softcap)
 
 
-def paged_attention_op(q, k_pool, v_pool, block_tables, ctx_lens, *,
+def paged_attention_op(q, k_pool, v_pool, layer, block_tables, ctx_lens, *,
                        k_scale=None, v_scale=None,
                        softcap=None, window=None, use_pallas=None,
                        interpret=None):
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas:
-        return paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
-                               k_scale=k_scale, v_scale=v_scale,
+        return paged_attention(q, k_pool, v_pool, layer, block_tables,
+                               ctx_lens, k_scale=k_scale, v_scale=v_scale,
                                softcap=softcap, window=window,
                                interpret=interpret)
     if k_scale is not None:
         return ref.paged_attention_quant_ref(
-            q, k_pool, v_pool, k_scale, v_scale, block_tables, ctx_lens,
-            softcap=softcap, window=window)
-    return ref.paged_attention_ref(q, k_pool, v_pool, block_tables, ctx_lens,
-                                   softcap=softcap, window=window)
+            q, k_pool, v_pool, k_scale, v_scale, layer, block_tables,
+            ctx_lens, softcap=softcap, window=window)
+    return ref.paged_attention_ref(q, k_pool, v_pool, layer, block_tables,
+                                   ctx_lens, softcap=softcap, window=window)
 
 
-def ragged_paged_attention_op(q, k_pool, v_pool, block_tables, tok_seq,
-                              tok_pos, *, k_scale=None, v_scale=None,
-                              softcap=None, window=None,
+def ragged_paged_attention_op(q, k_pool, v_pool, layer, block_tables,
+                              tok_seq, tok_pos, *, k_scale=None,
+                              v_scale=None, softcap=None, window=None,
                               use_pallas=None, interpret=None):
-    """Mixed-batch ragged-query attention (chunk + decode tokens flattened)."""
+    """Mixed-batch ragged-query attention (chunk + decode tokens flattened)
+    over layer ``layer`` of the stacked pools."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas:
-        return ragged_paged_attention(q, k_pool, v_pool, block_tables,
+        return ragged_paged_attention(q, k_pool, v_pool, layer, block_tables,
                                       tok_seq, tok_pos, k_scale=k_scale,
                                       v_scale=v_scale, softcap=softcap,
                                       window=window, interpret=interpret)
     if k_scale is not None:
         return ref.ragged_paged_attention_quant_ref(
-            q, k_pool, v_pool, k_scale, v_scale, block_tables, tok_seq,
-            tok_pos, softcap=softcap, window=window)
-    return ref.ragged_paged_attention_ref(q, k_pool, v_pool, block_tables,
-                                          tok_seq, tok_pos, softcap=softcap,
-                                          window=window)
+            q, k_pool, v_pool, k_scale, v_scale, layer, block_tables,
+            tok_seq, tok_pos, softcap=softcap, window=window)
+    return ref.ragged_paged_attention_ref(q, k_pool, v_pool, layer,
+                                          block_tables, tok_seq, tok_pos,
+                                          softcap=softcap, window=window)
 
 
-def kv_append_op(k_pool, v_pool, k_new, v_new, page_ids, offsets, valid, *,
-                 use_pallas=None, interpret=None):
-    """In-place scatter of new token K/V rows into pool page slots."""
+def kv_append_op(k_pool, v_pool, layer, k_new, v_new, page_ids, offsets,
+                 valid, *, use_pallas=None, interpret=None):
+    """In-place scatter of new token K/V rows into pool page slots of layer
+    ``layer`` of the stacked pools."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas:
-        return kv_append(k_pool, v_pool, k_new, v_new, page_ids, offsets,
-                         valid, interpret=interpret)
-    return ref.kv_append_ref(k_pool, v_pool, k_new, v_new, page_ids,
+        return kv_append(k_pool, v_pool, layer, k_new, v_new, page_ids,
+                         offsets, valid, interpret=interpret)
+    return ref.kv_append_ref(k_pool, v_pool, layer, k_new, v_new, page_ids,
                              offsets, valid)
 
 
-def kv_append_quant_op(k_pool, v_pool, k_scale, v_scale, k_new, v_new,
-                       page_ids, offsets, valid, *, discard_pid=None,
+def kv_append_quant_op(k_pool, v_pool, k_scale, v_scale, layer, k_new,
+                       v_new, page_ids, offsets, valid, *, discard_pid=None,
                        use_pallas=None, interpret=None):
     """Quantized in-place scatter of new token K/V rows + per-page scale
-    update (requantize-on-append; DESIGN.md §17). ``discard_pid`` is
-    required on the Pallas path (kv_append's write-discard contract);
-    the XLA path drops invalid rows by OOB scatter and ignores it."""
+    update into layer ``layer`` (requantize-on-append; DESIGN.md §17).
+    ``discard_pid`` is required on the Pallas path (kv_append's
+    write-discard contract); the XLA path drops invalid rows by OOB
+    scatter and ignores it."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas:
-        return kv_append_quant(k_pool, v_pool, k_scale, v_scale, k_new,
-                               v_new, page_ids, offsets, valid, discard_pid,
-                               interpret=interpret)
-    return ref.kv_append_quant_ref(k_pool, v_pool, k_scale, v_scale, k_new,
-                                   v_new, page_ids, offsets, valid)
+        return kv_append_quant(k_pool, v_pool, k_scale, v_scale, layer,
+                               k_new, v_new, page_ids, offsets, valid,
+                               discard_pid, interpret=interpret)
+    return ref.kv_append_quant_ref(k_pool, v_pool, k_scale, v_scale, layer,
+                                   k_new, v_new, page_ids, offsets, valid)
 
 
 def swap_pack_op(pool, page_ids, *, use_pallas=None, interpret=None):

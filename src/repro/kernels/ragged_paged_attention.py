@@ -18,8 +18,11 @@ ctx_lens) is the special case tok_seq = arange(B), tok_pos = ctx_lens - 1.
 Padded token rows carry ``tok_pos[i] == -1``: their output is zeros and the
 caller ignores it.
 
-Layout: q (N, Hkv, G, hd); pools (n_pages, page, Hkv, hd);
-block_tables (B, max_pages) int32; tok_seq/tok_pos (N,) int32.
+Layout: q (N, Hkv, G, hd); pools (L, n_pages, page, Hkv, hd), the model's
+stack over one layer group's layers, of which the call reads layer
+``layer`` (an int32 scalar-prefetch operand that every page's index map
+leads with, so the stack is addressed in place and no layer's pool is
+sliced out); block_tables (B, max_pages) int32; tok_seq/tok_pos (N,) int32.
 
 Iteration space: query tiles × KV blocks.
 
@@ -214,10 +217,10 @@ def _staged_rows(ref, h, S, Hkv):
     return jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
 
 
-def _ragged_kernel(bt_ref, meta_ref,        # scalar-prefetch operands
+def _ragged_kernel(lyr_ref, bt_ref, meta_ref,   # scalar-prefetch operands
                    q_ref, pos_ref, *refs, P, page, Hkv, G, n_tiles,
                    softcap, scale, window, quant, dot_dtype):
-    del bt_ref
+    del lyr_ref, bt_ref
     kr, vr = refs[:P], refs[P:2 * P]
     if quant:
         ksr, vsr = refs[2 * P:3 * P], refs[3 * P:4 * P]
@@ -301,21 +304,22 @@ def _ragged_kernel(bt_ref, meta_ref,        # scalar-prefetch operands
 @functools.partial(jax.jit,
                    static_argnames=("softcap", "scale", "window",
                                     "interpret"))
-def ragged_paged_attention(q, k_pool, v_pool, block_tables, tok_seq,
+def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, tok_seq,
                            tok_pos, *, k_scale=None, v_scale=None,
                            softcap=None, scale=None, window=None,
                            interpret=None):
     """q: (N, Hkv, G, hd) flat mixed-batch query tokens; pools:
-    (n_pages, page, Hkv, hd); block_tables: (B, max_pages); tok_seq (N,)
-    int32 names each token's sequence (block-table row); tok_pos (N,) int32
-    is its absolute position (-1 marks a padded token row — output zeros).
-    ``window`` (static) keeps only the last ``window`` positions visible.
-    ``k_scale``/``v_scale`` (n_pages, Hkv) f32 dequantize low-bit pools
+    (L, n_pages, page, Hkv, hd), of which layer ``layer`` (int32 scalar) is
+    read; block_tables: (B, max_pages); tok_seq (N,) int32 names each
+    token's sequence (block-table row); tok_pos (N,) int32 is its absolute
+    position (-1 marks a padded token row — output zeros). ``window``
+    (static) keeps only the last ``window`` positions visible.
+    ``k_scale``/``v_scale`` (L, n_pages, Hkv) f32 dequantize low-bit pools
     in-register (both set or both None). Returns (N, Hkv, G, hd)."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     N, Hkv, G, hd = q.shape
-    n_pages, page, _, _ = k_pool.shape
+    L, n_pages, page, _, _ = k_pool.shape
     B, max_pages = block_tables.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     quant = k_scale is not None
@@ -343,30 +347,32 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, tok_seq,
     row_pos = jnp.repeat(jnp.pad(pos_s, (0, Nq - N), constant_values=-1),
                          G)[:, None]
 
-    def q_map(t, i, bt, mt):
+    def q_map(t, i, lyr, bt, mt):
         return (0, mt[_QB * n_tiles + t], 0)
 
     def page_map(j):
-        def index(t, i, bt, mt):
+        def index(t, i, lyr, bt, mt):
             b = jnp.minimum(mt[_FIRST * n_tiles + t] + i,
                             mt[_LAST * n_tiles + t])
             col = jnp.minimum(b * P + j, max_pages - 1)
-            return (bt[mt[_SEQ * n_tiles + t], col], 0, 0)
+            return (lyr[0], bt[mt[_SEQ * n_tiles + t], col], 0, 0)
         return index
 
     q_spec = pl.BlockSpec((Hkv, T * G, hd), q_map)
     in_specs = [q_spec,
                 pl.BlockSpec((T * G, 1),
-                             lambda t, i, bt, mt: (mt[_QB * n_tiles + t], 0))]
-    page_specs = [pl.BlockSpec((1, page * Hkv, hd), page_map(j))
+                             lambda t, i, lyr, bt, mt:
+                             (mt[_QB * n_tiles + t], 0))]
+    # the layer axis is squeezed: the kernel sees one page of one layer
+    page_specs = [pl.BlockSpec((None, 1, page * Hkv, hd), page_map(j))
                   for j in range(P)]
     in_specs += page_specs + page_specs
     operands = [qs, row_pos]
-    operands += [k_pool.reshape(n_pages, page * Hkv, hd)] * P
-    operands += [v_pool.reshape(n_pages, page * Hkv, hd)] * P
+    operands += [k_pool.reshape(L, n_pages, page * Hkv, hd)] * P
+    operands += [v_pool.reshape(L, n_pages, page * Hkv, hd)] * P
     if quant:
-        # (n_pages, Hkv, 1): a page's scales as one legal (Hkv, 1) block
-        scale_specs = [pl.BlockSpec((1, Hkv, 1), page_map(j))
+        # (L, n_pages, Hkv, 1): a page's scales as one legal (Hkv, 1) block
+        scale_specs = [pl.BlockSpec((None, 1, Hkv, 1), page_map(j))
                        for j in range(P)]
         in_specs += scale_specs + scale_specs
         operands += [k_scale[..., None]] * P + [v_scale[..., None]] * P
@@ -382,7 +388,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, tok_seq,
         scratch += [pltpu.VMEM((hd // w * P * page * Hkv, w),
                                jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(n_tiles, nb),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -396,7 +402,8 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, tok_seq,
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="ragged_paged_attention",
-    )(block_tables.astype(i32), meta, *operands)
+    )(jnp.reshape(layer, (1,)).astype(i32), block_tables.astype(i32), meta,
+      *operands)
     out = out.reshape(Hkv, Nq, G, hd).transpose(1, 0, 2, 3)[:N]
     inv = jnp.zeros((N,), i32).at[order].set(jnp.arange(N, dtype=i32))
     out = out[inv]

@@ -1,4 +1,9 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
+"""Pure-jnp oracles for every Pallas kernel (the allclose targets).
+
+The paged oracles take the kernels' stacked pools, (L, n_pages, ...) with a
+leading layer axis, and the layer index they address; the append oracles
+write layer ``layer`` in place (``.at[layer, ...]``), so they also serve as
+the paged path's XLA lowering off the TPU."""
 from __future__ import annotations
 
 import math
@@ -30,12 +35,12 @@ def flash_attention_ref(q, k, v, *, causal=True, window=None,
     return out.astype(q.dtype)
 
 
-def paged_attention_ref(q, kv_pages_k, kv_pages_v, block_tables, ctx_lens, *,
-                        softcap=None, scale=None, window=None,
+def paged_attention_ref(q, kv_pages_k, kv_pages_v, layer, block_tables,
+                        ctx_lens, *, softcap=None, scale=None, window=None,
                         _k=None, _v=None):
-    """Decode attention over a paged KV pool.
+    """Decode attention over layer ``layer`` of a paged KV pool.
 
-    q: (B, Hkv, G, hd); pools: (n_pages, page, Hkv, hd);
+    q: (B, Hkv, G, hd); pools: (L, n_pages, page, Hkv, hd);
     block_tables: (B, max_pages) int32; ctx_lens: (B,) tokens valid.
     ``window`` keeps only the last ``window`` positions of each context.
     ``_k``/``_v`` bypass the pool gather with pre-gathered (B, S, Hkv, hd)
@@ -47,12 +52,14 @@ def paged_attention_ref(q, kv_pages_k, kv_pages_v, block_tables, ctx_lens, *,
         max_pages, page = block_tables.shape[1], k.shape[1] // block_tables.shape[1]
         scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     else:
-        page = kv_pages_k.shape[1]
+        page = kv_pages_k.shape[2]
         max_pages = block_tables.shape[1]
         scale = scale if scale is not None else 1.0 / math.sqrt(hd)
         # gather to (B, max_pages*page, Hkv, hd)
-        k = kv_pages_k[block_tables].reshape(B, max_pages * page, Hkv, hd)
-        v = kv_pages_v[block_tables].reshape(B, max_pages * page, Hkv, hd)
+        k = kv_pages_k[layer, block_tables].reshape(B, max_pages * page,
+                                                    Hkv, hd)
+        v = kv_pages_v[layer, block_tables].reshape(B, max_pages * page,
+                                                    Hkv, hd)
     s = jnp.einsum("bhgd,bshd->bhgs", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if softcap is not None:
@@ -67,12 +74,14 @@ def paged_attention_ref(q, kv_pages_k, kv_pages_v, block_tables, ctx_lens, *,
     return out.astype(q.dtype)
 
 
-def ragged_paged_attention_ref(q, kv_pages_k, kv_pages_v, block_tables,
-                               tok_seq, tok_pos, *, softcap=None, scale=None,
-                               window=None, _k=None, _v=None):
-    """Ragged-query attention over a paged KV pool (mixed-batch oracle).
+def ragged_paged_attention_ref(q, kv_pages_k, kv_pages_v, layer,
+                               block_tables, tok_seq, tok_pos, *,
+                               softcap=None, scale=None, window=None,
+                               _k=None, _v=None):
+    """Ragged-query attention over layer ``layer`` of a paged KV pool
+    (mixed-batch oracle).
 
-    q: (N, Hkv, G, hd) flat tokens; pools: (n_pages, page, Hkv, hd);
+    q: (N, Hkv, G, hd) flat tokens; pools: (L, n_pages, page, Hkv, hd);
     block_tables: (B, max_pages) int32; tok_seq (N,) names each token's
     block-table row; tok_pos (N,) its absolute position (-1 = padded row,
     output garbage). Token i sees kv positions <= tok_pos[i] of its own
@@ -87,11 +96,11 @@ def ragged_paged_attention_ref(q, kv_pages_k, kv_pages_v, block_tables,
         max_pages = block_tables.shape[1]
         page = k.shape[1] // max_pages
     else:
-        page = kv_pages_k.shape[1]
+        page = kv_pages_k.shape[2]
         max_pages = block_tables.shape[1]
         bt = block_tables[tok_seq]                       # (N, max_pages)
-        k = kv_pages_k[bt].reshape(N, max_pages * page, Hkv, hd)
-        v = kv_pages_v[bt].reshape(N, max_pages * page, Hkv, hd)
+        k = kv_pages_k[layer, bt].reshape(N, max_pages * page, Hkv, hd)
+        v = kv_pages_v[layer, bt].reshape(N, max_pages * page, Hkv, hd)
     s = jnp.einsum("bhgd,bshd->bhgs", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if softcap is not None:
@@ -106,27 +115,30 @@ def ragged_paged_attention_ref(q, kv_pages_k, kv_pages_v, block_tables,
     return out.astype(q.dtype)
 
 
-def kv_append_ref(k_pool, v_pool, k_new, v_new, page_ids, offsets, valid):
+def kv_append_ref(k_pool, v_pool, layer, k_new, v_new, page_ids, offsets,
+                  valid):
     """Scatter new K/V rows into pool page slots (kv_append oracle).
 
-    Row i lands at (page_ids[i], offsets[i]) iff valid[i] != 0; invalid
-    rows are dropped entirely (they must never touch any page).
+    Pools: (L, n_pages, page, Hkv, hd). Row i lands at (layer, page_ids[i],
+    offsets[i]) iff valid[i] != 0; invalid rows are dropped entirely (they
+    must never touch any page).
     """
-    n_pages = k_pool.shape[0]
+    n_pages = k_pool.shape[1]
     pids = jnp.where(valid != 0, page_ids, n_pages)      # OOB -> dropped
-    k_pool = k_pool.at[pids, offsets].set(k_new.astype(k_pool.dtype),
-                                          mode="drop")
-    v_pool = v_pool.at[pids, offsets].set(v_new.astype(v_pool.dtype),
-                                          mode="drop")
+    k_pool = k_pool.at[layer, pids, offsets].set(k_new.astype(k_pool.dtype),
+                                                 mode="drop")
+    v_pool = v_pool.at[layer, pids, offsets].set(v_new.astype(v_pool.dtype),
+                                                 mode="drop")
     return k_pool, v_pool
 
 
-def kv_append_quant_ref(k_pool, v_pool, k_scale, v_scale, k_new, v_new,
-                        page_ids, offsets, valid):
-    """Quantized scatter of new K/V rows (kv_append_quant oracle).
+def kv_append_quant_ref(k_pool, v_pool, k_scale, v_scale, layer, k_new,
+                        v_new, page_ids, offsets, valid):
+    """Quantized scatter of new K/V rows into layer ``layer`` (the
+    kv_append_quant oracle).
 
-    Pools: (n_pages, page, Hkv, hd) quantized storage dtype; scales:
-    (n_pages, Hkv) f32. Same three phases as the Pallas composite —
+    Pools: (L, n_pages, page, Hkv, hd) quantized storage dtype; scales:
+    (L, n_pages, Hkv) f32. Same three phases as the Pallas composite —
     monotone per-(page, head) scale update, whole-page requant of every
     touched page, then the row scatter with the new rows quantized at the
     post-update scales — expressed as drop-mode XLA gathers/scatters.
@@ -136,11 +148,12 @@ def kv_append_quant_ref(k_pool, v_pool, k_scale, v_scale, k_new, v_new,
     from repro.kernels.kv_quant import (kv_quant_qmax, quantize_rows,
                                         requant_payload,
                                         updated_page_scales)
-    n_pages = k_pool.shape[0]
+    n_pages = k_pool.shape[1]
     qmax = kv_quant_qmax(k_pool.dtype)
     pids = jnp.where(valid != 0, page_ids, n_pages)      # OOB -> dropped
+    k_scale_l, v_scale_l = k_scale[layer], v_scale[layer]
     new_k_scale, new_v_scale = updated_page_scales(
-        k_scale, v_scale, k_new, v_new, pids, qmax)
+        k_scale_l, v_scale_l, k_new, v_new, pids, qmax)
 
     gidx = jnp.clip(pids, 0, n_pages - 1)
 
@@ -149,18 +162,19 @@ def kv_append_quant_ref(k_pool, v_pool, k_scale, v_scale, k_new, v_new,
         r = jnp.where(n > 0, o / jnp.where(n > 0, n, 1.0), 0.0)
         return r[:, None, :]        # broadcast over the page-slot axis
 
-    k_pages = requant_payload(k_pool[gidx], ratio(k_scale, new_k_scale),
-                              k_pool.dtype)
-    v_pages = requant_payload(v_pool[gidx], ratio(v_scale, new_v_scale),
-                              v_pool.dtype)
-    k_pool = k_pool.at[pids].set(k_pages, mode="drop")
-    v_pool = v_pool.at[pids].set(v_pages, mode="drop")
+    k_pages = requant_payload(k_pool[layer, gidx],
+                              ratio(k_scale_l, new_k_scale), k_pool.dtype)
+    v_pages = requant_payload(v_pool[layer, gidx],
+                              ratio(v_scale_l, new_v_scale), v_pool.dtype)
+    k_pool = k_pool.at[layer, pids].set(k_pages, mode="drop")
+    v_pool = v_pool.at[layer, pids].set(v_pages, mode="drop")
 
     qk = quantize_rows(k_new, new_k_scale[gidx], k_pool.dtype)
     qv = quantize_rows(v_new, new_v_scale[gidx], v_pool.dtype)
-    k_pool = k_pool.at[pids, offsets].set(qk, mode="drop")
-    v_pool = v_pool.at[pids, offsets].set(qv, mode="drop")
-    return k_pool, v_pool, new_k_scale, new_v_scale
+    k_pool = k_pool.at[layer, pids, offsets].set(qk, mode="drop")
+    v_pool = v_pool.at[layer, pids, offsets].set(qv, mode="drop")
+    return (k_pool, v_pool, k_scale.at[layer].set(new_k_scale),
+            v_scale.at[layer].set(new_v_scale))
 
 
 def dequant_gathered(pages, scale_pages):
@@ -173,35 +187,37 @@ def dequant_gathered(pages, scale_pages):
 
 
 def paged_attention_quant_ref(q, kv_pages_k, kv_pages_v, k_scale, v_scale,
-                              block_tables, ctx_lens, *, softcap=None,
+                              layer, block_tables, ctx_lens, *, softcap=None,
                               scale=None, window=None):
     """paged_attention_ref over quantized pools: gather pages AND their
     scales through the block table, dequantize in f32, same math."""
-    k = dequant_gathered(kv_pages_k[block_tables], k_scale[block_tables])
-    v = dequant_gathered(kv_pages_v[block_tables], v_scale[block_tables])
+    k = dequant_gathered(kv_pages_k[layer, block_tables],
+                         k_scale[layer, block_tables])
+    v = dequant_gathered(kv_pages_v[layer, block_tables],
+                         v_scale[layer, block_tables])
     B = q.shape[0]
-    Hkv, hd = kv_pages_k.shape[2], kv_pages_k.shape[3]
-    S = block_tables.shape[1] * kv_pages_k.shape[1]
-    return paged_attention_ref(q, None, None, block_tables, ctx_lens,
+    page, Hkv, hd = kv_pages_k.shape[2:]
+    S = block_tables.shape[1] * page
+    return paged_attention_ref(q, None, None, layer, block_tables, ctx_lens,
                                softcap=softcap, scale=scale, window=window,
                                _k=k.reshape(B, S, Hkv, hd),
                                _v=v.reshape(B, S, Hkv, hd))
 
 
 def ragged_paged_attention_quant_ref(q, kv_pages_k, kv_pages_v, k_scale,
-                                     v_scale, block_tables, tok_seq,
+                                     v_scale, layer, block_tables, tok_seq,
                                      tok_pos, *, softcap=None, scale=None,
                                      window=None):
     """ragged_paged_attention_ref over quantized pools (see above)."""
     bt = block_tables[tok_seq]
-    k = dequant_gathered(kv_pages_k[bt], k_scale[bt])
-    v = dequant_gathered(kv_pages_v[bt], v_scale[bt])
+    k = dequant_gathered(kv_pages_k[layer, bt], k_scale[layer, bt])
+    v = dequant_gathered(kv_pages_v[layer, bt], v_scale[layer, bt])
     N = q.shape[0]
-    Hkv, hd = kv_pages_k.shape[2], kv_pages_k.shape[3]
-    S = block_tables.shape[1] * kv_pages_k.shape[1]
+    page, Hkv, hd = kv_pages_k.shape[2:]
+    S = block_tables.shape[1] * page
     return ragged_paged_attention_ref(
-        q, None, None, block_tables, tok_seq, tok_pos, softcap=softcap,
-        scale=scale, window=window,
+        q, None, None, layer, block_tables, tok_seq, tok_pos,
+        softcap=softcap, scale=scale, window=window,
         _k=k.reshape(N, S, Hkv, hd), _v=v.reshape(N, S, Hkv, hd))
 
 

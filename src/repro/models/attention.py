@@ -402,19 +402,23 @@ def mla_decode(p, a: AttentionCfg, x, cache, pos, *, window_override="cfg"):
 # These operate on the engine's shared page pools directly: the new tokens'
 # K/V are scattered into their pool page slots (kv_append kernel on TPU, a
 # drop-mode scatter elsewhere) and attention reads the pool through the
-# block table — no per-request contiguous cache is ever materialized. Rows
+# block table — no per-request contiguous cache is ever materialized. The
+# pools are the layer group's stacks, (L, n_pages, ...), carried through the
+# layer scan; each call addresses layer ``layer`` of them in place
+# (pool[layer]) and never slices a layer's pool out. Rows
 # whose write must be discarded (batch/chunk padding) are masked, never
 # routed to a shared scratch page. On CPU the attention math mirrors the
 # contiguous gqa_decode/gqa_extend implementations op-for-op so both
 # execution paths emit bit-identical logits (the differential-test oracle).
 
-def gqa_decode_paged(p, a: AttentionCfg, x, pool, block_tables, ctx_lens, *,
-                     window_override="cfg", discard_pid=None):
+def gqa_decode_paged(p, a: AttentionCfg, x, pool, layer, block_tables,
+                     ctx_lens, *, window_override="cfg", discard_pid=None):
     """One new token per sequence, written and attended in place.
 
-    x: (B, d); pool {"k","v"}: (n_pages, page, Hkv, hd) shared across the
-    batch; block_tables: (B, max_pages) int32 page ids; ctx_lens: (B,)
-    int32 context length INCLUDING the new token. ctx_lens == 0 marks a
+    x: (B, d); pool {"k","v"}: (L, n_pages, page, Hkv, hd) shared across
+    the batch, of which layer ``layer`` (int32 scalar) is used;
+    block_tables: (B, max_pages) int32 page ids; ctx_lens: (B,) int32
+    context length INCLUDING the new token. ctx_lens == 0 marks a
     padded row: its K/V write is dropped and its output is garbage.
     ``discard_pid`` (required) is the caller's write-discard page (the
     engine's scratch page) — invalid rows' appends are routed there on the
@@ -424,7 +428,7 @@ def gqa_decode_paged(p, a: AttentionCfg, x, pool, block_tables, ctx_lens, *,
     from repro.kernels.ops import kv_append_op, paged_attention_op
     window = effective_window(a, window_override)
     B, d = x.shape
-    n_pages, page, Hkv, hd = pool["k"].shape
+    _, n_pages, page, Hkv, hd = pool["k"].shape
     S = block_tables.shape[1] * page
     valid = ctx_lens > 0
     pos = jnp.maximum(ctx_lens - 1, 0)
@@ -446,7 +450,8 @@ def gqa_decode_paged(p, a: AttentionCfg, x, pool, block_tables, ctx_lens, *,
     if quant:
         from repro.kernels.ops import kv_append_quant_op
         k_pool, v_pool, k_scale, v_scale = kv_append_quant_op(
-            pool["k"], pool["v"], pool["k_scale"], pool["v_scale"], k, v,
+            pool["k"], pool["v"], pool["k_scale"], pool["v_scale"], layer,
+            k, v,
             pids.astype(jnp.int32), offs.astype(jnp.int32),
             valid.astype(jnp.int32), discard_pid=discard_pid,
             use_pallas=use_pallas)
@@ -457,13 +462,13 @@ def gqa_decode_paged(p, a: AttentionCfg, x, pool, block_tables, ctx_lens, *,
         if use_pallas:
             pids = jnp.where(valid, pids, discard_pid)
         k_pool, v_pool = kv_append_op(
-            pool["k"], pool["v"], k, v, pids.astype(jnp.int32),
+            pool["k"], pool["v"], layer, k, v, pids.astype(jnp.int32),
             offs.astype(jnp.int32), valid.astype(jnp.int32),
             use_pallas=use_pallas)
         new_pool = {"k": k_pool, "v": v_pool}
     if _paged_use_pallas():
         out = paged_attention_op(q.reshape(B, Hkv, G, hd), k_pool, v_pool,
-                                 block_tables, ctx_lens,
+                                 layer, block_tables, ctx_lens,
                                  k_scale=k_scale, v_scale=v_scale,
                                  softcap=a.logit_softcap, window=window,
                                  use_pallas=True)
@@ -471,14 +476,14 @@ def gqa_decode_paged(p, a: AttentionCfg, x, pool, block_tables, ctx_lens, *,
         if quant:
             from repro.kernels.ref import dequant_gathered
             k_cache = dequant_gathered(
-                k_pool[block_tables],
-                k_scale[block_tables]).reshape(B, S, Hkv, hd)
+                k_pool[layer, block_tables],
+                k_scale[layer, block_tables]).reshape(B, S, Hkv, hd)
             v_cache = dequant_gathered(
-                v_pool[block_tables],
-                v_scale[block_tables]).reshape(B, S, Hkv, hd)
+                v_pool[layer, block_tables],
+                v_scale[layer, block_tables]).reshape(B, S, Hkv, hd)
         else:
-            k_cache = k_pool[block_tables].reshape(B, S, Hkv, hd)
-            v_cache = v_pool[block_tables].reshape(B, S, Hkv, hd)
+            k_cache = k_pool[layer, block_tables].reshape(B, S, Hkv, hd)
+            v_cache = v_pool[layer, block_tables].reshape(B, S, Hkv, hd)
         qh = q.reshape(B, Hkv, G, hd).astype(jnp.float32)
         s = jnp.einsum("bhgk,bshk->bhgs", qh,
                        k_cache.astype(jnp.float32)) / math.sqrt(hd)
@@ -495,7 +500,7 @@ def gqa_decode_paged(p, a: AttentionCfg, x, pool, block_tables, ctx_lens, *,
     return jnp.einsum("bhk,hkd->bd", out, p["wo"]), new_pool
 
 
-def gqa_extend_paged(p, a: AttentionCfg, x, pool, block_tables, start,
+def gqa_extend_paged(p, a: AttentionCfg, x, pool, layer, block_tables, start,
                      n_new, *, window_override="cfg", discard_pid=None):
     """Chunked prefill writing pool pages as they are computed.
 
@@ -510,7 +515,7 @@ def gqa_extend_paged(p, a: AttentionCfg, x, pool, block_tables, start,
     from repro.kernels.ops import kv_append_op
     window = effective_window(a, window_override)
     B, T, d = x.shape
-    n_pages, page, Hkv, hd = pool["k"].shape
+    _, n_pages, page, Hkv, hd = pool["k"].shape
     S = block_tables.shape[1] * page
     positions = start[:, None] + jnp.arange(T)[None, :]          # (B, T)
     q, k, v = gqa_qkv(p, a, x, positions)
@@ -524,7 +529,7 @@ def gqa_extend_paged(p, a: AttentionCfg, x, pool, block_tables, start,
         from repro.kernels.ops import kv_append_quant_op
         from repro.kernels.ref import dequant_gathered
         k_pool, v_pool, k_scale, v_scale = kv_append_quant_op(
-            pool["k"], pool["v"], pool["k_scale"], pool["v_scale"],
+            pool["k"], pool["v"], pool["k_scale"], pool["v_scale"], layer,
             k.reshape(B * T, Hkv, hd), v.reshape(B * T, Hkv, hd),
             pids.reshape(-1).astype(jnp.int32),
             offs.reshape(-1).astype(jnp.int32),
@@ -536,7 +541,7 @@ def gqa_extend_paged(p, a: AttentionCfg, x, pool, block_tables, start,
         if use_pallas:
             pids = jnp.where(t_valid, pids, discard_pid)
         k_pool, v_pool = kv_append_op(
-            pool["k"], pool["v"],
+            pool["k"], pool["v"], layer,
             k.reshape(B * T, Hkv, hd), v.reshape(B * T, Hkv, hd),
             pids.reshape(-1).astype(jnp.int32),
             offs.reshape(-1).astype(jnp.int32),
@@ -548,14 +553,14 @@ def gqa_extend_paged(p, a: AttentionCfg, x, pool, block_tables, start,
     # per-generated-token hot path is the decode kernel above)
     if quant:
         k_cache = dequant_gathered(
-            k_pool[block_tables],
-            k_scale[block_tables]).reshape(B, S, Hkv, hd)
+            k_pool[layer, block_tables],
+            k_scale[layer, block_tables]).reshape(B, S, Hkv, hd)
         v_cache = dequant_gathered(
-            v_pool[block_tables],
-            v_scale[block_tables]).reshape(B, S, Hkv, hd)
+            v_pool[layer, block_tables],
+            v_scale[layer, block_tables]).reshape(B, S, Hkv, hd)
     else:
-        k_cache = k_pool[block_tables].reshape(B, S, Hkv, hd)
-        v_cache = v_pool[block_tables].reshape(B, S, Hkv, hd)
+        k_cache = k_pool[layer, block_tables].reshape(B, S, Hkv, hd)
+        v_cache = v_pool[layer, block_tables].reshape(B, S, Hkv, hd)
     G = a.n_heads // Hkv
     qh = q.reshape(B, T, Hkv, G, hd).astype(jnp.float32)
     s = jnp.einsum("bthgk,bshk->bhgts", qh,
@@ -574,16 +579,17 @@ def gqa_extend_paged(p, a: AttentionCfg, x, pool, block_tables, start,
     return jnp.einsum("bthk,hkd->btd", out, p["wo"]), new_pool
 
 
-def mla_decode_paged(p, a: AttentionCfg, x, pool, block_tables, ctx_lens, *,
-                     window_override="cfg", discard_pid=None):
+def mla_decode_paged(p, a: AttentionCfg, x, pool, layer, block_tables,
+                     ctx_lens, *, window_override="cfg", discard_pid=None):
     """Absorbed MLA decode over paged latent pools (drop-mode XLA scatter
     on every backend, so ``discard_pid`` is unused).
 
-    pool: {"c": (n_pages, page, kv_lora), "kr": (n_pages, page, rope)}.
+    pool: {"c": (L, n_pages, page, kv_lora), "kr": (L, n_pages, page,
+    rope)}, used at layer ``layer``.
     """
     window = effective_window(a, window_override)
     B, d = x.shape
-    n_pages, page, _ = pool["c"].shape
+    _, n_pages, page, _ = pool["c"].shape
     S = block_tables.shape[1] * page
     valid = ctx_lens > 0
     pos = jnp.maximum(ctx_lens - 1, 0)
@@ -594,13 +600,13 @@ def mla_decode_paged(p, a: AttentionCfg, x, pool, block_tables, ctx_lens, *,
     bidx = jnp.arange(B)
     pids = jnp.where(valid, block_tables[bidx, pos // page], n_pages)
     offs = pos % page
-    c_pool = pool["c"].at[pids, offs].set(
+    c_pool = pool["c"].at[layer, pids, offs].set(
         c_new[:, 0].astype(pool["c"].dtype), mode="drop")
-    kr_pool = pool["kr"].at[pids, offs].set(
+    kr_pool = pool["kr"].at[layer, pids, offs].set(
         kr_new[:, 0].astype(pool["kr"].dtype), mode="drop")
 
-    c_cache = c_pool[block_tables].reshape(B, S, -1)
-    kr_cache = kr_pool[block_tables].reshape(B, S, -1)
+    c_cache = c_pool[layer, block_tables].reshape(B, S, -1)
+    kr_cache = kr_pool[layer, block_tables].reshape(B, S, -1)
     q_lat = jnp.einsum("bhn,lhn->bhl", qn.astype(jnp.float32),
                        p["w_uk"].astype(jnp.float32))
     qk = a.qk_nope_head_dim + a.qk_rope_head_dim
@@ -620,13 +626,13 @@ def mla_decode_paged(p, a: AttentionCfg, x, pool, block_tables, ctx_lens, *,
                                                      "kr": kr_pool}
 
 
-def mla_extend_paged(p, a: AttentionCfg, x, pool, block_tables, start,
+def mla_extend_paged(p, a: AttentionCfg, x, pool, layer, block_tables, start,
                      n_new, *, window_override="cfg", discard_pid=None):
     """Absorbed MLA chunked prefill over paged latent pools (drop-mode XLA
     scatter on every backend, so ``discard_pid`` is unused)."""
     window = effective_window(a, window_override)
     B, T, d = x.shape
-    n_pages, page, _ = pool["c"].shape
+    _, n_pages, page, _ = pool["c"].shape
     S = block_tables.shape[1] * page
     positions = start[:, None] + jnp.arange(T)[None, :]
     qn, qr = _mla_q(p, a, x, positions)
@@ -635,13 +641,13 @@ def mla_extend_paged(p, a: AttentionCfg, x, pool, block_tables, start,
     pids = jnp.take_along_axis(block_tables, positions // page, axis=1)
     pids = jnp.where(t_valid, pids, n_pages).reshape(-1)
     offs = (positions % page).reshape(-1)
-    c_pool = pool["c"].at[pids, offs].set(
+    c_pool = pool["c"].at[layer, pids, offs].set(
         c_new.reshape(B * T, -1).astype(pool["c"].dtype), mode="drop")
-    kr_pool = pool["kr"].at[pids, offs].set(
+    kr_pool = pool["kr"].at[layer, pids, offs].set(
         kr_new.reshape(B * T, -1).astype(pool["kr"].dtype), mode="drop")
 
-    c_cache = c_pool[block_tables].reshape(B, S, -1)
-    kr_cache = kr_pool[block_tables].reshape(B, S, -1)
+    c_cache = c_pool[layer, block_tables].reshape(B, S, -1)
+    kr_cache = kr_pool[layer, block_tables].reshape(B, S, -1)
     q_lat = jnp.einsum("bthn,lhn->bthl", qn.astype(jnp.float32),
                        p["w_uk"].astype(jnp.float32))
     qk = a.qk_nope_head_dim + a.qk_rope_head_dim
@@ -676,17 +682,18 @@ def mla_extend_paged(p, a: AttentionCfg, x, pool, block_tables, start,
 # the fused pass emits bit-identical logits to the per-call paths on CPU —
 # the fused-vs-unfused differential property.
 
-def gqa_mixed_paged(p, a: AttentionCfg, x, pool, block_tables, tok_seq,
-                    tok_pos, *, window_override="cfg", discard_pid=None):
+def gqa_mixed_paged(p, a: AttentionCfg, x, pool, layer, block_tables,
+                    tok_seq, tok_pos, *, window_override="cfg",
+                    discard_pid=None):
     """x: (N, d) flat mixed-batch tokens; pool {"k","v"}:
-    (n_pages, page, Hkv, hd); block_tables: (B, max_pages) int32;
-    tok_seq/tok_pos: (N,) int32 (tok_pos == -1 marks a padded row: its K/V
+    (L, n_pages, page, Hkv, hd), used at layer ``layer``; block_tables:
+    (B, max_pages) int32; tok_seq/tok_pos: (N,) int32 (tok_pos == -1 marks a padded row: its K/V
     write is dropped and its output is garbage). Returns (out (N, d),
     updated pool)."""
     from repro.kernels.ops import kv_append_op, ragged_paged_attention_op
     window = effective_window(a, window_override)
     N, d = x.shape
-    n_pages, page, Hkv, hd = pool["k"].shape
+    _, n_pages, page, Hkv, hd = pool["k"].shape
     S = block_tables.shape[1] * page
     valid = tok_pos >= 0
     pos = jnp.maximum(tok_pos, 0)
@@ -708,7 +715,8 @@ def gqa_mixed_paged(p, a: AttentionCfg, x, pool, block_tables, tok_seq,
     if quant:
         from repro.kernels.ops import kv_append_quant_op
         k_pool, v_pool, k_scale, v_scale = kv_append_quant_op(
-            pool["k"], pool["v"], pool["k_scale"], pool["v_scale"], k, v,
+            pool["k"], pool["v"], pool["k_scale"], pool["v_scale"], layer,
+            k, v,
             pids.astype(jnp.int32), offs.astype(jnp.int32),
             valid.astype(jnp.int32), discard_pid=discard_pid,
             use_pallas=use_pallas)
@@ -719,13 +727,13 @@ def gqa_mixed_paged(p, a: AttentionCfg, x, pool, block_tables, tok_seq,
         if use_pallas:
             pids = jnp.where(valid, pids, discard_pid)
         k_pool, v_pool = kv_append_op(
-            pool["k"], pool["v"], k, v, pids.astype(jnp.int32),
+            pool["k"], pool["v"], layer, k, v, pids.astype(jnp.int32),
             offs.astype(jnp.int32), valid.astype(jnp.int32),
             use_pallas=use_pallas)
         new_pool = {"k": k_pool, "v": v_pool}
     if _paged_use_pallas():
         out = ragged_paged_attention_op(
-            q.reshape(N, Hkv, G, hd), k_pool, v_pool, block_tables,
+            q.reshape(N, Hkv, G, hd), k_pool, v_pool, layer, block_tables,
             tok_seq.astype(jnp.int32), tok_pos.astype(jnp.int32),
             k_scale=k_scale, v_scale=v_scale,
             softcap=a.logit_softcap, window=window, use_pallas=True)
@@ -733,12 +741,14 @@ def gqa_mixed_paged(p, a: AttentionCfg, x, pool, block_tables, tok_seq,
         if quant:
             from repro.kernels.ref import dequant_gathered
             k_cache = dequant_gathered(
-                k_pool[bt_tok], k_scale[bt_tok]).reshape(N, S, Hkv, hd)
+                k_pool[layer, bt_tok],
+                k_scale[layer, bt_tok]).reshape(N, S, Hkv, hd)
             v_cache = dequant_gathered(
-                v_pool[bt_tok], v_scale[bt_tok]).reshape(N, S, Hkv, hd)
+                v_pool[layer, bt_tok],
+                v_scale[layer, bt_tok]).reshape(N, S, Hkv, hd)
         else:
-            k_cache = k_pool[bt_tok].reshape(N, S, Hkv, hd)
-            v_cache = v_pool[bt_tok].reshape(N, S, Hkv, hd)
+            k_cache = k_pool[layer, bt_tok].reshape(N, S, Hkv, hd)
+            v_cache = v_pool[layer, bt_tok].reshape(N, S, Hkv, hd)
         qh = q.reshape(N, Hkv, G, hd).astype(jnp.float32)
         s = jnp.einsum("bhgk,bshk->bhgs", qh,
                        k_cache.astype(jnp.float32)) / math.sqrt(hd)
@@ -755,14 +765,15 @@ def gqa_mixed_paged(p, a: AttentionCfg, x, pool, block_tables, tok_seq,
     return jnp.einsum("bhk,hkd->bd", out, p["wo"]), new_pool
 
 
-def mla_mixed_paged(p, a: AttentionCfg, x, pool, block_tables, tok_seq,
-                    tok_pos, *, window_override="cfg", discard_pid=None):
+def mla_mixed_paged(p, a: AttentionCfg, x, pool, layer, block_tables,
+                    tok_seq, tok_pos, *, window_override="cfg",
+                    discard_pid=None):
     """Absorbed MLA mixed-batch pass over paged latent pools (drop-mode XLA
     scatter + O(context) latent gather on every backend, mirroring
     mla_decode_paged — ``discard_pid`` is unused)."""
     window = effective_window(a, window_override)
     N, d = x.shape
-    n_pages, page, _ = pool["c"].shape
+    _, n_pages, page, _ = pool["c"].shape
     S = block_tables.shape[1] * page
     valid = tok_pos >= 0
     pos = jnp.maximum(tok_pos, 0)
@@ -774,13 +785,13 @@ def mla_mixed_paged(p, a: AttentionCfg, x, pool, block_tables, tok_seq,
     pids = jnp.take_along_axis(bt_tok, (pos // page)[:, None], axis=1)[:, 0]
     pids = jnp.where(valid, pids, n_pages)
     offs = pos % page
-    c_pool = pool["c"].at[pids, offs].set(
+    c_pool = pool["c"].at[layer, pids, offs].set(
         c_new[:, 0].astype(pool["c"].dtype), mode="drop")
-    kr_pool = pool["kr"].at[pids, offs].set(
+    kr_pool = pool["kr"].at[layer, pids, offs].set(
         kr_new[:, 0].astype(pool["kr"].dtype), mode="drop")
 
-    c_cache = c_pool[bt_tok].reshape(N, S, -1)
-    kr_cache = kr_pool[bt_tok].reshape(N, S, -1)
+    c_cache = c_pool[layer, bt_tok].reshape(N, S, -1)
+    kr_cache = kr_pool[layer, bt_tok].reshape(N, S, -1)
     q_lat = jnp.einsum("bhn,lhn->bhl", qn.astype(jnp.float32),
                        p["w_uk"].astype(jnp.float32))
     qk = a.qk_nope_head_dim + a.qk_rope_head_dim
@@ -800,24 +811,25 @@ def mla_mixed_paged(p, a: AttentionCfg, x, pool, block_tables, tok_seq,
                                                      "kr": kr_pool}
 
 
-def attention_mixed_paged(p, a, x, pool, block_tables, tok_seq, tok_pos, *,
-                          window_override="cfg", discard_pid=None):
+def attention_mixed_paged(p, a, x, pool, layer, block_tables, tok_seq,
+                          tok_pos, *, window_override="cfg",
+                          discard_pid=None):
     fn = mla_mixed_paged if a.kind == "mla" else gqa_mixed_paged
-    return fn(p, a, x, pool, block_tables, tok_seq, tok_pos,
+    return fn(p, a, x, pool, layer, block_tables, tok_seq, tok_pos,
               window_override=window_override, discard_pid=discard_pid)
 
 
-def attention_decode_paged(p, a, x, pool, block_tables, ctx_lens, *,
+def attention_decode_paged(p, a, x, pool, layer, block_tables, ctx_lens, *,
                            window_override="cfg", discard_pid=None):
     fn = mla_decode_paged if a.kind == "mla" else gqa_decode_paged
-    return fn(p, a, x, pool, block_tables, ctx_lens,
+    return fn(p, a, x, pool, layer, block_tables, ctx_lens,
               window_override=window_override, discard_pid=discard_pid)
 
 
-def attention_extend_paged(p, a, x, pool, block_tables, start, n_new, *,
-                           window_override="cfg", discard_pid=None):
+def attention_extend_paged(p, a, x, pool, layer, block_tables, start, n_new,
+                           *, window_override="cfg", discard_pid=None):
     fn = mla_extend_paged if a.kind == "mla" else gqa_extend_paged
-    return fn(p, a, x, pool, block_tables, start, n_new,
+    return fn(p, a, x, pool, layer, block_tables, start, n_new,
               window_override=window_override, discard_pid=discard_pid)
 
 

@@ -89,19 +89,21 @@ def block_decode(p, cfg: ModelConfig, blk: BlockCfg, x, cache, ctx):
     return x + h.astype(x.dtype), state
 
 
-def block_decode_paged(p, cfg: ModelConfig, blk: BlockCfg, x, pool, ctx):
+def block_decode_paged(p, cfg: ModelConfig, blk: BlockCfg, x, pool, layer,
+                       ctx):
     """Single-token pass over the shared paged pool (DESIGN.md §9).
 
-    x: (B, d); pool: this block's page pool (no batch axis — sequences are
-    routed through ctx["block_tables"] / ctx["ctx_lens"]). Returns
-    (x, updated pool). Attention-cache blocks only.
+    x: (B, d); pool: this block's page pools stacked over the group's
+    layers, addressed at ``layer`` (no batch axis — sequences are routed
+    through ctx["block_tables"] / ctx["ctx_lens"]). Returns (x, updated
+    pool). Attention-cache blocks only.
     """
     if blk.kind not in ("attn", "shared_attn"):
         raise ValueError(f"paged execution serves attention blocks, "
                          f"got {blk.kind}")
     eps = cfg.norm_eps
     h, pool = attention.attention_decode_paged(
-        p["attn"], blk.attn, rms_norm(x, p["norm1"], eps), pool,
+        p["attn"], blk.attn, rms_norm(x, p["norm1"], eps), pool, layer,
         ctx["block_tables"], ctx["ctx_lens"],
         window_override=ctx.get("window_override", "cfg"),
         discard_pid=ctx.get("discard_pid"))
@@ -119,20 +121,22 @@ def block_decode_paged(p, cfg: ModelConfig, blk: BlockCfg, x, pool, ctx):
     return x + h.astype(x.dtype), pool
 
 
-def block_mixed_paged(p, cfg: ModelConfig, blk: BlockCfg, x, pool, ctx):
+def block_mixed_paged(p, cfg: ModelConfig, blk: BlockCfg, x, pool, layer,
+                      ctx):
     """Fused mixed-batch pass over the shared paged pool (DESIGN.md §10).
 
     x: (N, d) — one row per flat token of the iteration (chunk tokens and
     decode tokens alike), routed through ctx["block_tables"] by
-    ctx["tok_seq"] / ctx["tok_pos"]. Returns (x, updated pool).
-    Attention-cache blocks only.
+    ctx["tok_seq"] / ctx["tok_pos"]; pool and layer as in
+    block_decode_paged. Returns (x, updated pool). Attention-cache blocks
+    only.
     """
     if blk.kind not in ("attn", "shared_attn"):
         raise ValueError(f"paged execution serves attention blocks, "
                          f"got {blk.kind}")
     eps = cfg.norm_eps
     h, pool = attention.attention_mixed_paged(
-        p["attn"], blk.attn, rms_norm(x, p["norm1"], eps), pool,
+        p["attn"], blk.attn, rms_norm(x, p["norm1"], eps), pool, layer,
         ctx["block_tables"], ctx["tok_seq"], ctx["tok_pos"],
         window_override=ctx.get("window_override", "cfg"),
         discard_pid=ctx.get("discard_pid"))
@@ -150,17 +154,18 @@ def block_mixed_paged(p, cfg: ModelConfig, blk: BlockCfg, x, pool, ctx):
     return x + h.astype(x.dtype), pool
 
 
-def block_extend_paged(p, cfg: ModelConfig, blk: BlockCfg, x, pool, ctx):
+def block_extend_paged(p, cfg: ModelConfig, blk: BlockCfg, x, pool, layer,
+                       ctx):
     """Chunked-prefill pass writing pool pages in place. x: (B, T, d) at
     positions ctx["start"][b] + t; only the first ctx["n_new"][b] tokens
-    per row are real. Returns (x, updated pool, aux)."""
+    per row are real; pool and layer as in block_decode_paged. Returns
+    (x, updated pool)."""
     if blk.kind not in ("attn", "shared_attn"):
         raise ValueError(f"paged execution serves attention blocks, "
                          f"got {blk.kind}")
     eps = cfg.norm_eps
-    aux = jnp.zeros((), jnp.float32)
     h, pool = attention.attention_extend_paged(
-        p["attn"], blk.attn, rms_norm(x, p["norm1"], eps), pool,
+        p["attn"], blk.attn, rms_norm(x, p["norm1"], eps), pool, layer,
         ctx["block_tables"], ctx["start"], ctx["n_new"],
         window_override=ctx.get("window_override", "cfg"),
         discard_pid=ctx.get("discard_pid"))
@@ -168,13 +173,13 @@ def block_extend_paged(p, cfg: ModelConfig, blk: BlockCfg, x, pool, ctx):
         h = rms_norm(h, p["post_norm1"], eps)
     x = x + h.astype(x.dtype)
     if blk.ffn.kind == "moe":
-        h, aux = moe.moe_forward(p["moe"], blk.ffn,
-                                 rms_norm(x, p["norm2"], eps))
+        h, _ = moe.moe_forward(p["moe"], blk.ffn,
+                               rms_norm(x, p["norm2"], eps))
     else:
         h = mlp.mlp_forward(p["mlp"], blk.ffn, rms_norm(x, p["norm2"], eps))
     if blk.post_norms:
         h = rms_norm(h, p["post_norm2"], eps)
-    return x + h.astype(x.dtype), pool, aux
+    return x + h.astype(x.dtype), pool
 
 
 def block_extend(p, cfg: ModelConfig, blk: BlockCfg, x, cache, ctx):

@@ -333,29 +333,10 @@ class LM:
         ctx = {"block_tables": block_tables, "ctx_lens": ctx_lens,
                "window_override": window_override,
                "discard_pid": discard_pid}
-        shared = params.get("shared")
-        new_pools = []
-
-        for gi, g in enumerate(cfg.groups):
-            period = g.period
-
-            def body(xx, inp, period=period):
-                per_params, pool_p = inp
-                new_p = {}
-                for j, blk in enumerate(period):
-                    pj = shared if blk.kind == "shared_attn" \
-                        else per_params[f"b{j}"]
-                    xx, pool_j = B.block_decode_paged(pj, cfg, blk, xx,
-                                                      pool_p[f"b{j}"], ctx)
-                    new_p[f"b{j}"] = pool_j
-                return xx, new_p
-
-            x, pools_g = jax.lax.scan(
-                body, x, (params["groups"][gi]["scan"], pools[gi]))
-            new_pools.append(pools_g)
-
+        x, new_pools = self._paged_layers(params, x, pools,
+                                          B.block_decode_paged, ctx)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return self.logits(params, x), tuple(new_pools)
+        return self.logits(params, x), new_pools
 
     def forward_mixed_paged(self, params, tokens, tok_seq, tok_pos, q_last,
                             pools, block_tables, sampling=None, *,
@@ -397,27 +378,8 @@ class LM:
         ctx = {"block_tables": block_tables, "tok_seq": tok_seq,
                "tok_pos": tok_pos, "window_override": window_override,
                "discard_pid": discard_pid}
-        shared = params.get("shared")
-        new_pools = []
-
-        for gi, g in enumerate(cfg.groups):
-            period = g.period
-
-            def body(xx, inp, period=period):
-                per_params, pool_p = inp
-                new_p = {}
-                for j, blk in enumerate(period):
-                    pj = shared if blk.kind == "shared_attn" \
-                        else per_params[f"b{j}"]
-                    xx, pool_j = B.block_mixed_paged(pj, cfg, blk, xx,
-                                                     pool_p[f"b{j}"], ctx)
-                    new_p[f"b{j}"] = pool_j
-                return xx, new_p
-
-            x, pools_g = jax.lax.scan(
-                body, x, (params["groups"][gi]["scan"], pools[gi]))
-            new_pools.append(pools_g)
-
+        x, new_pools = self._paged_layers(params, x, pools,
+                                          B.block_mixed_paged, ctx)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         last = x[q_last]                                  # (B, d)
         logits = self.logits(params, last)
@@ -430,7 +392,7 @@ class LM:
             temps, top_ks, top_ps, seeds = sampling
             sampled = sample_tokens(flat, temps, top_ks, top_ps, seeds,
                                     tok_pos[q_last] + 1)
-        return sampled, logits, tuple(new_pools)
+        return sampled, logits, new_pools
 
     def extend_step_paged(self, params, tokens, start, n_new, pools,
                           block_tables, *, embeds=None,
@@ -446,36 +408,45 @@ class LM:
         ctx = {"block_tables": block_tables, "start": start, "n_new": n_new,
                "window_override": window_override,
                "discard_pid": discard_pid}
-        shared = params.get("shared")
-        new_pools = []
-
-        for gi, g in enumerate(cfg.groups):
-            period = g.period
-
-            def body(carry, inp, period=period):
-                xx, aa = carry
-                per_params, pool_p = inp
-                new_p = {}
-                for j, blk in enumerate(period):
-                    pj = shared if blk.kind == "shared_attn" \
-                        else per_params[f"b{j}"]
-                    xx, pool_j, auxj = B.block_extend_paged(
-                        pj, cfg, blk, xx, pool_p[f"b{j}"], ctx)
-                    new_p[f"b{j}"] = pool_j
-                    aa = aa + auxj
-                return (xx, aa), new_p
-
-            (x, _), pools_g = jax.lax.scan(
-                body, (x, jnp.zeros((), jnp.float32)),
-                (params["groups"][gi]["scan"], pools[gi]))
-            new_pools.append(pools_g)
-
+        x, new_pools = self._paged_layers(params, x, pools,
+                                          B.block_extend_paged, ctx)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         if logits_index is None:
             last = x[:, -1]
         else:
             last = x[jnp.arange(x.shape[0]), logits_index]
-        return self.logits(params, last), tuple(new_pools)
+        return self.logits(params, last), new_pools
+
+    def _paged_layers(self, params, x, pools, block_fn, ctx):
+        """The layer groups over the shared paged pools (DESIGN.md §9).
+
+        Each group's pools, stacked over its layers, ride its layer scan as
+        part of the carry, and ``block_fn`` addresses them in place at the
+        scan's layer index (``pool[layer]``), so no layer's pool is sliced
+        out of the stack, copied, or written into a second stack; only the
+        per-layer params are scanned over. Returns (x, new pools)."""
+        cfg = self.cfg
+        shared = params.get("shared")
+        new_pools = []
+        for gi, g in enumerate(cfg.groups):
+
+            def body(carry, inp, period=g.period):
+                xx, pools_g = carry
+                per_params, layer = inp
+                pools_g = dict(pools_g)
+                for j, blk in enumerate(period):
+                    pj = shared if blk.kind == "shared_attn" \
+                        else per_params[f"b{j}"]
+                    xx, pools_g[f"b{j}"] = block_fn(
+                        pj, cfg, blk, xx, pools_g[f"b{j}"], layer, ctx)
+                return (xx, pools_g), None
+
+            (x, pools_g), _ = jax.lax.scan(
+                body, (x, pools[gi]),
+                (params["groups"][gi]["scan"],
+                 jnp.arange(g.n_periods, dtype=jnp.int32)))
+            new_pools.append(pools_g)
+        return x, tuple(new_pools)
 
     def extend_step(self, params, tokens, start, cache, *, embeds=None,
                     window_override="cfg", logits_index=None):

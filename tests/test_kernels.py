@@ -73,8 +73,9 @@ def test_paged_attention(B, Hkv, G, hd, page, max_pages, n_pages, dtype):
     bt = jnp.asarray(rng.integers(0, n_pages, (B, max_pages)), jnp.int32)
     lens = jnp.asarray(rng.integers(1, page * max_pages + 1, (B,)),
                        jnp.int32)
-    out = paged_attention(q, kp, vp, bt, lens, interpret=True)
-    want = ref.paged_attention_ref(q, kp, vp, bt, lens)
+    out = paged_attention(q, kp[None], vp[None], 0, bt, lens,
+                          interpret=True)
+    want = ref.paged_attention_ref(q, kp[None], vp[None], 0, bt, lens)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), atol=tol)
@@ -90,7 +91,8 @@ def test_paged_attention_matches_dense_decode():
     vp = jax.random.normal(ks[2], (n_pages, page, Hkv, hd))
     bt = jnp.asarray(rng.integers(0, n_pages, (B, max_pages)), jnp.int32)
     lens = jnp.asarray([page * max_pages, 17], jnp.int32)
-    out = paged_attention(q, kp, vp, bt, lens, interpret=True)
+    out = paged_attention(q, kp[None], vp[None], 0, bt, lens,
+                          interpret=True)
     k = kp[bt].reshape(B, max_pages * page, Hkv, hd)
     v = vp[bt].reshape(B, max_pages * page, Hkv, hd)
     s = jnp.einsum("bhgd,bshd->bhgs", q, k) / np.sqrt(hd)
@@ -116,8 +118,9 @@ def test_paged_attention_ragged_edges(G, ctx):
     # one row at the edge case, the others ragged around it
     lens = jnp.asarray([ctx, max(1, ctx - 1), min(page * max_pages, ctx + 1)],
                        jnp.int32)
-    out = paged_attention(q, kp, vp, bt, lens, interpret=True)
-    want = ref.paged_attention_ref(q, kp, vp, bt, lens)
+    out = paged_attention(q, kp[None], vp[None], 0, bt, lens,
+                          interpret=True)
+    want = ref.paged_attention_ref(q, kp[None], vp[None], 0, bt, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
@@ -130,9 +133,10 @@ def test_paged_attention_sliding_window(window):
     vp = jax.random.normal(ks[2], (16, 8, 2, 32))
     bt = jnp.asarray(rng.integers(0, 16, (2, 4)), jnp.int32)
     lens = jnp.asarray([29, 5], jnp.int32)
-    out = paged_attention(q, kp, vp, bt, lens, window=window,
-                          interpret=True)
-    want = ref.paged_attention_ref(q, kp, vp, bt, lens, window=window)
+    out = paged_attention(q, kp[None], vp[None], 0, bt, lens,
+                          window=window, interpret=True)
+    want = ref.paged_attention_ref(q, kp[None], vp[None], 0, bt, lens,
+                                   window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
@@ -169,27 +173,45 @@ TILED = {
 }
 
 
-@pytest.mark.parametrize("N,Hkv,G,hd,page,max_pages,n_pages,B,spans", [
-    pytest.param(1, 2, 4, 64, 16, 8, 32, 1, None,      # a single decode
+def _layer_of_stack(pool, L, key):
+    """(L, ...) stack whose layer 1 is ``pool`` and whose other layers hold
+    other values, or ``pool`` as a one-layer stack (L = 1, layer 0);
+    returns (stack, layer)."""
+    if L == 1:
+        return pool[None], 0
+    other = jax.random.normal(key, (L,) + pool.shape).astype(pool.dtype)
+    return other.at[1].set(pool), 1
+
+
+@pytest.mark.parametrize("N,Hkv,G,hd,page,max_pages,n_pages,B,spans,L", [
+    pytest.param(1, 2, 4, 64, 16, 8, 32, 1, None, 1,   # a single decode
                  id="1-2-4-64-16-8-32-1"),
-    pytest.param(9, 2, 4, 64, 16, 8, 32, 3, None,      # mixed ragged batch
+    pytest.param(9, 2, 4, 64, 16, 8, 32, 3, None, 1,   # mixed ragged batch
                  id="9-2-4-64-16-8-32-3"),
-    pytest.param(6, 1, 8, 128, 8, 16, 64, 2, None, id="6-1-8-128-8-16-64-2"),
-    pytest.param(5, 4, 1, 32, 32, 4, 16, 5, None, id="5-4-1-32-32-4-16-5"),
-    pytest.param(192, 4, 1, 128, 8, 32, 96, 4, TILED["mha-chunk-midpage"],
+    pytest.param(6, 1, 8, 128, 8, 16, 64, 2, None, 1,
+                 id="6-1-8-128-8-16-64-2"),
+    pytest.param(5, 4, 1, 32, 32, 4, 16, 5, None, 1,
+                 id="5-4-1-32-32-4-16-5"),
+    pytest.param(192, 4, 1, 128, 8, 32, 96, 4, TILED["mha-chunk-midpage"], 1,
                  id="mha-chunk-midpage"),
-    pytest.param(64, 2, 8, 128, 16, 8, 32, 3, TILED["gqa-chunk-at-0"],
+    pytest.param(64, 2, 8, 128, 16, 8, 32, 3, TILED["gqa-chunk-at-0"], 1,
                  id="gqa-chunk-at-0"),
     pytest.param(32, 2, 4, 64, 8, 6, 24, 2, TILED["chunk-to-full-context"],
-                 id="chunk-to-full-context"),
-    pytest.param(128, 2, 3, 128, 8, 16, 48, 3, TILED["odd-group"],
+                 1, id="chunk-to-full-context"),
+    pytest.param(128, 2, 3, 128, 8, 16, 48, 3, TILED["odd-group"], 1,
                  id="odd-group"),
+    # layer 1 of a three-layer stack, G 1 and G 8
+    pytest.param(5, 4, 1, 32, 32, 4, 16, 5, None, 3, id="G1-layer1-of-3"),
+    pytest.param(64, 2, 8, 128, 16, 8, 32, 3, TILED["gqa-chunk-at-0"], 3,
+                 id="G8-layer1-of-3"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_ragged_paged_attention(N, Hkv, G, hd, page, max_pages, n_pages, B,
-                                spans, dtype):
+                                spans, L, dtype):
+    """Against the oracle; on a stack of L = 3 at layer 1, also bit-equal
+    to the kernel on that layer's pool alone (a one-layer stack)."""
     rng = np.random.default_rng(N * 13 + page)
-    ks = jax.random.split(KEY, 3)
+    ks = jax.random.split(KEY, 5)
     q = jax.random.normal(ks[0], (N, Hkv, G, hd)).astype(dtype)
     kp = jax.random.normal(ks[1], (n_pages, page, Hkv, hd)).astype(dtype)
     vp = jax.random.normal(ks[2], (n_pages, page, Hkv, hd)).astype(dtype)
@@ -200,15 +222,22 @@ def test_ragged_paged_attention(N, Hkv, G, hd, page, max_pages, n_pages, B,
                               jnp.int32)
     else:
         tok_seq, tok_pos = _span_rows(spans, N)
-    out = ragged_paged_attention(q, kp, vp, bt, tok_seq, tok_pos,
-                                 interpret=True)
-    want = ref.ragged_paged_attention_ref(q, kp, vp, bt, tok_seq, tok_pos)
+    k_stack, layer = _layer_of_stack(kp, L, ks[3])
+    v_stack, _ = _layer_of_stack(vp, L, ks[4])
+    out = ragged_paged_attention(q, k_stack, v_stack, layer, bt, tok_seq,
+                                 tok_pos, interpret=True)
+    want = ref.ragged_paged_attention_ref(q, k_stack, v_stack, layer, bt,
+                                          tok_seq, tok_pos)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     real = np.asarray(tok_pos) >= 0
     np.testing.assert_allclose(np.asarray(out, np.float32)[real],
                                np.asarray(want, np.float32)[real], atol=tol)
     assert not np.asarray(out, np.float32)[~real].any(), \
         "padded rows must come out zero"
+    if L > 1:
+        alone = ragged_paged_attention(q, kp[None], vp[None], 0, bt,
+                                       tok_seq, tok_pos, interpret=True)
+        assert jnp.array_equal(out, alone)
 
 
 @pytest.mark.parametrize("window,softcap,tiled", [
@@ -239,11 +268,12 @@ def test_ragged_paged_attention_window_softcap(window, softcap, tiled):
         bt = jnp.asarray(rng.integers(0, 16, (2, 4)), jnp.int32)
         tok_seq = jnp.asarray([0, 0, 0, 1, 1, 0], jnp.int32)
         tok_pos = jnp.asarray([0, 12, 31, 7, 8, 29], jnp.int32)
-    out = ragged_paged_attention(q, kp, vp, bt, tok_seq, tok_pos,
-                                 window=window, softcap=softcap,
+    out = ragged_paged_attention(q, kp[None], vp[None], 0, bt, tok_seq,
+                                 tok_pos, window=window, softcap=softcap,
                                  interpret=True)
-    want = ref.ragged_paged_attention_ref(q, kp, vp, bt, tok_seq, tok_pos,
-                                          window=window, softcap=softcap)
+    want = ref.ragged_paged_attention_ref(q, kp[None], vp[None], 0, bt,
+                                          tok_seq, tok_pos, window=window,
+                                          softcap=softcap)
     real = np.asarray(tok_pos) >= 0
     np.testing.assert_allclose(np.asarray(out)[real], np.asarray(want)[real],
                                atol=2e-5)
@@ -260,10 +290,11 @@ def test_ragged_degenerates_to_paged_attention():
     vp = jax.random.normal(ks[2], (n_pages, page, Hkv, hd))
     bt = jnp.asarray(rng.integers(0, n_pages, (B, max_pages)), jnp.int32)
     lens = jnp.asarray([page * max_pages, 17, 1, 0], jnp.int32)  # 0 = pad
-    got = ragged_paged_attention(q, kp, vp, bt,
+    got = ragged_paged_attention(q, kp[None], vp[None], 0, bt,
                                  jnp.arange(B, dtype=jnp.int32),
                                  lens - 1, interpret=True)
-    want = paged_attention(q, kp, vp, bt, lens, interpret=True)
+    want = paged_attention(q, kp[None], vp[None], 0, bt, lens,
+                           interpret=True)
     np.testing.assert_allclose(np.asarray(got)[:3], np.asarray(want)[:3],
                                atol=2e-5)
 
@@ -281,13 +312,13 @@ def test_ragged_chunk_internal_causality():
     bt = jnp.asarray(rng.integers(0, n_pages, (1, max_pages)), jnp.int32)
     tok_seq = jnp.zeros(4, jnp.int32)
     tok_pos = jnp.asarray([4, 5, 6, 7], jnp.int32)       # one chunk
-    base = ragged_paged_attention(q, kp, vp, bt, tok_seq, tok_pos,
-                                  interpret=True)
+    base = ragged_paged_attention(q, kp[None], vp[None], 0, bt, tok_seq,
+                                  tok_pos, interpret=True)
     # clobber position 6's slot (page bt[0, 0], offset 6)
     kp2 = kp.at[bt[0, 0], 6].add(3.0)
     vp2 = vp.at[bt[0, 0], 6].add(-2.0)
-    pert = ragged_paged_attention(q, kp2, vp2, bt, tok_seq, tok_pos,
-                                  interpret=True)
+    pert = ragged_paged_attention(q, kp2[None], vp2[None], 0, bt, tok_seq,
+                                  tok_pos, interpret=True)
     d = np.max(np.abs(np.asarray(pert) - np.asarray(base)),
                axis=(1, 2, 3))
     assert np.all(d[:2] == 0.0), "earlier chunk tokens saw a later slot"
@@ -295,27 +326,47 @@ def test_ragged_chunk_internal_causality():
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("N", [1, 4, 9])
-def test_kv_append_matches_ref(dtype, N):
+@pytest.mark.parametrize("N,L", [
+    pytest.param(1, 1, id="1"), pytest.param(4, 1, id="4"),
+    pytest.param(9, 1, id="9"),
+    # layer 1 of a three-layer stack, padded rows to the discard page
+    pytest.param(9, 3, id="9-layer1-of-3"),
+])
+def test_kv_append_matches_ref(dtype, N, L):
     rng = np.random.default_rng(N)
     n_pages, page, Hkv, hd = 12, 8, 2, 16
-    ks = jax.random.split(KEY, 4)
+    ks = jax.random.split(KEY, 6)
     kp = jax.random.normal(ks[0], (n_pages, page, Hkv, hd)).astype(dtype)
     vp = jax.random.normal(ks[1], (n_pages, page, Hkv, hd)).astype(dtype)
     kn = jax.random.normal(ks[2], (N, Hkv, hd)).astype(dtype)
     vn = jax.random.normal(ks[3], (N, Hkv, hd)).astype(dtype)
-    # distinct live slots; rows randomly flagged invalid keep their slot
-    # index (in interpret mode the kernel's copy-back is content-preserving,
-    # matching the ref's drop semantics bit-for-bit)
-    slots = rng.choice(n_pages * page, N, replace=False)
-    ids = jnp.asarray(slots // page, jnp.int32)
-    offs = jnp.asarray(slots % page, jnp.int32)
+    k_stack, layer = _layer_of_stack(kp, L, ks[4])
+    v_stack, _ = _layer_of_stack(vp, L, ks[5])
     valid = jnp.asarray(rng.integers(0, 2, N), jnp.int32)
+    if L == 1:
+        # distinct live slots; rows randomly flagged invalid keep their
+        # slot index (in interpret mode the kernel's copy-back is
+        # content-preserving, matching the ref's drop semantics
+        # bit-for-bit)
+        slots = rng.choice(n_pages * page, N, replace=False)
+        ids = jnp.asarray(slots // page, jnp.int32)
+    else:
+        # live slots off the last page, the write-discard page that every
+        # invalid row is routed to (the engine's contract)
+        slots = rng.choice((n_pages - 1) * page, N, replace=False)
+        ids = jnp.where(valid != 0, jnp.asarray(slots // page, jnp.int32),
+                        n_pages - 1)
+    offs = jnp.asarray(slots % page, jnp.int32)
     # the kernel donates its pools: hand it copies, keep the originals
-    got_k, got_v = kv_append(kp.copy(), vp.copy(), kn, vn, ids, offs, valid,
-                             interpret=True)
-    want_k, want_v = ref.kv_append_ref(kp, vp, kn, vn, ids, offs, valid)
-    assert jnp.array_equal(got_k, want_k) and jnp.array_equal(got_v, want_v)
+    got_k, got_v = kv_append(k_stack.copy(), v_stack.copy(), layer, kn, vn,
+                             ids, offs, valid, interpret=True)
+    want_k, want_v = ref.kv_append_ref(kp[None], vp[None], 0, kn, vn, ids,
+                                       offs, valid)
+    assert jnp.array_equal(got_k[layer], want_k[0])
+    assert jnp.array_equal(got_v[layer], want_v[0])
+    others = np.asarray([i for i in range(L) if i != layer], np.int32)
+    assert jnp.array_equal(got_k[others], k_stack[others])
+    assert jnp.array_equal(got_v[others], v_stack[others])
 
 
 def test_kv_append_invalid_rows_leave_pool_untouched():
@@ -329,9 +380,9 @@ def test_kv_append_invalid_rows_leave_pool_untouched():
     ids = jnp.asarray([2, 2, 2, 5, 0], jnp.int32)
     offs = jnp.asarray([1, 1, 3, 0, 0], jnp.int32)
     valid = jnp.zeros(5, jnp.int32)
-    got_k, got_v = kv_append(kp.copy(), vp.copy(), kn, vn, ids, offs, valid,
-                             interpret=True)
-    assert jnp.array_equal(got_k, kp) and jnp.array_equal(got_v, vp)
+    got_k, got_v = kv_append(kp[None], vp[None], 0, kn, vn, ids, offs,
+                             valid, interpret=True)
+    assert jnp.array_equal(got_k[0], kp) and jnp.array_equal(got_v[0], vp)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8,

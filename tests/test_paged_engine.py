@@ -530,3 +530,77 @@ def test_fused_step_counts_full_attention_layer(monkeypatch):
     counters count a full-attention layer's launch."""
     a = _check_attention_grid_counts(monkeypatch, "gemma2-9b")
     assert a.sliding_window is None
+
+
+@pytest.mark.parametrize("pallas", [False, True], ids=["xla", "pallas"])
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_fused_step_matches_per_layer_reference(monkeypatch, pallas,
+                                                kv_dtype):
+    """The fused step carries the stacked pools through the layer scan and
+    addresses pool[layer] in place: on a two-layer model its pools (every
+    layer, the appended rows of layer 1 included) and sampled ids equal a
+    reference that runs each layer by itself on that layer's own one-layer
+    pool. Both the jnp path and the Pallas kernels (interpreted)."""
+    from repro.models import attention
+    from repro.models import blocks as B
+    from repro.models.common import rms_norm
+    monkeypatch.setattr(attention, "_paged_use_pallas", lambda: pallas)
+    cfg = get_config("llama3.2-1b", tiny=True)
+    (g,) = cfg.groups
+    assert g.n_periods >= 2 and len(g.period) == 1
+    m = LM(cfg)
+    params = m.init(jax.random.PRNGKey(0), dtype=jnp.float32)
+    page, n_pages, max_pages = 8, 12, 4
+    pools = m.init_cache(n_pages, page, dtype=jnp.float32, kv_dtype=kv_dtype)
+    # every layer starts from contents of its own, so a write to the wrong
+    # layer (or none) shows
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 8))
+    pools = jax.tree.map(
+        lambda l: (jnp.abs(jax.random.normal(next(keys), l.shape)) * 0.01
+                   if l.dtype == jnp.float32 and l.ndim == 3 else
+                   jax.random.normal(next(keys), l.shape) * 3).astype(
+                       l.dtype), pools)
+    bt = np.zeros((2, max_pages), np.int64)
+    bt[0, :2] = [3, 4]          # seq 0: a chunk at positions 5..10
+    bt[1, :1] = [7]             # seq 1: a decode at position 1
+    tseq = jnp.asarray([0] * 6 + [1] + [0] * 3 + [1], jnp.int32)
+    tpos = jnp.asarray(list(range(5, 11)) + [1] + [-1] * 4, jnp.int32)
+    toks = jnp.asarray([5, 6, 7, 8, 9, 10, 11, 1, 1, 1, 1], jnp.int32)
+    qlast = jnp.asarray([5, 6], jnp.int32)
+    bt = jnp.asarray(bt, jnp.int32)
+    discard = n_pages - 1
+
+    fused = jax.jit(lambda p, pl: m.forward_mixed_paged(
+        p, toks, tseq, tpos, qlast, pl, bt, discard_pid=discard))
+
+    @jax.jit
+    def per_layer(p, pl):
+        ctx = {"block_tables": bt, "tok_seq": tseq, "tok_pos": tpos,
+               "discard_pid": discard}
+        x = m.embed(p, toks[:, None])[:, 0]
+        own = []
+        for layer in range(g.n_periods):
+            pj = jax.tree.map(lambda w: w[layer], p["groups"][0]["scan"])
+            pool = jax.tree.map(lambda a: a[layer][None], pl[0]["b0"])
+            x, pool = B.block_mixed_paged(pj["b0"], cfg, g.period[0], x,
+                                          pool, 0, ctx)
+            own.append(pool)
+        x = rms_norm(x, p["final_norm"], cfg.norm_eps)
+        ids = jnp.argmax(m.logits(p, x[qlast]), axis=-1).astype(jnp.int32)
+        return ids, ({"b0": jax.tree.map(
+            lambda *a: jnp.concatenate(a), *own)},)
+
+    want_ids, want = per_layer(params, jax.tree.map(jnp.copy, pools))
+    got_ids, _, got = fused(params, jax.tree.map(jnp.copy, pools))
+    assert jnp.array_equal(got_ids, want_ids)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g_leaf, w_leaf in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.array_equal(np.asarray(g_leaf).view(np.uint8),
+                              np.asarray(w_leaf).view(np.uint8))
+    # the rows landed in layer 1 as in layer 0: the chunk's positions 5..7
+    # on page 3, 8..10 on page 4, the decode's position 1 on page 7
+    new_k, old_k = got[0]["b0"]["k"], pools[0]["b0"]["k"]
+    for pid, slots in ((3, slice(5, 8)), (4, slice(0, 3)), (7, slice(1, 2))):
+        for layer in range(g.n_periods):
+            assert not np.array_equal(new_k[layer, pid, slots],
+                                      old_k[layer, pid, slots])

@@ -63,8 +63,9 @@ def _rand_rows(key, n, Hkv, hd, scale=3.0):
 
 
 def _append_round(key, qdtype, pools, *, n=6, n_pages=8, page=4, Hkv=2,
-                  hd=16, discard_pid=7, interpret=True):
-    """One quantized append on both paths; returns (pallas, ref) pools."""
+                  hd=16, discard_pid=7, layer=0, interpret=True):
+    """One quantized append into layer ``layer`` of stacked pools on both
+    paths; returns (pallas, ref) pools."""
     ks = jax.random.split(key, 4)
     k_new = _rand_rows(ks[0], n, Hkv, hd)
     v_new = _rand_rows(ks[1], n, Hkv, hd, scale=1.5)
@@ -73,37 +74,61 @@ def _append_round(key, qdtype, pools, *, n=6, n_pages=8, page=4, Hkv=2,
     offs = jnp.asarray([0, 1, 2, 0, 3, 1][:n], jnp.int32)
     valid = jnp.asarray([1, 1, 1, 1, 1, 0][:n], jnp.int32)
     (pk, pv, pks, pvs), (rk, rv, rks, rvs) = pools
-    pal = kv_append_quant(pk, pv, pks, pvs, k_new, v_new, pids, offs,
-                          valid, discard_pid, interpret=interpret)
-    r = ref.kv_append_quant_ref(rk, rv, rks, rvs, k_new, v_new, pids,
+    pal = kv_append_quant(pk, pv, pks, pvs, layer, k_new, v_new, pids,
+                          offs, valid, discard_pid, interpret=interpret)
+    r = ref.kv_append_quant_ref(rk, rv, rks, rvs, layer, k_new, v_new, pids,
                                 offs, valid)
     return pal, r
 
 
-def _zero_pools(qdtype, n_pages=8, page=4, Hkv=2, hd=16):
+def _zero_pools(qdtype, n_pages=8, page=4, Hkv=2, hd=16, L=1, layer=0):
+    """(pallas, ref) stacked pools of L layers: layer ``layer`` zeroed (an
+    empty pool), every other layer holding payload and scales of its own."""
+    key = jax.random.fold_in(KEY, 5)
+
+    def leaf(shape, dtype, i):
+        full = (jax.random.normal(jax.random.fold_in(key, i), (L,) + shape)
+                * (0.01 if dtype == jnp.float32 else 50.0))
+        full = jnp.abs(full) if dtype == jnp.float32 else full
+        full = full.astype(dtype)
+        return full.at[layer].set(jnp.zeros(shape, dtype))
+
+    shapes = [((n_pages, page, Hkv, hd), qdtype)] * 2 \
+        + [((n_pages, Hkv), jnp.float32)] * 2
     # separate buffers per leaf: the Pallas path donates its pools
-    def zk():
-        return jnp.zeros((n_pages, page, Hkv, hd), qdtype)
-
-    def zs():
-        return jnp.zeros((n_pages, Hkv), jnp.float32)
-    return (zk(), zk(), zs(), zs()), (zk(), zk(), zs(), zs())
+    return tuple(tuple(leaf(sh, dt, i) for i, (sh, dt) in enumerate(shapes))
+                 for _ in range(2))
 
 
-@pytest.mark.parametrize("name", ALL_KV_DTYPES)
-def test_kv_append_quant_pallas_matches_ref(name):
+def _bytes(x):
+    return np.asarray(x).view(np.uint8)
+
+
+@pytest.mark.parametrize("name,L", [
+    pytest.param(n, 1, id=n) for n in ALL_KV_DTYPES] + [
+    # layer 1 of a three-layer stack: layers 0 and 2 stay as they were
+    pytest.param("int8", 3, id="int8-layer1-of-3")])
+def test_kv_append_quant_pallas_matches_ref(name, L):
     """Two append rounds (the second re-quantizes already-written pages):
     the Pallas path is bit-exact against the jnp reference everywhere but
-    the write-discard page."""
+    the write-discard page; on a stack, the other layers are bit-identical
+    to what they held, discard page included."""
     qdtype = kv_quant_jnp_dtype(name)
-    pal, r = _zero_pools(qdtype)
-    pal, r = _append_round(jax.random.fold_in(KEY, 1), qdtype, (pal, r))
-    pal, r = _append_round(jax.random.fold_in(KEY, 2), qdtype, (pal, r))
+    layer = 1 if L > 1 else 0
+    pal, r = _zero_pools(qdtype, L=L, layer=layer)
+    before = [np.asarray(x).copy() for x in r]
+    pal, r = _append_round(jax.random.fold_in(KEY, 1), qdtype, (pal, r),
+                           layer=layer)
+    pal, r = _append_round(jax.random.fold_in(KEY, 2), qdtype, (pal, r),
+                           layer=layer)
     live = np.setdiff1d(np.arange(8), [7])      # exclude the discard page
     for got, want, label in [(pal[0], r[0], "k"), (pal[1], r[1], "v")]:
-        assert np.array_equal(np.asarray(got)[live].view(np.uint8),
-                              np.asarray(want)[live].view(np.uint8)), label
+        assert np.array_equal(_bytes(got[layer])[live],
+                              _bytes(want[layer])[live]), label
     assert jnp.array_equal(pal[2], r[2]) and jnp.array_equal(pal[3], r[3])
+    others = np.asarray([i for i in range(L) if i != layer], np.int32)
+    for got, old in zip(pal, before):
+        assert np.array_equal(_bytes(got[others]), _bytes(old[others]))
 
 
 @pytest.mark.parametrize("name", ALL_KV_DTYPES)
@@ -112,17 +137,17 @@ def test_kv_append_quant_dequant_recovers_rows(name):
     storage dtype's precision envelope (relative to the row max)."""
     qdtype = kv_quant_jnp_dtype(name)
     n_pages, page, Hkv, hd = 8, 4, 2, 16
-    pk = jnp.zeros((n_pages, page, Hkv, hd), qdtype)
-    ks = jnp.zeros((n_pages, Hkv), jnp.float32)
+    pk = jnp.zeros((1, n_pages, page, Hkv, hd), qdtype)
+    ks = jnp.zeros((1, n_pages, Hkv), jnp.float32)
     k_new = _rand_rows(jax.random.fold_in(KEY, 3), 4, Hkv, hd)
     pids = jnp.asarray([1, 1, 2, 4], jnp.int32)
     offs = jnp.asarray([0, 1, 0, 0], jnp.int32)
     valid = jnp.ones(4, jnp.int32)
     # the K and V pools are donated, so each gets its own buffer
-    pk, _, ks, _ = kv_append_quant(pk, pk.copy(), ks, ks, k_new, k_new,
+    pk, _, ks, _ = kv_append_quant(pk, pk.copy(), ks, ks, 0, k_new, k_new,
                                    pids, offs, valid, discard_pid=7,
                                    interpret=True)
-    deq = ref.dequant_gathered(pk[pids], ks[pids])   # (4, page, Hkv, hd)
+    deq = ref.dequant_gathered(pk[0, pids], ks[0, pids])  # (4, page, ...)
     got = deq[jnp.arange(4), offs]                   # the written slots
     err = np.abs(np.asarray(got) - np.asarray(k_new)).max()
     rel = err / np.abs(np.asarray(k_new)).max()
@@ -135,24 +160,24 @@ def test_scale_update_is_monotone_and_requant_preserves_old_rows():
     the (new, coarser) quantization step."""
     qdtype = kv_quant_jnp_dtype("int8")
     n_pages, page, Hkv, hd = 4, 4, 1, 8
-    pk = jnp.zeros((n_pages, page, Hkv, hd), qdtype)
-    ks = jnp.zeros((n_pages, Hkv), jnp.float32)
+    pk = jnp.zeros((1, n_pages, page, Hkv, hd), qdtype)
+    ks = jnp.zeros((1, n_pages, Hkv), jnp.float32)
     small = jnp.full((1, Hkv, hd), 0.5, jnp.float32)
     big = jnp.full((1, Hkv, hd), 8.0, jnp.float32)
     ids0 = jnp.zeros(1, jnp.int32)
     one = jnp.ones(1, jnp.int32)
-    pk, _, ks, _ = kv_append_quant(pk, pk.copy(), ks, ks, small, small,
+    pk, _, ks, _ = kv_append_quant(pk, pk.copy(), ks, ks, 0, small, small,
                                    ids0, 0 * one, one, discard_pid=3,
                                    interpret=True)
-    s0 = float(ks[0, 0])
-    pk, _, ks, _ = kv_append_quant(pk, pk.copy(), ks, ks, big, big, ids0,
+    s0 = float(ks[0, 0, 0])
+    pk, _, ks, _ = kv_append_quant(pk, pk.copy(), ks, ks, 0, big, big, ids0,
                                    1 * one, one, discard_pid=3,
                                    interpret=True)
-    s1 = float(ks[0, 0])
+    s1 = float(ks[0, 0, 0])
     assert s1 > s0 > 0.0                      # monotone while alive
-    deq = float(pk[0, 0, 0, 0]) * s1
+    deq = float(pk[0, 0, 0, 0, 0]) * s1
     assert abs(deq - 0.5) <= s1               # within one coarse step
-    assert abs(float(pk[0, 1, 0, 0]) * s1 - 8.0) <= s1
+    assert abs(float(pk[0, 0, 1, 0, 0]) * s1 - 8.0) <= s1
 
 
 @pytest.mark.parametrize("name", ALL_KV_DTYPES)
@@ -172,15 +197,16 @@ def test_quant_paged_attention_matches_ref(name):
     vq = quantize_rows(vf, vscale[:, None], qdtype)
     bt = jnp.asarray([[0, 1, 2], [3, 4, 5]], jnp.int32)
     ctx = jnp.asarray([7, 12], jnp.int32)
-    pal = paged_attention_op(q, kq, vq, bt, ctx, k_scale=scale,
-                             v_scale=vscale, use_pallas=True,
-                             interpret=True)
-    rf = ref.paged_attention_quant_ref(q, kq, vq, scale, vscale, bt, ctx,
+    pal = paged_attention_op(q, kq[None], vq[None], 0, bt, ctx,
+                             k_scale=scale[None], v_scale=vscale[None],
+                             use_pallas=True, interpret=True)
+    rf = ref.paged_attention_quant_ref(q, kq[None], vq[None], scale[None],
+                                       vscale[None], 0, bt, ctx,
                                        softcap=None, scale=None,
                                        window=None)
     np.testing.assert_allclose(np.asarray(pal), np.asarray(rf), atol=1e-5)
-    exact = ref.paged_attention_ref(q, kf, vf, bt, ctx, softcap=None,
-                                    scale=None, window=None)
+    exact = ref.paged_attention_ref(q, kf[None], vf[None], 0, bt, ctx,
+                                    softcap=None, scale=None, window=None)
     tol = {"int8": 0.05, "float8_e4m3": 0.2, "float8_e5m2": 0.5}[name]
     np.testing.assert_allclose(np.asarray(pal), np.asarray(exact),
                                atol=tol)
@@ -201,24 +227,40 @@ def test_ragged_quant_attention_matches_ref():
     bt = jnp.asarray([[0, 1, 2], [3, 4, 5]], jnp.int32)
     tok_seq = jnp.asarray([0, 0, 0, 1, 1], jnp.int32)
     tok_pos = jnp.asarray([4, 5, 6, 0, 1], jnp.int32)
-    pal = ragged_paged_attention_op(q, kq, vq, bt, tok_seq, tok_pos,
-                                    k_scale=kscale, v_scale=vscale,
-                                    use_pallas=True, interpret=True)
+    pal = ragged_paged_attention_op(q, kq[None], vq[None], 0, bt, tok_seq,
+                                    tok_pos, k_scale=kscale[None],
+                                    v_scale=vscale[None], use_pallas=True,
+                                    interpret=True)
     rf = ref.ragged_paged_attention_quant_ref(
-        q, kq, vq, kscale, vscale, bt, tok_seq, tok_pos, softcap=None,
-        scale=None, window=None)
+        q, kq[None], vq[None], kscale[None], vscale[None], 0, bt, tok_seq,
+        tok_pos, softcap=None, scale=None, window=None)
     np.testing.assert_allclose(np.asarray(pal), np.asarray(rf), atol=1e-5)
 
 
-@pytest.mark.parametrize("name", ["int8", "float8_e4m3"])
-def test_ragged_quant_attention_tiled(name):
+def _stack_at_1(x, key):
+    """A three-layer stack whose layer 1 is ``x``; the others hold other
+    values of its dtype (scales stay positive)."""
+    scale = 0.01 if x.dtype == jnp.float32 else 50.0
+    other = jnp.abs(jax.random.normal(key, (3,) + x.shape)) * scale
+    return other.astype(x.dtype).at[1].set(x)
+
+
+@pytest.mark.parametrize("name,G,L", [
+    pytest.param("int8", 4, 1, id="int8"),
+    pytest.param("float8_e4m3", 4, 1, id="float8_e4m3"),
+    # layer 1 of a three-layer stack, G 1 and G 8
+    pytest.param("int8", 1, 3, id="int8-G1-layer1-of-3"),
+    pytest.param("int8", 8, 3, id="int8-G8-layer1-of-3"),
+])
+def test_ragged_quant_attention_tiled(name, G, L):
     """The tiled grid over quantized pools: a chunk over several query
-    tiles (GQA 4: 32 rows a tile) from mid-page, decodes (one at full
-    context), padded rows; per-page per-head scales ride each page's
-    block."""
+    tiles (GQA 4: 32 rows a tile; G 1 and 8 on a stack) from mid-page,
+    decodes (one at full context), padded rows; per-page per-head scales
+    ride each page's block. On a stack of three layers, layer 1 reads
+    bit-equal to the kernel on that layer's pool and scales alone."""
     from repro.kernels.ops import ragged_paged_attention_op
     qdtype = kv_quant_jnp_dtype(name)
-    N, Hkv, G, hd, page, n_pages, max_pages = 96, 2, 4, 16, 8, 64, 16
+    N, Hkv, hd, page, n_pages, max_pages = 96, 2, 16, 8, 64, 16
     ks = jax.random.split(jax.random.fold_in(KEY, 11), 3)
     q = jax.random.normal(ks[0], (N, Hkv, G, hd), jnp.float32)
     kf = jax.random.normal(ks[1], (n_pages, page, Hkv, hd), jnp.float32)
@@ -233,15 +275,29 @@ def test_ragged_quant_attention_tiled(name):
     pos = list(range(11, 81)) + [max_pages * page - 1, 40]
     tok_seq = jnp.asarray(seq + [0] * (N - 72), jnp.int32)
     tok_pos = jnp.asarray(pos + [-1] * (N - 72), jnp.int32)
-    pal = ragged_paged_attention_op(q, kq, vq, bt, tok_seq, tok_pos,
-                                    k_scale=kscale, v_scale=vscale,
+    alone = (kq[None], vq[None], kscale[None], vscale[None])
+    if L == 1:
+        (k_st, v_st, ks_st, vs_st), layer = alone, 0
+    else:
+        sk = jax.random.split(ks[2], 4)
+        k_st, v_st, ks_st, vs_st = (_stack_at_1(x, k) for x, k in
+                                    zip((kq, vq, kscale, vscale), sk))
+        layer = 1
+    pal = ragged_paged_attention_op(q, k_st, v_st, layer, bt, tok_seq,
+                                    tok_pos, k_scale=ks_st, v_scale=vs_st,
                                     use_pallas=True, interpret=True)
     rf = ref.ragged_paged_attention_quant_ref(
-        q, kq, vq, kscale, vscale, bt, tok_seq, tok_pos, softcap=None,
-        scale=None, window=None)
+        q, k_st, v_st, ks_st, vs_st, layer, bt, tok_seq, tok_pos,
+        softcap=None, scale=None, window=None)
     np.testing.assert_allclose(np.asarray(pal)[:72], np.asarray(rf)[:72],
                                atol=1e-5)
     assert not np.asarray(pal)[72:].any()
+    if L > 1:
+        got = ragged_paged_attention_op(
+            q, alone[0], alone[1], 0, bt, tok_seq, tok_pos,
+            k_scale=alone[2], v_scale=alone[3], use_pallas=True,
+            interpret=True)
+        assert jnp.array_equal(pal, got)
 
 
 # ---------------------------------------------------------------------------
