@@ -14,7 +14,7 @@ Paper targets (InferCept, ICML 2024):
              held by paused >60% of time; Swap 26% waste
   §4.4     — dynamic estimator reaches 93% of oracle
   §5.1     — single-augment workloads (QA, Chatbot) + multi-GPU scaling
-  kernels  — Pallas flash/paged/swap-pack vs refs (interpret-mode checked,
+  kernels  — Pallas flash/GLA/swap-pack vs refs (interpret-mode checked,
              XLA-path timed)
   cache    — beyond-paper prefix-KV-cache sweep on the agent workload
              (hit rate / tokens saved vs prefix-share; JSON emitted)
@@ -189,7 +189,6 @@ def bench_kernels(quick=False):
     import jax.numpy as jnp
     from repro.kernels import ref
     from repro.kernels.flash_attention import flash_attention
-    from repro.kernels.paged_attention import paged_attention
     from repro.kernels.swap_pack import swap_pack
 
     key = jax.random.PRNGKey(0)
@@ -214,21 +213,6 @@ def bench_kernels(quick=False):
          {"interpret_max_err": f"{err:.2e}",
           "shape": f"B{B}xHkv{Hkv}xG{G}xT{T}xhd{hd}"})
 
-    # paged attention
-    rng = np.random.default_rng(0)
-    q2 = jax.random.normal(key, (4, 2, 4, 64), jnp.float32)
-    kp = jax.random.normal(key, (64, 16, 2, 64), jnp.float32)
-    vp = jax.random.normal(key, (64, 16, 2, 64), jnp.float32)
-    bt = jnp.asarray(rng.integers(0, 64, (4, 8)), jnp.int32)
-    lens = jnp.asarray([100, 30, 128, 64], jnp.int32)
-    us_ref = timed(jax.jit(lambda *a: ref.paged_attention_ref(*a)),
-                   q2, kp, vp, bt, lens)
-    out = paged_attention(q2, kp, vp, bt, lens, interpret=True)
-    err = float(jnp.max(jnp.abs(out - ref.paged_attention_ref(
-        q2, kp, vp, bt, lens))))
-    _row("kernel_paged_attention", us_ref,
-         {"interpret_max_err": f"{err:.2e}", "pages": 64, "page": 16})
-
     # chunked GLA scan (mamba2 / mLSTM SSD core)
     from repro.kernels.gla_scan import gla_scan
     from repro.models.ssm import chunked_gla
@@ -244,6 +228,7 @@ def bench_kernels(quick=False):
          {"interpret_max_err": f"{err:.2e}", "chunk": 128, "T": 256})
 
     # swap pack
+    rng = np.random.default_rng(0)
     pool = jax.random.normal(key, (64, 16, 2, 64), jnp.bfloat16)
     ids = jnp.asarray(rng.choice(64, 16, replace=False), jnp.int32)
     us_ref = timed(jax.jit(lambda *a: ref.swap_pack_ref(*a)), pool, ids)
@@ -302,144 +287,6 @@ def bench_prefix_cache_sweep(quick=False):
                   if k not in ("prefix_share", "policy")})
     out = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "prefix_cache_sweep.json")
-    with open(out, "w") as f:
-        json.dump(results, f, indent=2)
-
-
-def bench_decode_sweep(quick=False):
-    """In-place paged execution vs the gather/scatter oracle (DESIGN.md §9):
-    KV bytes moved per generated token and decode throughput on the real
-    engine, swept over context length; greedy token streams are asserted
-    bit-identical between the two paths. Writes
-    benchmarks/decode_sweep.json next to this file."""
-    import json
-    import os
-    from repro.configs import get_config
-    from repro.core import POLICIES
-    from repro.core.request import Request, Segment
-    from repro.serving.engine import Engine
-
-    cfg = get_config("llama3.2-1b", tiny=True)
-    ctxs = [128, 512] if quick else [128, 256, 512]
-    gen = 8 if quick else 12
-    page, n_reqs = 16, 2
-    results = []
-    for ctx in ctxs:
-        max_len = ctx + 2 * gen + page
-        n_pages = n_reqs * (max_len // page + 1) + 16
-        streams = {}
-        rows = {}
-        for mode in ("paged", "gather"):
-            eng = Engine(cfg, POLICIES["vllm"], page_size=page,
-                         n_pages=n_pages, max_model_len=max_len,
-                         paged=(mode == "paged"))
-            for i in range(n_reqs):
-                eng.add_request(Request(
-                    rid=i, arrival=0.0, prompt_len=ctx,
-                    segments=[Segment(gen_tokens=gen, interception=None)]))
-            t0 = time.time()
-            fin = eng.run()
-            wall = time.time() - t0
-            assert len(fin) == n_reqs, f"{mode} ctx={ctx} incomplete"
-            streams[mode] = {r.rid: eng.generated_text(r) for r in fin}
-            rows[mode] = {
-                "ctx": ctx,
-                "mode": mode,
-                "decode_tokens": eng.counters["decode_tokens"],
-                "kv_token_bytes": eng.kv_token_bytes,
-                "bytes_per_decode_token":
-                    round(eng.kv_bytes_per_decode_token(), 1),
-                "bytes_per_prefill_token":
-                    round(eng.kv_bytes_per_prefill_token(), 1),
-                "decode_tokens_per_s":
-                    round(eng.counters["decode_tokens"] / max(1e-9, wall),
-                          2),
-                "wall_s": round(wall, 3),
-            }
-        identical = streams["paged"] == streams["gather"]
-        ratio = (rows["gather"]["bytes_per_decode_token"]
-                 / max(1.0, rows["paged"]["bytes_per_decode_token"]))
-        for mode in ("paged", "gather"):
-            rows[mode]["streams_identical"] = identical
-            rows[mode]["gather_over_paged_bytes_ratio"] = round(ratio, 1)
-            results.append(rows[mode])
-            _row(f"decode_sweep_ctx{ctx}_{mode}",
-                 rows[mode]["wall_s"] * 1e6,
-                 {k: v for k, v in rows[mode].items()
-                  if k not in ("ctx", "mode", "wall_s")})
-        assert identical, f"paged/gather streams diverged at ctx={ctx}"
-    out = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       "decode_sweep.json")
-    with open(out, "w") as f:
-        json.dump(results, f, indent=2)
-
-
-def bench_mixed_sweep(quick=False):
-    """Fused mixed-batch iteration vs the unfused per-call path
-    (DESIGN.md §10) on a bursty agent workload whose iterations carry
-    prefill chunks AND decode batches at once: device dispatches per
-    non-empty iteration, decode throughput, and logit bytes crossing the
-    host boundary per step; greedy token streams are asserted identical
-    between the two paths. Writes benchmarks/mixed_sweep.json."""
-    import json
-    import os
-    from repro.configs import get_config
-    from repro.core import POLICIES
-    from repro.serving.engine import Engine
-    from repro.serving.workloads import make_agent_workload
-
-    cfg = get_config("llama3.2-1b", tiny=True)
-    sessions = [2, 4] if quick else [2, 4, 6]
-    results = []
-    for n_sessions in sessions:
-        reqs = make_agent_workload(
-            seed=7, n_sessions=n_sessions, rate_rps=500.0,
-            vocab=cfg.vocab_size, n_templates=2, system_prompt_len=50,
-            turns=(2, 2), turn_gap_s=0.01, hist_per_turn=12,
-            prefix_share=0.75, gen_tokens=(10, 3), final_gen=(10, 3),
-            ret_tokens=(6, 2), max_tool_calls=2, max_ctx=240)
-        streams = {}
-        rows = {}
-        for mode in ("fused", "unfused"):
-            eng = Engine(cfg, POLICIES["vllm"], page_size=16,
-                         n_pages=64 * n_sessions, max_model_len=256,
-                         paged=True, fused=(mode == "fused"))
-            for r in copy.deepcopy(reqs):
-                eng.add_request(r)
-            t0 = time.time()
-            fin = eng.run()
-            wall = time.time() - t0
-            assert len(fin) == len(reqs), f"{mode} x{n_sessions} incomplete"
-            streams[mode] = {r.rid: eng.generated_text(r) for r in fin}
-            c = eng.counters
-            iters = max(1, c["mixed_iterations"])
-            rows[mode] = {
-                "n_sessions": n_sessions,
-                "mode": mode,
-                "mixed_iterations": c["mixed_iterations"],
-                "device_dispatches": c["device_dispatches"],
-                "dispatches_per_iteration":
-                    round(c["device_dispatches"] / iters, 3),
-                "logit_bytes_per_step":
-                    round(c["logit_bytes"] / iters, 1),
-                "decode_tokens": c["decode_tokens"],
-                "tokens_per_s":
-                    round((c["decode_tokens"] + c["prefill_tokens"])
-                          / max(1e-9, wall), 2),
-                "wall_s": round(wall, 3),
-            }
-        identical = streams["fused"] == streams["unfused"]
-        for mode in ("fused", "unfused"):
-            rows[mode]["streams_identical"] = identical
-            results.append(rows[mode])
-            _row(f"mixed_sweep_x{n_sessions}_{mode}",
-                 rows[mode]["wall_s"] * 1e6,
-                 {k: v for k, v in rows[mode].items()
-                  if k not in ("n_sessions", "mode", "wall_s")})
-        assert identical, f"fused/unfused streams diverged x{n_sessions}"
-        assert rows["fused"]["dispatches_per_iteration"] == 1.0
-    out = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       "mixed_sweep.json")
     with open(out, "w") as f:
         json.dump(results, f, indent=2)
 
@@ -969,8 +816,7 @@ def bench_quant_sweep(quick=False):
         t0 = time.time()
         eng = Engine(cfg, POLICIES["infercept"], page_size=page,
                      n_pages=n_pages, max_model_len=max_ctx, seed=0,
-                     paged=True, fused=True, prefix_cache=True,
-                     kv_dtype=kv_dtype)
+                     prefix_cache=True, kv_dtype=kv_dtype)
         for r in copy.deepcopy(reqs):
             eng.add_request(r)
         fin = eng.run()
@@ -1061,7 +907,7 @@ def bench_multi_gpu_scaling(quick=False):
 ALL = [bench_table1_workload, bench_fig2_end2end, bench_fig3_breakdown,
        bench_waste_s32, bench_estimator, bench_single_augment,
        bench_kernels, bench_multi_gpu_scaling, bench_prefix_cache_sweep,
-       bench_decode_sweep, bench_mixed_sweep, bench_serve_sweep,
+       bench_serve_sweep,
        bench_overlap_sweep, bench_waste_trace, bench_predictive_sweep,
        bench_fault_sweep, bench_quant_sweep]
 
@@ -1070,12 +916,6 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
-    ap.add_argument("--decode-sweep", action="store_true",
-                    help="run only the paged-vs-gather decode sweep "
-                         "(alias for --only decode_sweep)")
-    ap.add_argument("--mixed-sweep", action="store_true",
-                    help="run only the fused-vs-unfused mixed-batch sweep "
-                         "(alias for --only mixed_sweep)")
     ap.add_argument("--serve-sweep", action="store_true",
                     help="run only the session-API per-policy TTFT / "
                          "normalized-latency sweep "
@@ -1104,10 +944,6 @@ def main() -> None:
                          "findings and streams bit-identical to the "
                          "unsanitized run")
     args = ap.parse_args()
-    if args.decode_sweep:
-        args.only = "decode_sweep"
-    if args.mixed_sweep:
-        args.only = "mixed_sweep"
     if args.serve_sweep:
         args.only = "serve_sweep"
     if args.overlap_sweep:

@@ -15,16 +15,15 @@ from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.kv_append import kv_append
 from repro.kernels.kv_quant import kv_append_quant
-from repro.kernels.paged_attention import paged_attention
 from repro.kernels.ragged_paged_attention import ragged_paged_attention
 from repro.kernels.gla_scan import gla_scan
 from repro.kernels.swap_pack import swap_pack, swap_unpack
 
-__all__ = ["flash_attention_op", "paged_attention_op",
+__all__ = ["flash_attention_op",
            "ragged_paged_attention_op", "kv_append_op",
            "kv_append_quant_op",
            "swap_pack_op", "swap_unpack_op", "gla_scan_op",
-           "flash_attention", "paged_attention", "ragged_paged_attention",
+           "flash_attention", "ragged_paged_attention",
            "kv_append", "kv_append_quant", "swap_pack", "swap_unpack",
            "gla_scan"]
 
@@ -49,25 +48,6 @@ def flash_attention_op(q, k, v, *, causal=True, window=None, softcap=None,
                                softcap=softcap, interpret=interpret)
     return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                    softcap=softcap)
-
-
-def paged_attention_op(q, k_pool, v_pool, layer, block_tables, ctx_lens, *,
-                       k_scale=None, v_scale=None,
-                       softcap=None, window=None, use_pallas=None,
-                       interpret=None):
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas:
-        return paged_attention(q, k_pool, v_pool, layer, block_tables,
-                               ctx_lens, k_scale=k_scale, v_scale=v_scale,
-                               softcap=softcap, window=window,
-                               interpret=interpret)
-    if k_scale is not None:
-        return ref.paged_attention_quant_ref(
-            q, k_pool, v_pool, k_scale, v_scale, layer, block_tables,
-            ctx_lens, softcap=softcap, window=window)
-    return ref.paged_attention_ref(q, k_pool, v_pool, layer, block_tables,
-                                   ctx_lens, softcap=softcap, window=window)
 
 
 def ragged_paged_attention_op(q, k_pool, v_pool, layer, block_tables,

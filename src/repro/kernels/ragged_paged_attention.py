@@ -13,8 +13,8 @@ token sees the prefix plus the earlier tokens *of its own chunk* — the
 chunk-internal causal contract — while later chunk tokens and every other
 sequence's pages are invisible.
 
-``paged_attention`` (one decode token per sequence with per-sequence
-ctx_lens) is the special case tok_seq = arange(B), tok_pos = ctx_lens - 1.
+A decode-only launch (one token per sequence with per-sequence context
+lengths ctx_lens) is the case tok_seq = arange(B), tok_pos = ctx_lens - 1.
 Padded token rows carry ``tok_pos[i] == -1``: their output is zeros and the
 caller ignores it.
 

@@ -35,45 +35,6 @@ def flash_attention_ref(q, k, v, *, causal=True, window=None,
     return out.astype(q.dtype)
 
 
-def paged_attention_ref(q, kv_pages_k, kv_pages_v, layer, block_tables,
-                        ctx_lens, *, softcap=None, scale=None, window=None,
-                        _k=None, _v=None):
-    """Decode attention over layer ``layer`` of a paged KV pool.
-
-    q: (B, Hkv, G, hd); pools: (L, n_pages, page, Hkv, hd);
-    block_tables: (B, max_pages) int32; ctx_lens: (B,) tokens valid.
-    ``window`` keeps only the last ``window`` positions of each context.
-    ``_k``/``_v`` bypass the pool gather with pre-gathered (B, S, Hkv, hd)
-    caches (the dequantized view the quant oracle hands in).
-    """
-    B, Hkv, G, hd = q.shape
-    if _k is not None:
-        k, v = _k, _v
-        max_pages, page = block_tables.shape[1], k.shape[1] // block_tables.shape[1]
-        scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    else:
-        page = kv_pages_k.shape[2]
-        max_pages = block_tables.shape[1]
-        scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-        # gather to (B, max_pages*page, Hkv, hd)
-        k = kv_pages_k[layer, block_tables].reshape(B, max_pages * page,
-                                                    Hkv, hd)
-        v = kv_pages_v[layer, block_tables].reshape(B, max_pages * page,
-                                                    Hkv, hd)
-    s = jnp.einsum("bhgd,bshd->bhgs", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-    j = jnp.arange(max_pages * page)[None]
-    valid = j < ctx_lens[:, None]
-    if window is not None:
-        valid &= j > ctx_lens[:, None] - 1 - window
-    s = jnp.where(valid[:, None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgs,bshd->bhgd", p, v.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
 def ragged_paged_attention_ref(q, kv_pages_k, kv_pages_v, layer,
                                block_tables, tok_seq, tok_pos, *,
                                softcap=None, scale=None, window=None,
@@ -186,29 +147,13 @@ def dequant_gathered(pages, scale_pages):
     return pages.astype(jnp.float32) * scale_pages[..., None, :, None]
 
 
-def paged_attention_quant_ref(q, kv_pages_k, kv_pages_v, k_scale, v_scale,
-                              layer, block_tables, ctx_lens, *, softcap=None,
-                              scale=None, window=None):
-    """paged_attention_ref over quantized pools: gather pages AND their
-    scales through the block table, dequantize in f32, same math."""
-    k = dequant_gathered(kv_pages_k[layer, block_tables],
-                         k_scale[layer, block_tables])
-    v = dequant_gathered(kv_pages_v[layer, block_tables],
-                         v_scale[layer, block_tables])
-    B = q.shape[0]
-    page, Hkv, hd = kv_pages_k.shape[2:]
-    S = block_tables.shape[1] * page
-    return paged_attention_ref(q, None, None, layer, block_tables, ctx_lens,
-                               softcap=softcap, scale=scale, window=window,
-                               _k=k.reshape(B, S, Hkv, hd),
-                               _v=v.reshape(B, S, Hkv, hd))
-
-
 def ragged_paged_attention_quant_ref(q, kv_pages_k, kv_pages_v, k_scale,
                                      v_scale, layer, block_tables, tok_seq,
                                      tok_pos, *, softcap=None, scale=None,
                                      window=None):
-    """ragged_paged_attention_ref over quantized pools (see above)."""
+    """ragged_paged_attention_ref over quantized pools: gather pages AND
+    their scales through each token's block-table row, dequantize in f32,
+    same math."""
     bt = block_tables[tok_seq]
     k = dequant_gathered(kv_pages_k[layer, bt], k_scale[layer, bt])
     v = dequant_gathered(kv_pages_v[layer, bt], v_scale[layer, bt])

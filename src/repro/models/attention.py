@@ -1,10 +1,10 @@
 """Attention: blocked (flash-style, XLA) full-sequence attention, GQA and MLA
-projections, and single-token decode paths over slotted KV caches.
+projections, single-token decode and chunked-extend paths over slotted KV
+caches (the plain reference and the dry-run's steps), and the fused step's
+ragged pass over the engine's paged pools.
 
-The Pallas TPU kernels in ``repro.kernels`` implement the same math for the
-perf-critical paths (prefill flash attention / paged decode); these XLA
-implementations are the lowering-robust default used by pjit dry-runs and
-serve as additional oracles.
+On TPU the fused step runs the Pallas kernels in ``repro.kernels``
+(kv_append, ragged paged attention); elsewhere it runs their jnp mirrors.
 """
 from __future__ import annotations
 
@@ -21,18 +21,17 @@ NEG_INF = -1e30
 
 
 def _paged_use_pallas() -> bool:
-    """Paged-path kernel dispatch: Pallas on TPU, mirrored jnp elsewhere.
+    """Fused-step kernel dispatch: Pallas on TPU, mirrored jnp elsewhere.
 
-    The jnp mirror reproduces the contiguous decode/extend math op-for-op
-    (same einsum strings, same masking, same scaling) so paged and gathered
-    execution produce bit-identical logits on CPU — the property the
-    differential engine tests pin down.
+    The jnp mirror computes each token row's attention over its own
+    sequence's gathered block-table view (same einsum strings, masking and
+    scaling as the kernel's oracle, ``ref.ragged_paged_attention_ref``).
     """
     return jax.default_backend() == "tpu"
 
 
 def _require_discard(discard_pid) -> None:
-    """The GQA paged paths append through kv_append, whose contract routes
+    """The GQA fused path appends through kv_append, whose contract routes
     invalid rows to a write-discard page; without one there is no correct
     Pallas path, so a missing page is an error, never a quiet jnp
     fallback."""
@@ -397,7 +396,8 @@ def mla_decode(p, a: AttentionCfg, x, cache, pos, *, window_override="cfg"):
 
 
 # ==========================================================================
-# Paged decode / extend (in-place pool execution; DESIGN.md §9)
+# Fused mixed-batch pass over the paged pools (ragged chunks + decodes in
+# one dispatch; DESIGN.md §9/§10)
 # ==========================================================================
 # These operate on the engine's shared page pools directly: the new tokens'
 # K/V are scattered into their pool page slots (kv_append kernel on TPU, a
@@ -405,282 +405,16 @@ def mla_decode(p, a: AttentionCfg, x, cache, pos, *, window_override="cfg"):
 # block table — no per-request contiguous cache is ever materialized. The
 # pools are the layer group's stacks, (L, n_pages, ...), carried through the
 # layer scan; each call addresses layer ``layer`` of them in place
-# (pool[layer]) and never slices a layer's pool out. Rows
-# whose write must be discarded (batch/chunk padding) are masked, never
-# routed to a shared scratch page. On CPU the attention math mirrors the
-# contiguous gqa_decode/gqa_extend implementations op-for-op so both
-# execution paths emit bit-identical logits (the differential-test oracle).
-
-def gqa_decode_paged(p, a: AttentionCfg, x, pool, layer, block_tables,
-                     ctx_lens, *, window_override="cfg", discard_pid=None):
-    """One new token per sequence, written and attended in place.
-
-    x: (B, d); pool {"k","v"}: (L, n_pages, page, Hkv, hd) shared across
-    the batch, of which layer ``layer`` (int32 scalar) is used;
-    block_tables: (B, max_pages) int32 page ids; ctx_lens: (B,) int32
-    context length INCLUDING the new token. ctx_lens == 0 marks a
-    padded row: its K/V write is dropped and its output is garbage.
-    ``discard_pid`` (required) is the caller's write-discard page (the
-    engine's scratch page) — invalid rows' appends are routed there on the
-    Pallas path, which the kv_append kernel contract requires.
-    Returns (out (B, d), updated pool).
-    """
-    from repro.kernels.ops import kv_append_op, paged_attention_op
-    window = effective_window(a, window_override)
-    B, d = x.shape
-    _, n_pages, page, Hkv, hd = pool["k"].shape
-    S = block_tables.shape[1] * page
-    valid = ctx_lens > 0
-    pos = jnp.maximum(ctx_lens - 1, 0)
-    q = jnp.einsum("bd,dhk->bhk", x, p["wq"])
-    k = jnp.einsum("bd,dhk->bhk", x, p["wk"])
-    v = jnp.einsum("bd,dhk->bhk", x, p["wv"])
-    if a.qkv_bias:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = apply_rope(q[:, None], pos[:, None], a.rope_theta)[:, 0]
-    k = apply_rope(k[:, None], pos[:, None], a.rope_theta)[:, 0]
-
-    bidx = jnp.arange(B)
-    pids = block_tables[bidx, pos // page]
-    offs = pos % page
-    G = a.n_heads // Hkv
-    quant = "k_scale" in pool            # quantized pool (DESIGN.md §17)
-    use_pallas = _paged_use_pallas()
-    _require_discard(discard_pid)
-    if quant:
-        from repro.kernels.ops import kv_append_quant_op
-        k_pool, v_pool, k_scale, v_scale = kv_append_quant_op(
-            pool["k"], pool["v"], pool["k_scale"], pool["v_scale"], layer,
-            k, v,
-            pids.astype(jnp.int32), offs.astype(jnp.int32),
-            valid.astype(jnp.int32), discard_pid=discard_pid,
-            use_pallas=use_pallas)
-        new_pool = {"k": k_pool, "v": v_pool,
-                    "k_scale": k_scale, "v_scale": v_scale}
-    else:
-        k_scale = v_scale = None
-        if use_pallas:
-            pids = jnp.where(valid, pids, discard_pid)
-        k_pool, v_pool = kv_append_op(
-            pool["k"], pool["v"], layer, k, v, pids.astype(jnp.int32),
-            offs.astype(jnp.int32), valid.astype(jnp.int32),
-            use_pallas=use_pallas)
-        new_pool = {"k": k_pool, "v": v_pool}
-    if _paged_use_pallas():
-        out = paged_attention_op(q.reshape(B, Hkv, G, hd), k_pool, v_pool,
-                                 layer, block_tables, ctx_lens,
-                                 k_scale=k_scale, v_scale=v_scale,
-                                 softcap=a.logit_softcap, window=window,
-                                 use_pallas=True)
-    else:
-        if quant:
-            from repro.kernels.ref import dequant_gathered
-            k_cache = dequant_gathered(
-                k_pool[layer, block_tables],
-                k_scale[layer, block_tables]).reshape(B, S, Hkv, hd)
-            v_cache = dequant_gathered(
-                v_pool[layer, block_tables],
-                v_scale[layer, block_tables]).reshape(B, S, Hkv, hd)
-        else:
-            k_cache = k_pool[layer, block_tables].reshape(B, S, Hkv, hd)
-            v_cache = v_pool[layer, block_tables].reshape(B, S, Hkv, hd)
-        qh = q.reshape(B, Hkv, G, hd).astype(jnp.float32)
-        s = jnp.einsum("bhgk,bshk->bhgs", qh,
-                       k_cache.astype(jnp.float32)) / math.sqrt(hd)
-        if a.logit_softcap is not None:
-            s = softcap(s, a.logit_softcap)
-        j = jnp.arange(S)[None, :]
-        live = j < ctx_lens[:, None]
-        if window is not None:
-            live &= j > ctx_lens[:, None] - 1 - window
-        s = jnp.where(live[:, None, None, :], s, NEG_INF)
-        w = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bhgs,bshk->bhgk", w, v_cache.astype(jnp.float32))
-    out = out.reshape(B, a.n_heads, hd).astype(x.dtype)
-    return jnp.einsum("bhk,hkd->bd", out, p["wo"]), new_pool
-
-
-def gqa_extend_paged(p, a: AttentionCfg, x, pool, layer, block_tables, start,
-                     n_new, *, window_override="cfg", discard_pid=None):
-    """Chunked prefill writing pool pages as they are computed.
-
-    x: (B, T, d) at absolute positions start[b] + t; only the first
-    n_new[b] tokens per row are real — the rest are bucket padding whose
-    K/V writes are dropped (their outputs are garbage and must be ignored
-    by the caller). Padding positions can resolve to a request's own live
-    tail page, so on the Pallas path they are rerouted to ``discard_pid``
-    (see gqa_decode_paged). All written positions must fit the block table
-    (start + T <= max_pages * page). Returns (out (B, T, d), updated pool).
-    """
-    from repro.kernels.ops import kv_append_op
-    window = effective_window(a, window_override)
-    B, T, d = x.shape
-    _, n_pages, page, Hkv, hd = pool["k"].shape
-    S = block_tables.shape[1] * page
-    positions = start[:, None] + jnp.arange(T)[None, :]          # (B, T)
-    q, k, v = gqa_qkv(p, a, x, positions)
-    t_valid = (jnp.arange(T)[None, :] < n_new[:, None])
-    pids = jnp.take_along_axis(block_tables, positions // page, axis=1)
-    offs = positions % page
-    quant = "k_scale" in pool            # quantized pool (DESIGN.md §17)
-    use_pallas = _paged_use_pallas()
-    _require_discard(discard_pid)
-    if quant:
-        from repro.kernels.ops import kv_append_quant_op
-        from repro.kernels.ref import dequant_gathered
-        k_pool, v_pool, k_scale, v_scale = kv_append_quant_op(
-            pool["k"], pool["v"], pool["k_scale"], pool["v_scale"], layer,
-            k.reshape(B * T, Hkv, hd), v.reshape(B * T, Hkv, hd),
-            pids.reshape(-1).astype(jnp.int32),
-            offs.reshape(-1).astype(jnp.int32),
-            t_valid.reshape(-1).astype(jnp.int32),
-            discard_pid=discard_pid, use_pallas=use_pallas)
-        new_pool = {"k": k_pool, "v": v_pool,
-                    "k_scale": k_scale, "v_scale": v_scale}
-    else:
-        if use_pallas:
-            pids = jnp.where(t_valid, pids, discard_pid)
-        k_pool, v_pool = kv_append_op(
-            pool["k"], pool["v"], layer,
-            k.reshape(B * T, Hkv, hd), v.reshape(B * T, Hkv, hd),
-            pids.reshape(-1).astype(jnp.int32),
-            offs.reshape(-1).astype(jnp.int32),
-            t_valid.reshape(-1).astype(jnp.int32), use_pallas=use_pallas)
-        new_pool = {"k": k_pool, "v": v_pool}
-
-    # ragged-query attention over the pool; the gather-by-block-table is
-    # XLA's lowering (a fused ragged-prefill kernel is future work — the
-    # per-generated-token hot path is the decode kernel above)
-    if quant:
-        k_cache = dequant_gathered(
-            k_pool[layer, block_tables],
-            k_scale[layer, block_tables]).reshape(B, S, Hkv, hd)
-        v_cache = dequant_gathered(
-            v_pool[layer, block_tables],
-            v_scale[layer, block_tables]).reshape(B, S, Hkv, hd)
-    else:
-        k_cache = k_pool[layer, block_tables].reshape(B, S, Hkv, hd)
-        v_cache = v_pool[layer, block_tables].reshape(B, S, Hkv, hd)
-    G = a.n_heads // Hkv
-    qh = q.reshape(B, T, Hkv, G, hd).astype(jnp.float32)
-    s = jnp.einsum("bthgk,bshk->bhgts", qh,
-                   k_cache.astype(jnp.float32)) / math.sqrt(hd)
-    if a.logit_softcap is not None:
-        s = softcap(s, a.logit_softcap)
-    j = jnp.arange(S)[None, None, :]
-    qpos = positions[:, :, None]
-    live = j <= qpos
-    if window is not None:
-        live &= j > qpos - window
-    s = jnp.where(live[:, None, None], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgts,bshk->bthgk", w, v_cache.astype(jnp.float32))
-    out = out.reshape(B, T, a.n_heads, hd).astype(x.dtype)
-    return jnp.einsum("bthk,hkd->btd", out, p["wo"]), new_pool
-
-
-def mla_decode_paged(p, a: AttentionCfg, x, pool, layer, block_tables,
-                     ctx_lens, *, window_override="cfg", discard_pid=None):
-    """Absorbed MLA decode over paged latent pools (drop-mode XLA scatter
-    on every backend, so ``discard_pid`` is unused).
-
-    pool: {"c": (L, n_pages, page, kv_lora), "kr": (L, n_pages, page,
-    rope)}, used at layer ``layer``.
-    """
-    window = effective_window(a, window_override)
-    B, d = x.shape
-    _, n_pages, page, _ = pool["c"].shape
-    S = block_tables.shape[1] * page
-    valid = ctx_lens > 0
-    pos = jnp.maximum(ctx_lens - 1, 0)
-    qn, qr = _mla_q(p, a, x[:, None], pos[:, None])
-    qn, qr = qn[:, 0], qr[:, 0]
-    c_new, kr_new = _mla_latent(p, a, x[:, None], pos[:, None])
-
-    bidx = jnp.arange(B)
-    pids = jnp.where(valid, block_tables[bidx, pos // page], n_pages)
-    offs = pos % page
-    c_pool = pool["c"].at[layer, pids, offs].set(
-        c_new[:, 0].astype(pool["c"].dtype), mode="drop")
-    kr_pool = pool["kr"].at[layer, pids, offs].set(
-        kr_new[:, 0].astype(pool["kr"].dtype), mode="drop")
-
-    c_cache = c_pool[layer, block_tables].reshape(B, S, -1)
-    kr_cache = kr_pool[layer, block_tables].reshape(B, S, -1)
-    q_lat = jnp.einsum("bhn,lhn->bhl", qn.astype(jnp.float32),
-                       p["w_uk"].astype(jnp.float32))
-    qk = a.qk_nope_head_dim + a.qk_rope_head_dim
-    s = (jnp.einsum("bhl,bsl->bhs", q_lat, c_cache.astype(jnp.float32))
-         + jnp.einsum("bhr,bsr->bhs", qr.astype(jnp.float32),
-                      kr_cache.astype(jnp.float32))) / math.sqrt(qk)
-    j = jnp.arange(S)[None, :]
-    live = j < ctx_lens[:, None]
-    if window is not None:
-        live &= j > ctx_lens[:, None] - 1 - window
-    s = jnp.where(live[:, None, :], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    ctxv = jnp.einsum("bhs,bsl->bhl", w, c_cache.astype(jnp.float32))
-    out = jnp.einsum("bhl,lhv->bhv", ctxv,
-                     p["w_uv"].astype(jnp.float32)).astype(x.dtype)
-    return jnp.einsum("bhv,hvd->bd", out, p["wo"]), {"c": c_pool,
-                                                     "kr": kr_pool}
-
-
-def mla_extend_paged(p, a: AttentionCfg, x, pool, layer, block_tables, start,
-                     n_new, *, window_override="cfg", discard_pid=None):
-    """Absorbed MLA chunked prefill over paged latent pools (drop-mode XLA
-    scatter on every backend, so ``discard_pid`` is unused)."""
-    window = effective_window(a, window_override)
-    B, T, d = x.shape
-    _, n_pages, page, _ = pool["c"].shape
-    S = block_tables.shape[1] * page
-    positions = start[:, None] + jnp.arange(T)[None, :]
-    qn, qr = _mla_q(p, a, x, positions)
-    c_new, kr_new = _mla_latent(p, a, x, positions)
-    t_valid = jnp.arange(T)[None, :] < n_new[:, None]
-    pids = jnp.take_along_axis(block_tables, positions // page, axis=1)
-    pids = jnp.where(t_valid, pids, n_pages).reshape(-1)
-    offs = (positions % page).reshape(-1)
-    c_pool = pool["c"].at[layer, pids, offs].set(
-        c_new.reshape(B * T, -1).astype(pool["c"].dtype), mode="drop")
-    kr_pool = pool["kr"].at[layer, pids, offs].set(
-        kr_new.reshape(B * T, -1).astype(pool["kr"].dtype), mode="drop")
-
-    c_cache = c_pool[layer, block_tables].reshape(B, S, -1)
-    kr_cache = kr_pool[layer, block_tables].reshape(B, S, -1)
-    q_lat = jnp.einsum("bthn,lhn->bthl", qn.astype(jnp.float32),
-                       p["w_uk"].astype(jnp.float32))
-    qk = a.qk_nope_head_dim + a.qk_rope_head_dim
-    s = (jnp.einsum("bthl,bsl->bhts", q_lat, c_cache.astype(jnp.float32))
-         + jnp.einsum("bthr,bsr->bhts", qr.astype(jnp.float32),
-                      kr_cache.astype(jnp.float32))) / math.sqrt(qk)
-    j = jnp.arange(S)[None, None, :]
-    qpos = positions[:, :, None]
-    live = j <= qpos
-    if window is not None:
-        live &= j > qpos - window
-    s = jnp.where(live[:, None], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    ctxv = jnp.einsum("bhts,bsl->bthl", w, c_cache.astype(jnp.float32))
-    out = jnp.einsum("bthl,lhv->bthv", ctxv,
-                     p["w_uv"].astype(jnp.float32)).astype(x.dtype)
-    return jnp.einsum("bthv,hvd->btd", out, p["wo"]), {"c": c_pool,
-                                                       "kr": kr_pool}
-
-
-# ==========================================================================
-# Fused mixed-batch pass (ragged chunks + decodes in one dispatch; §10)
-# ==========================================================================
+# (pool[layer]) and never slices a layer's pool out.
+#
 # One scheduler iteration's whole token workload arrives flattened: token i
 # belongs to sequence tok_seq[i] at absolute position tok_pos[i] (-1 marks a
-# padded row). All N tokens' K/V are appended to the pool in ONE kv_append
-# call, then each token attends through its sequence's block table with the
-# causal mask `kv pos <= tok_pos[i]` — which simultaneously gives decode
-# tokens their full context and chunk tokens the prefix plus the earlier
-# tokens of their own chunk (the chunk-internal causal contract). The jnp
-# mirror repeats gqa_decode_paged's per-row math op-for-op with N rows, so
-# the fused pass emits bit-identical logits to the per-call paths on CPU —
-# the fused-vs-unfused differential property.
+# padded row, whose write is masked). All N tokens' K/V are appended to the
+# pool in ONE kv_append call, then each token attends through its
+# sequence's block table with the causal mask `kv pos <= tok_pos[i]` —
+# which simultaneously gives decode tokens their full context and chunk
+# tokens the prefix plus the earlier tokens of their own chunk (the
+# chunk-internal causal contract).
 
 def gqa_mixed_paged(p, a: AttentionCfg, x, pool, layer, block_tables,
                     tok_seq, tok_pos, *, window_override="cfg",
@@ -769,8 +503,9 @@ def mla_mixed_paged(p, a: AttentionCfg, x, pool, layer, block_tables,
                     tok_seq, tok_pos, *, window_override="cfg",
                     discard_pid=None):
     """Absorbed MLA mixed-batch pass over paged latent pools (drop-mode XLA
-    scatter + O(context) latent gather on every backend, mirroring
-    mla_decode_paged — ``discard_pid`` is unused)."""
+    scatter + O(context) latent gather per token on every backend, so
+    ``discard_pid`` is unused). pool: {"c": (L, n_pages, page, kv_lora),
+    "kr": (L, n_pages, page, rope)}, used at layer ``layer``."""
     window = effective_window(a, window_override)
     N, d = x.shape
     _, n_pages, page, _ = pool["c"].shape
@@ -816,20 +551,6 @@ def attention_mixed_paged(p, a, x, pool, layer, block_tables, tok_seq,
                           discard_pid=None):
     fn = mla_mixed_paged if a.kind == "mla" else gqa_mixed_paged
     return fn(p, a, x, pool, layer, block_tables, tok_seq, tok_pos,
-              window_override=window_override, discard_pid=discard_pid)
-
-
-def attention_decode_paged(p, a, x, pool, layer, block_tables, ctx_lens, *,
-                           window_override="cfg", discard_pid=None):
-    fn = mla_decode_paged if a.kind == "mla" else gqa_decode_paged
-    return fn(p, a, x, pool, layer, block_tables, ctx_lens,
-              window_override=window_override, discard_pid=discard_pid)
-
-
-def attention_extend_paged(p, a, x, pool, layer, block_tables, start, n_new,
-                           *, window_override="cfg", discard_pid=None):
-    fn = mla_extend_paged if a.kind == "mla" else gqa_extend_paged
-    return fn(p, a, x, pool, layer, block_tables, start, n_new,
               window_override=window_override, discard_pid=discard_pid)
 
 

@@ -1,5 +1,7 @@
-"""Residual blocks: init/apply dispatch over BlockCfg kinds, in both
-full-sequence (train/prefill) and single-token (decode) modes."""
+"""Residual blocks: init/apply dispatch over BlockCfg kinds, in
+full-sequence (train/prefill), single-token (decode) and chunked-extend
+modes over slotted caches, and the fused step's ragged pass over the
+paged pools."""
 from __future__ import annotations
 
 import jax
@@ -89,47 +91,15 @@ def block_decode(p, cfg: ModelConfig, blk: BlockCfg, x, cache, ctx):
     return x + h.astype(x.dtype), state
 
 
-def block_decode_paged(p, cfg: ModelConfig, blk: BlockCfg, x, pool, layer,
-                       ctx):
-    """Single-token pass over the shared paged pool (DESIGN.md §9).
-
-    x: (B, d); pool: this block's page pools stacked over the group's
-    layers, addressed at ``layer`` (no batch axis — sequences are routed
-    through ctx["block_tables"] / ctx["ctx_lens"]). Returns (x, updated
-    pool). Attention-cache blocks only.
-    """
-    if blk.kind not in ("attn", "shared_attn"):
-        raise ValueError(f"paged execution serves attention blocks, "
-                         f"got {blk.kind}")
-    eps = cfg.norm_eps
-    h, pool = attention.attention_decode_paged(
-        p["attn"], blk.attn, rms_norm(x, p["norm1"], eps), pool, layer,
-        ctx["block_tables"], ctx["ctx_lens"],
-        window_override=ctx.get("window_override", "cfg"),
-        discard_pid=ctx.get("discard_pid"))
-    if blk.post_norms:
-        h = rms_norm(h, p["post_norm1"], eps)
-    x = x + h.astype(x.dtype)
-    xin = rms_norm(x, p["norm2"], eps)
-    if blk.ffn.kind == "moe":
-        h, _ = moe.moe_forward(p["moe"], blk.ffn, xin[:, None])
-        h = h[:, 0]
-    else:
-        h = mlp.mlp_forward(p["mlp"], blk.ffn, xin)
-    if blk.post_norms:
-        h = rms_norm(h, p["post_norm2"], eps)
-    return x + h.astype(x.dtype), pool
-
-
 def block_mixed_paged(p, cfg: ModelConfig, blk: BlockCfg, x, pool, layer,
                       ctx):
     """Fused mixed-batch pass over the shared paged pool (DESIGN.md §10).
 
     x: (N, d) — one row per flat token of the iteration (chunk tokens and
     decode tokens alike), routed through ctx["block_tables"] by
-    ctx["tok_seq"] / ctx["tok_pos"]; pool and layer as in
-    block_decode_paged. Returns (x, updated pool). Attention-cache blocks
-    only.
+    ctx["tok_seq"] / ctx["tok_pos"]; pool: this block's page pools
+    stacked over the group's layers, addressed at ``layer``. Returns (x,
+    updated pool). Attention-cache blocks only.
     """
     if blk.kind not in ("attn", "shared_attn"):
         raise ValueError(f"paged execution serves attention blocks, "
@@ -149,34 +119,6 @@ def block_mixed_paged(p, cfg: ModelConfig, blk: BlockCfg, x, pool, layer,
         h = h[:, 0]
     else:
         h = mlp.mlp_forward(p["mlp"], blk.ffn, xin)
-    if blk.post_norms:
-        h = rms_norm(h, p["post_norm2"], eps)
-    return x + h.astype(x.dtype), pool
-
-
-def block_extend_paged(p, cfg: ModelConfig, blk: BlockCfg, x, pool, layer,
-                       ctx):
-    """Chunked-prefill pass writing pool pages in place. x: (B, T, d) at
-    positions ctx["start"][b] + t; only the first ctx["n_new"][b] tokens
-    per row are real; pool and layer as in block_decode_paged. Returns
-    (x, updated pool)."""
-    if blk.kind not in ("attn", "shared_attn"):
-        raise ValueError(f"paged execution serves attention blocks, "
-                         f"got {blk.kind}")
-    eps = cfg.norm_eps
-    h, pool = attention.attention_extend_paged(
-        p["attn"], blk.attn, rms_norm(x, p["norm1"], eps), pool, layer,
-        ctx["block_tables"], ctx["start"], ctx["n_new"],
-        window_override=ctx.get("window_override", "cfg"),
-        discard_pid=ctx.get("discard_pid"))
-    if blk.post_norms:
-        h = rms_norm(h, p["post_norm1"], eps)
-    x = x + h.astype(x.dtype)
-    if blk.ffn.kind == "moe":
-        h, _ = moe.moe_forward(p["moe"], blk.ffn,
-                               rms_norm(x, p["norm2"], eps))
-    else:
-        h = mlp.mlp_forward(p["mlp"], blk.ffn, rms_norm(x, p["norm2"], eps))
     if blk.post_norms:
         h = rms_norm(h, p["post_norm2"], eps)
     return x + h.astype(x.dtype), pool

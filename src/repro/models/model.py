@@ -32,11 +32,10 @@ class ForwardOut:
 
 
 def sample_tokens(flat_logits, temps, top_ks, top_ps, seeds, positions):
-    """Per-row token sampling, shared by the fused on-device path and the
-    host-side per-call oracle paths (DESIGN.md §11).
+    """Per-row token sampling inside the fused step (DESIGN.md §11).
 
-    flat_logits: (B, V) float; temps (B,) float — <= 0 means greedy argmax
-    (the differential oracle); top_ks (B,) int32 — <= 0 means the full
+    flat_logits: (B, V) float; temps (B,) float — <= 0 means greedy
+    argmax; top_ks (B,) int32 — <= 0 means the full
     vocabulary; top_ps (B,) float — nucleus mass threshold, values outside
     (0, 1) disable the filter; seeds (B,) int32 per-request sampling
     seeds; positions (B,) int32 — the absolute context index the sampled
@@ -51,8 +50,7 @@ def sample_tokens(flat_logits, temps, top_ks, top_ps, seeds, positions):
     function of its context, seed, and position — independent of batch
     composition, bucketing, and scheduling policy. The §6
     policy-equivalence property therefore survives stochastic sampling,
-    and the fused/unfused/gather paths stay bit-identical (they feed this
-    function the same logits).
+    and a speculative fork samples exactly what the resumed request would.
     """
     flat = flat_logits.astype(jnp.float32)
     B, V = flat.shape
@@ -308,36 +306,6 @@ class LM:
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         return self.logits(params, x), tuple(new_caches)
 
-    def decode_step_paged(self, params, tokens, ctx_lens, pools,
-                          block_tables, *, embeds=None,
-                          window_override="cfg", discard_pid=None):
-        """In-place paged decode (DESIGN.md §9): one new token per sequence
-        written directly into the shared page pools and attended through
-        per-request block tables — no contiguous per-request cache exists.
-
-        tokens: (B,) int32 (or (B, K) audio; or None with embeds (B, d));
-        ctx_lens: (B,) int32 context length INCLUDING the new token (0
-        marks a padding row — nothing is written, logits are garbage);
-        pools: the pytree from init_cache(n_pages, page_size);
-        block_tables: (B, max_pages) int32; discard_pid (required for GQA
-        blocks) names the caller's write-discard page for masked appends
-        on the Pallas path.
-        Returns (logits, new_pools).
-        """
-        cfg = self.cfg
-        if tokens is not None:
-            tok = tokens[:, None] if tokens.ndim == 1 else tokens[:, None, :]
-            x = self.embed(params, tok)[:, 0]
-        else:
-            x = embeds
-        ctx = {"block_tables": block_tables, "ctx_lens": ctx_lens,
-               "window_override": window_override,
-               "discard_pid": discard_pid}
-        x, new_pools = self._paged_layers(params, x, pools,
-                                          B.block_decode_paged, ctx)
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return self.logits(params, x), new_pools
-
     def forward_mixed_paged(self, params, tokens, tok_seq, tok_pos, q_last,
                             pools, block_tables, sampling=None, *,
                             embeds=None, window_override="cfg",
@@ -349,8 +317,7 @@ class LM:
         one ragged paged-attention pass, and sampling on device so only
         int32 token ids need to cross the host boundary.
 
-        ``sampling`` is None for pure-greedy batches (argmax, the
-        differential oracle) or a (temps (B,), top_ks (B,), top_ps (B,),
+        ``sampling`` is None for pure-greedy batches (argmax) or a (temps (B,), top_ks (B,), top_ps (B,),
         seeds (B,)) tuple applied per sequence row by ``sample_tokens`` —
         the sampled token's position is derived on device as
         tok_pos[q_last] + 1 (DESIGN.md §11).
@@ -360,10 +327,13 @@ class LM:
         sequence (block-table row); tok_pos (N,) int32 its absolute
         position (-1 marks a padded token row); q_last (B,) int32 is the
         flat index of each sequence's last real token (0 for padded
-        sequence rows); pools / block_tables / discard_pid as in
-        decode_step_paged. Causality inside a chunk comes from the
-        per-token mask `kv pos <= tok_pos[i]` — all appends land before
-        attention reads, and later chunk tokens sit at higher positions.
+        sequence rows); pools: the pytree from init_cache(n_pages,
+        page_size); block_tables: (B, max_pages) int32; discard_pid
+        (required for GQA blocks) names the caller's write-discard page
+        for masked appends on the Pallas path. Causality inside a chunk
+        comes from the per-token mask `kv pos <= tok_pos[i]` — all appends
+        land before attention reads, and later chunk tokens sit at higher
+        positions.
 
         Returns (sampled (B,) int32 greedy ids at each sequence's last
         token, logits (B, V) / (B, K, V) — retrievable but not fetched by
@@ -378,13 +348,11 @@ class LM:
         ctx = {"block_tables": block_tables, "tok_seq": tok_seq,
                "tok_pos": tok_pos, "window_override": window_override,
                "discard_pid": discard_pid}
-        x, new_pools = self._paged_layers(params, x, pools,
-                                          B.block_mixed_paged, ctx)
+        x, new_pools = self._paged_layers(params, x, pools, ctx)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         last = x[q_last]                                  # (B, d)
         logits = self.logits(params, last)
-        # sampling on device over the last codebook's row — greedy is
-        # exactly the engine's host-side np.argmax(...reshape(-1, V)[-1])
+        # sampling on device over the last codebook's row
         flat = logits.reshape(logits.shape[0], -1, cfg.vocab_size)[:, -1]
         if sampling is None:
             sampled = jnp.argmax(flat, axis=-1).astype(jnp.int32)
@@ -394,34 +362,12 @@ class LM:
                                     tok_pos[q_last] + 1)
         return sampled, logits, new_pools
 
-    def extend_step_paged(self, params, tokens, start, n_new, pools,
-                          block_tables, *, embeds=None,
-                          window_override="cfg", logits_index=None,
-                          discard_pid=None):
-        """In-place paged chunked prefill: the chunk's K/V pages are written
-        as they are computed; tokens past n_new[b] are bucket padding whose
-        writes are dropped. All written positions must fit the block table
-        (start + T <= max_pages * page_size). Returns (logits at
-        logits_index — default the last position — and the new pools)."""
-        cfg = self.cfg
-        x = self.embed(params, tokens) if tokens is not None else embeds
-        ctx = {"block_tables": block_tables, "start": start, "n_new": n_new,
-               "window_override": window_override,
-               "discard_pid": discard_pid}
-        x, new_pools = self._paged_layers(params, x, pools,
-                                          B.block_extend_paged, ctx)
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        if logits_index is None:
-            last = x[:, -1]
-        else:
-            last = x[jnp.arange(x.shape[0]), logits_index]
-        return self.logits(params, last), new_pools
-
-    def _paged_layers(self, params, x, pools, block_fn, ctx):
-        """The layer groups over the shared paged pools (DESIGN.md §9).
+    def _paged_layers(self, params, x, pools, ctx):
+        """The layer groups of the fused step over the shared paged pools
+        (DESIGN.md §9/§10).
 
         Each group's pools, stacked over its layers, ride its layer scan as
-        part of the carry, and ``block_fn`` addresses them in place at the
+        part of the carry, and each block addresses them in place at the
         scan's layer index (``pool[layer]``), so no layer's pool is sliced
         out of the stack, copied, or written into a second stack; only the
         per-layer params are scanned over. Returns (x, new pools)."""
@@ -437,7 +383,7 @@ class LM:
                 for j, blk in enumerate(period):
                     pj = shared if blk.kind == "shared_attn" \
                         else per_params[f"b{j}"]
-                    xx, pools_g[f"b{j}"] = block_fn(
+                    xx, pools_g[f"b{j}"] = B.block_mixed_paged(
                         pj, cfg, blk, xx, pools_g[f"b{j}"], layer, ctx)
                 return (xx, pools_g), None
 

@@ -68,7 +68,7 @@ ENGINE_COUNTER_SCHEMA: Dict[str, float] = {
     # steps and those that move a live KV block
     # (kernels/ragged_paged_attention.py grid_steps)
     "attn_grid_steps": 0, "attn_live_steps": 0,
-    # bucket padding of each model dispatch: the token rows computed,
+    # bucket padding of each fused step: the token rows computed,
     # those that pad the power-of-two bucket, and padded sequence rows
     "step_tokens": 0, "pad_tokens": 0, "pad_rows": 0,
     # requests refused at intake because the prompt alone exceeds

@@ -6,27 +6,19 @@ model with paged KV storage:
   * KV lives in global paged pools (one pytree mirroring the model's cache
     structure, page-indexed); a BlockManager allocates pages; per-request
     block tables map logical positions to pages.
-  * fused         — fused=True (default, requires paged): each scheduler
-                    iteration's chunks AND decodes are flattened into ONE
-                    ragged token batch and executed by a single jitted
-                    LM.forward_mixed_paged dispatch — one kv_append scatter
-                    covering every new token, one ragged paged-attention
-                    pass, greedy argmax ON DEVICE so only B int32 ids cross
-                    the host boundary instead of B×vocab float logits
-                    (DESIGN.md §10). fused=False keeps the per-call paths
-                    below as the differential oracle, exactly as
-                    paged=False preserves the gather oracle.
-  * decode        — paged=True: one jitted bucketed-batch call over the
-                    shared pools (LM.decode_step_paged) — each new token is
-                    ONE page-slot write (kv_append) and attention reads the
-                    pool through the block tables. paged=False keeps the
-                    legacy gather path (materialize a contiguous
-                    per-request cache view, decode, scatter back) as the
-                    reference oracle: O(context) HBM traffic per token, the
-                    scatter-cost pathology of §3.2 (DESIGN.md §9).
-  * chunks        — chunked prefill / recomputation; the paged path
-                    (LM.extend_step_paged) writes pages as they are
-                    computed instead of round-tripping the whole table.
+  * fused step    — each scheduler iteration's prefill chunks AND decodes
+                    are flattened into ONE ragged token batch and executed
+                    by a single jitted LM.forward_mixed_paged dispatch: one
+                    kv_append scatter per layer writes every new token's
+                    page slot in place, one ragged paged-attention pass
+                    reads the pools through the block tables, and sampling
+                    runs ON DEVICE, so only B int32 ids cross the host
+                    boundary (DESIGN.md §9/§10). It is the engine's only
+                    way to run the model: speculative forks (§14) go
+                    through the same dispatch as one-entry batches.
+  * chunks        — chunked prefill / recomputation: a chunk is one entry
+                    of the fused batch, its pages written as they are
+                    computed.
   * swap_out/in   — page-granular HBM<->host movement staged through ONE
                     contiguous slab per request (the §4.1 coalesced
                     transfer) into a host numpy slab;
@@ -42,8 +34,8 @@ model with paged KV storage:
                     recomputing them, discarded/finished contexts are
                     registered, shared pages are copy-on-write, and LRU
                     eviction reclaims cache-only pages under page pressure
-                    (DESIGN.md §8). Both execution paths route every write
-                    through _ensure_writable, so COW forks work unchanged.
+                    (DESIGN.md §8). Every write routes through
+                    _ensure_writable, so COW forks work unchanged.
 
 Two lifecycles drive the same iteration machinery (DESIGN.md §11):
 
@@ -66,31 +58,29 @@ swap amounts), **dispatch** (swap-out staging, swap-in scatter, and the
 model call all ISSUED together, no host sync between them), **commit**
 (fetch sampled ids, collect staged swap slabs, reconcile bookkeeping,
 advance the virtual clock, consult session boundaries). ``overlap=False``
-preserves the serial execute-then-sync order as the differential oracle —
-token streams are bit-identical either way, only wall-clock concurrency
-and the overlap accounting differ. Caller-side ToolExecutors can run
-off-thread through an ``AsyncToolRuntime`` whose completions are injected
-at the next plan phase via the same resume queue the caller uses.
+preserves the serial execute-then-sync order — token streams are
+bit-identical either way, only wall-clock concurrency and the overlap
+accounting differ. Caller-side ToolExecutors can run off-thread through
+an ``AsyncToolRuntime`` whose completions are injected at the next plan
+phase via the same resume queue the caller uses.
 
 Time is virtual (the same cost model as the simulator, priced with the
 peaks of the device the engine runs on — ``utils.hw.chip_of``) so
 interception durations and swap budgets are exact and runs are
-reproducible; tensor math is real. On TPU the paged path runs the Pallas paged-attention / kv_append
-kernels (repro.kernels); on CPU it runs a jnp mirror of the contiguous
-math, so paged and gather execution produce bit-identical greedy streams —
-the differential property tests/test_paged_engine.py pins down. The
+reproducible; tensor math is real. On TPU the fused step runs the Pallas
+ragged paged-attention / kv_append kernels (repro.kernels); on CPU it runs
+their jnp mirrors. The plain reference is the contiguous ``LM.forward``:
+tests/test_paged_engine.py teacher-forces it over every served stream. The
 ``counters`` dict tracks KV bytes *copied between buffers* per phase
-(gathers, scatters, appends — attention's streaming reads are compute,
-not movement), the measurable form of the O(1)-vs-O(context) claim.
-Sampling is greedy argmax by default, or per-request SamplingParams
-(temperature/top-k/seed) applied on device in the fused dispatch; noise
-is keyed by (seed, position) only, so runs across scheduling policies
-must produce IDENTICAL token streams either way — the strongest
-end-to-end correctness property of the stack (tested).
+(appends, swap slabs, COW copies — attention's streaming reads are
+compute, not movement). Sampling is greedy argmax by default, or
+per-request SamplingParams (temperature/top-k/top-p/seed) applied on
+device; noise is keyed by (seed, position) only, so runs across
+scheduling policies must produce IDENTICAL token streams either way — the
+strongest end-to-end correctness property of the stack (tested).
 
-Scope: attention-cache architectures (the paper's scope). SSM-state archs
-are served by the slot engine in examples/ (their state is O(1) per request
-and trivially preserved; see DESIGN.md §4).
+Scope: attention-cache architectures (the paper's scope); the engine
+refuses any other block kind (DESIGN.md §4).
 """
 from __future__ import annotations
 
@@ -114,7 +104,7 @@ from repro.core.scheduler import Scheduler
 from repro.kernels.ragged_paged_attention import grid_steps
 from repro.kernels.swap_pack import SwapStager
 from repro.memory.block_manager import BlockManager
-from repro.models import LM, sample_tokens
+from repro.models import LM
 from repro.obs.ledger import WasteLedger
 from repro.obs.metrics import ENGINE_COUNTER_SCHEMA, MetricsRegistry
 from repro.obs.trace import HostClock, NullTracer, SpanTracer
@@ -216,7 +206,6 @@ class Engine:
                  estimator: Optional[DurationEstimator] = None,
                  prefix_cache: bool = False,
                  cache_pages: Optional[int] = None,
-                 paged: bool = True,
                  fused: bool = True,
                  overlap: bool = True,
                  speculate: bool = False,
@@ -227,23 +216,22 @@ class Engine:
                  sanitize: bool = False,
                  kv_dtype: Optional[str] = None,
                  dtype=jnp.float32):
+        # the fused step is the only step; the keyword stays while the
+        # benchmark's set-up passes fused=True, and goes when it stops
+        if not fused:
+            raise ValueError("fused=False is not supported: the fused step "
+                             "is the engine's only execution path")
         for blk in cfg.blocks:
             assert blk.kind in ("attn", "shared_attn"), \
                 "paged engine serves attention-cache architectures"
         # quantized KV pools (DESIGN.md §17): low-bit payload + per-page
-        # fp32 scales owned by the same BlockManager pages. Only the
-        # paged path can host them — the gather/scatter oracle
-        # round-trips pools through a slotted (periods, B, S, ...) view
-        # that has no slot axis for a scale leaf.
+        # fp32 scales owned by the same BlockManager pages
         if kv_dtype is not None:
             from repro.kernels.kv_quant import KV_QUANT_DTYPES
             if kv_dtype not in KV_QUANT_DTYPES:
                 raise ValueError(
                     f"unsupported kv_dtype {kv_dtype!r}; "
                     f"choose from {sorted(KV_QUANT_DTYPES)}")
-            if not paged:
-                raise ValueError("kv_dtype requires the paged engine "
-                                 "(paged=True)")
         self.kv_dtype = kv_dtype
         self.cfg = cfg
         self.model = LM(cfg)
@@ -328,11 +316,6 @@ class Engine:
         # resume) instead of after the engine drains
         self.event_sink = None
         self._prefill_emits: List[Tuple[Request, int]] = []
-        # unfused oracle paths: logits fetches + host-side sampling issued
-        # at dispatch are parked here and resolved at the commit phase's
-        # single sync point (entries: ("chunk", req, st, logits) /
-        # ("decode", reqs, logits, positions))
-        self._pending_oracle: List[tuple] = []
         # kept sorted by DESCENDING arrival: the next request to admit is
         # at the tail, so intake is one bisect + shift and admission is an
         # O(1) pop() — no O(n^2) re-sort or front-pop under bursty loads;
@@ -365,22 +348,18 @@ class Engine:
         # engine; the chaos harness uses it to inject cancellations
         # deterministically mid-run
         self.on_plan = None
-        self.paged = paged
-        self.fused = bool(fused and paged)   # the fused path runs on pools
         # pipelined step (DESIGN.md §12): dispatch-phase swap DMA staged
         # through a double-buffered SwapStager and collected at commit;
-        # overlap=False is the serial execute-then-sync oracle
+        # overlap=False is the serial execute-then-sync order
         self.overlap = overlap
         self.stager = SwapStager(depth=2)
         # speculative resume past intercepts (DESIGN.md §14): at an
         # interception, COW-fork the sequence pages and keep decoding
         # against the predictor's guess at the tool return; validate at
         # resume. speculate=False (the default) never forks — streams,
-        # counters and the ledger are bit-identical to the baseline, the
-        # same differential-oracle discipline as paged/fused/overlap.
-        # Requires the paged path (forks ARE page refcounts) and a
-        # predictor to consult.
-        self.speculate = bool(speculate and paged and predictor is not None)
+        # counters and the ledger are bit-identical to the baseline.
+        # Requires a predictor to consult.
+        self.speculate = bool(speculate and predictor is not None)
         self.predictor = predictor
         self.spec_tokens = int(spec_tokens)
         self._spec_forks: Dict[int, SpecFork] = {}
@@ -401,14 +380,13 @@ class Engine:
         # running total stays exact)
         self._tool_windows: Dict[int, List[float]] = {}
         # KV bytes copied between buffers, split by phase (DESIGN.md §9):
-        # gather-path decode/prefill round-trip the whole block-table view;
-        # the paged path appends exactly the new tokens' slots. The fused
-        # path additionally tracks dispatch density (DESIGN.md §10):
-        # device_dispatches counts jitted model calls, mixed_iterations the
-        # scheduler iterations that executed any chunk or decode (fused:
-        # exactly one dispatch each), logit_bytes what the sampling
-        # boundary actually moved device->host (fused: B int32 ids;
-        # unfused: the full B×vocab float logits).
+        # the fused step appends exactly the new tokens' slots. Dispatch
+        # density (DESIGN.md §10): device_dispatches counts the step's
+        # fused dispatches, mixed_iterations the scheduler iterations that
+        # executed any chunk or decode (exactly one dispatch each),
+        # logit_bytes what the sampling boundary moved device->host (B
+        # int32 ids). A speculative fork's dispatch adds to its spec_*
+        # token counters only.
         # Overlap accounting (DESIGN.md §12), mirrored by sim/simulator.py
         # via the shared CostModel.overlap_terms so both stay
         # bit-consistent: swap_overlap_bytes — swap DMA hidden under the
@@ -440,8 +418,8 @@ class Engine:
         self.kv_token_bytes = -(-int(sum(
             int(leaf.nbytes) for leaf in jax.tree.leaves(self.pools)))
             // page_slots)
-        # MLA blocks have no paged decode kernel: their latent pools are
-        # gathered O(context) per step on every backend, and the counters
+        # MLA blocks have no ragged kernel: their latent pools are
+        # gathered O(context) per token on every backend, and the counters
         # must say so (GQA-only models: 0, paged decode is truly O(1))
         self.kv_mla_token_bytes = 0
         for gi, g in enumerate(cfg.groups):
@@ -467,28 +445,13 @@ class Engine:
                         window=a.sliding_window))
         launches.sort(key=lambda la: la["window"] is not None)
         self._attn_launch = launches[0] if launches else None
-        # jitted entry points (stable shapes via bucketing); pools are
-        # donated so the paged update is truly in place — every caller
-        # rebinds self.pools to the result and never reads the old tree
-        self._decode_jit = jax.jit(
-            lambda p, t, pos, c: self.model.decode_step(p, t, pos, c))
-        self._extend_jit = jax.jit(
-            lambda p, t, s, c, li: self.model.extend_step(
-                p, t, s, c, logits_index=li))
-        # pad-row appends are routed to the reserved scratch page on the
-        # Pallas path (the kv_append write-discard contract)
-        self._decode_paged_jit = jax.jit(
-            lambda p, t, cl, pools, bt: self.model.decode_step_paged(
-                p, t, cl, pools, bt, discard_pid=self.scratch_page),
-            donate_argnums=(3,))
-        self._extend_paged_jit = jax.jit(
-            lambda p, t, s, nn, pools, bt, li: self.model.extend_step_paged(
-                p, t, s, nn, pools, bt, logits_index=li,
-                discard_pid=self.scratch_page),
-            donate_argnums=(4,))
-        # the whole mixed iteration — every chunk, every decode, and
-        # sampling (greedy or per-request SamplingParams) — in one
-        # dispatch (DESIGN.md §10/§11)
+        # the jitted step (stable shapes via bucketing): the whole mixed
+        # iteration — every chunk, every decode, and sampling (greedy or
+        # per-request SamplingParams) — in one dispatch (DESIGN.md
+        # §10/§11). Pools are donated so the update is truly in place —
+        # every caller rebinds self.pools to the result and never reads
+        # the old tree; pad-row appends are routed to the reserved scratch
+        # page on the Pallas path (the kv_append write-discard contract)
         self._mixed_jit = jax.jit(
             lambda p, t, ts, tp, ql, pools, bt, samp:
                 self.model.forward_mixed_paged(
@@ -1019,27 +982,10 @@ class Engine:
             tr.span(("req", req.rid), "swap_in", start, end,
                     {"tokens": n})
 
-    def _sample_row(self, req: Request, flat_row: np.ndarray,
-                    position: int) -> int:
-        """Sample one token from a host-fetched logits row on the per-call
-        oracle paths, mirroring the fused path's on-device sampling bit-
-        for-bit (same jnp ops, same (seed, position) noise key). Greedy
-        requests keep the legacy host np.argmax."""
-        sp = req.sampling
-        if sp is None or sp.greedy:
-            return int(np.argmax(flat_row))
-        out = sample_tokens(jnp.asarray(flat_row)[None, :],
-                            jnp.asarray([sp.temperature], jnp.float32),
-                            jnp.asarray([sp.top_k], jnp.int32),
-                            jnp.asarray([sp.top_p], jnp.float32),
-                            jnp.asarray([sp.seed], jnp.int32),
-                            jnp.asarray([position], jnp.int32))
-        return int(out[0])
-
     def _sampling_rows(self, reqs: Sequence[Request], B_pad: int):
         """Per-row (temps, top_ks, top_ps, seeds) arrays for the fused
-        dispatch; None when every row is greedy — keeping the oracle's
-        exact argmax-only compiled graph for legacy runs."""
+        dispatch; None when every row is greedy (the argmax-only
+        program)."""
         if all(r.sampling is None or r.sampling.greedy for r in reqs):
             return None
         temps = np.zeros(B_pad, np.float32)
@@ -1105,7 +1051,7 @@ class Engine:
         adopted from this request, or pages a speculative fork holds) are
         immutable — take a private copy of the payload first. Exclusive
         pages are written in place. Without a cache or speculation no page
-        is ever shared, so the early-out keeps the oracle path free.
+        is ever shared, so the early-out keeps the common path free.
         Under exhaustion the copy target is reclaimed by evicting cold
         cache pages one at a time, then sacrificing speculative forks;
         False only when the pool genuinely cannot back the copy."""
@@ -1203,49 +1149,6 @@ class Engine:
                 "request not fully device-resident"
             ids.append(e[1])
         return ids
-
-    def _gather_cache(self, blocktables: np.ndarray):
-        """blocktables: (B, P) page ids (pad with 0). Returns a slotted cache
-        view (periods, B, P*page, ...) gathered from the pools."""
-        bt = jnp.asarray(blocktables, jnp.int32)
-        Bsz, P = blocktables.shape
-
-        def g(leaf):
-            out = jnp.take(leaf, bt.reshape(-1), axis=1)
-            out = out.reshape(leaf.shape[0], Bsz, P, self.page,
-                              *leaf.shape[3:])
-            return out.reshape(leaf.shape[0], Bsz, P * self.page,
-                               *leaf.shape[3:])
-        return jax.tree.map(g, self.pools)
-
-    def _scatter_tokens(self, cache, blocktables: np.ndarray,
-                        batch_idx: np.ndarray, positions: np.ndarray,
-                        pad_to: int = 0):
-        """Write cache entries at (batch_idx[i], positions[i]) back into the
-        pools at the pages given by each request's block table. Padded
-        entries (stable jit shapes) carry an out-of-range page id and are
-        dropped by the scatter — they must never touch a physical page (two
-        pad rows aliasing one page in a single scatter is unordered)."""
-        n = len(positions)
-        pad_to = max(pad_to, n)
-        pids = np.full(pad_to, self.blocks.n_pages, np.int64)  # OOB: dropped
-        offs = np.zeros(pad_to, np.int64)
-        bidx = np.zeros(pad_to, np.int64)
-        pos = np.zeros(pad_to, np.int64)
-        pids[:n] = blocktables[batch_idx, positions // self.page]
-        offs[:n] = positions % self.page
-        bidx[:n] = batch_idx
-        pos[:n] = positions
-        pids = jnp.asarray(pids, jnp.int32)
-        offs = jnp.asarray(offs, jnp.int32)
-        bidx = jnp.asarray(bidx, jnp.int32)
-        pos = jnp.asarray(pos, jnp.int32)
-
-        def s(pool_leaf, cache_leaf):
-            vals = cache_leaf[:, bidx, pos]      # (periods, pad_to, ...)
-            return pool_leaf.at[:, pids, offs].set(
-                vals.astype(pool_leaf.dtype), mode="drop")
-        self.pools = jax.tree.map(s, self.pools, cache)
 
     # ------------------------------------------------------------------
     # prefix cache
@@ -1469,7 +1372,7 @@ class Engine:
     def _back_plan(self, plan):
         """Graceful admission (DESIGN.md §15): pre-flight the physical
         backing for every planned chunk/decode write — pages allocated
-        and COW targets resolved in the exact order the dispatch paths
+        and COW targets resolved in the exact order the dispatch
         would — BEFORE anything reaches the device. Entries the pool
         cannot back are dropped from the plan and their requests
         re-preempted via _pool_preempt; the dropped entries' planned
@@ -1520,140 +1423,49 @@ class Engine:
         st.pages = []
         st.computed = 0
 
-    def _exec_chunk(self, req: Request, n: int):
-        st = self.kv[req.rid]
-        assert req.host_tokens == 0, "chunks require device-resident prefix"
-        start = st.computed
-        n_pad = max(n, min(self._bucket(n),
-                           self.max_pages * self.page - start))
-        self._ensure_pages(st, start + n)
-        # only the first page of the chunk range can be shared (a matched
-        # COW tail); pages past it were freshly allocated above
-        self._ensure_writable(st, start)
-        bt = np.full((1, self.max_pages), self.scratch_page, np.int64)
-        ids = self._device_page_ids(st, len(st.pages))
-        bt[0, :len(ids)] = ids
-        # pad the chunk to a bucketed length; padding tokens sit at
-        # positions > the real range and are causally invisible. On the
-        # gather path they are written into the throwaway cache view and
-        # not scattered back; on the paged path their writes are dropped.
-        ids_list = st.tokens[start:start + n] + [0] * (n_pad - n)
-        chunk_ids = jnp.asarray([ids_list], jnp.int32)
-        if self.cfg.n_codebooks:
-            chunk_ids = jnp.broadcast_to(chunk_ids[..., None],
-                                         (1, n_pad, self.cfg.n_codebooks))
-        if self.paged:
-            logits, self.pools = self._extend_paged_jit(
-                self.params, chunk_ids, jnp.asarray([start], jnp.int32),
-                jnp.asarray([n], jnp.int32), self.pools,
-                jnp.asarray(bt, jnp.int32), jnp.asarray([n - 1], jnp.int32))
-            # one latent-table gather per batch row (of one) for MLA
-            # blocks — mla_extend_paged materializes the view once per
-            # call, unlike the fused path's per-token row views
-            self.counters["prefill_bytes"] += n * self.kv_token_bytes \
-                + self.max_pages * self.page * self.kv_mla_token_bytes
-        else:
-            cache = self._gather_cache(bt)
-            logits, cache = self._extend_jit(
-                self.params, chunk_ids, jnp.asarray([start], jnp.int32),
-                cache, jnp.asarray([n - 1], jnp.int32))
-            self._scatter_tokens(cache, bt, np.zeros(n, np.int64),
-                                 np.arange(start, start + n), pad_to=n_pad)
-            self.counters["prefill_bytes"] += \
-                (self.max_pages * self.page + n) * self.kv_token_bytes
-        self.counters["prefill_tokens"] += n
-        self.counters["device_dispatches"] += 1
-        self._count_padding(n, n_pad, 1, 1)
-        st.computed = start + n
-        # final chunk of a fresh prefill emits the first generated token —
-        # but the logits fetch + host-side sampling are DEFERRED to the
-        # commit phase (issue-only dispatch, DESIGN.md §12): nothing reads
-        # st.tokens / _prefill_emits before commit, so the stream is
-        # bit-identical while staged swap DMA drains behind the fetch
-        if st.computed == req.target_ctx and len(st.tokens) == req.target_ctx:
-            self._pending_oracle.append(("chunk", req, st, logits))
-        if st.computed == req.target_ctx:
-            # prefill/recompute complete: publish the context so concurrent
-            # same-prefix requests can hit before this one even finishes
-            # (indexes only full pages below st.computed — independent of
-            # the deferred sampled-token append)
-            self._register_in_cache(st)
-
-    def _exec_decode(self, reqs: List[Request]):
-        if not reqs:
-            return
-        sts = [self.kv[r.rid] for r in reqs]
-        for r, st in zip(reqs, sts):
-            self._ensure_pages(st, r.target_ctx + 1)
-            self._ensure_writable(st, r.target_ctx)
-        B = len(reqs)
-        B_pad = self._bucket(B)   # bucketed batch -> stable jit shapes
+    def _launch(self, entries):
+        """Issue the fused step on one ragged token batch. ``entries`` are
+        (req, st, start, n, ...) tuples: n tokens of one sequence from
+        position ``start``. The batch is the flattened ids, per-token
+        (sequence, position) routing and a stacked block-table matrix,
+        bucketed for stable jit shapes; sampling runs on device, so the
+        only device->host transfer is B int32 ids (DESIGN.md §10).
+        Issue-only: returns (sampled ids still on device, N_pad, B_pad)."""
+        B_pad = self._bucket(len(entries))
+        N_pad = self._bucket(sum(e[3] for e in entries))
         bt = np.full((B_pad, self.max_pages), self.scratch_page, np.int64)
-        for b, st in enumerate(sts):
+        toks = np.zeros(N_pad, np.int64)
+        tseq = np.zeros(N_pad, np.int64)      # pad rows: masked via tok_pos
+        tpos = np.full(N_pad, -1, np.int64)   # -1 marks a padded token row
+        qlast = np.zeros(B_pad, np.int64)
+        off = 0
+        for b, (_, st, start, n, *_) in enumerate(entries):
             ids = self._device_page_ids(st, len(st.pages))
             bt[b, :len(ids)] = ids
-        pos = np.zeros(B_pad, np.int64)
-        pos[:B] = [r.target_ctx for r in reqs]
-        feed = np.zeros(B_pad, np.int64)
-        feed[:B] = [st.tokens[p] for st, p in zip(sts, pos[:B])]
-        toks = jnp.asarray(feed, jnp.int32)
-        if self.cfg.n_codebooks:
-            toks = jnp.broadcast_to(toks[:, None],
-                                    (B_pad, self.cfg.n_codebooks))
-        if self.paged:
-            # in-place paged decode: ctx_lens counts the new token;
-            # 0 marks a padded row (its pool write is masked in-kernel)
-            cl = np.zeros(B_pad, np.int64)
-            cl[:B] = pos[:B] + 1
-            logits, self.pools = self._decode_paged_jit(
-                self.params, toks, jnp.asarray(cl, jnp.int32), self.pools,
-                jnp.asarray(bt, jnp.int32))
-            # O(1) appends, plus the O(context) latent gather MLA blocks
-            # still pay (no paged decode kernel for MLA yet)
-            self.counters["decode_bytes"] += B * self.kv_token_bytes \
-                + B_pad * self.max_pages * self.page * self.kv_mla_token_bytes
-        else:
-            cache = self._gather_cache(bt)
-            logits, cache = self._decode_jit(
-                self.params, toks, jnp.asarray(pos, jnp.int32), cache)
-            self._scatter_tokens(cache, bt, np.arange(B),
-                                 np.asarray(pos[:B]), pad_to=B_pad)
-            self.counters["decode_bytes"] += \
-                (B_pad * self.max_pages * self.page + B) \
-                * self.kv_token_bytes
-        self.counters["decode_tokens"] += B
-        self.counters["device_dispatches"] += 1
-        self._count_padding(B, B_pad, B, B_pad)
-        # the full B_pad x vocab logits still cross the host boundary (the
-        # per-step cost the fused path's on-device sampling removes), but
-        # the fetch + sampling are DEFERRED to commit so dispatch stays
-        # issue-only; _decode_ids is not read until the commit boundary
-        # consults, so values and ordering are unchanged
-        self._pending_oracle.append(
-            ("decode", list(reqs), logits, [int(p) for p in pos[:B]]))
-        for st, p in zip(sts, pos[:B]):
-            st.computed = int(p) + 1
+            toks[off:off + n] = st.tokens[start:start + n]
+            tseq[off:off + n] = b
+            tpos[off:off + n] = np.arange(start, start + n)
+            qlast[b] = off + n - 1
+            off += n
 
-    def _count_padding(self, tokens: int, tokens_pad: int, rows: int,
-                       rows_pad: int):
-        """A dispatch's bucket padding: the token rows it computes
-        (``step_tokens``), those that are padding (``pad_tokens``), and
-        its padded sequence rows (``pad_rows``). Host arithmetic only."""
-        self.counters["step_tokens"] += tokens_pad
-        self.counters["pad_tokens"] += tokens_pad - tokens
-        self.counters["pad_rows"] += rows_pad - rows
+        toks_j = jnp.asarray(toks, jnp.int32)
+        if self.cfg.n_codebooks:
+            toks_j = jnp.broadcast_to(toks_j[:, None],
+                                      (N_pad, self.cfg.n_codebooks))
+        samp = self._sampling_rows([e[0] for e in entries], B_pad)
+        sampled, _logits, self.pools = self._mixed_jit(
+            self.params, toks_j, jnp.asarray(tseq, jnp.int32),
+            jnp.asarray(tpos, jnp.int32), jnp.asarray(qlast, jnp.int32),
+            self.pools, jnp.asarray(bt, jnp.int32), samp)
+        return sampled, N_pad, B_pad
 
     def _dispatch_mixed(self, plan):
-        """Fused mixed-batch iteration (DESIGN.md §10): flatten every chunk
-        and every decode of this plan into one ragged token batch —
-        flattened ids + per-token (sequence, position) routing + a stacked
-        block-table matrix, bucketed for stable jit shapes — and execute it
-        with a single LM.forward_mixed_paged dispatch. Greedy sampling runs
-        on device, so the only device->host transfer is B int32 ids; full
-        logits stay resident (retrievable, never fetched here). Issue-only
-        (DESIGN.md §12): returns (entries, sampled_dev) with the sampled
-        ids still on device — _commit_mixed fetches them, so staged swap
-        DMA drains behind the model call in between."""
+        """The step's model work (DESIGN.md §10): every chunk and every
+        decode of this plan in one fused dispatch (``_launch``), and the
+        step's counters. Issue-only (DESIGN.md §12): returns (entries,
+        sampled_dev) with the sampled ids still on device — _commit_mixed
+        fetches them, so staged swap DMA drains behind the model call in
+        between."""
         entries = []                       # (req, st, start, n, is_chunk)
         for req, n in plan.chunks:
             st = self.kv[req.rid]
@@ -1672,44 +1484,17 @@ class Engine:
             entries.append((req, st, req.target_ctx, 1, False))
         if not entries:
             return None
+        sampled, N_pad, B_pad = self._launch(entries)
 
         B = len(entries)
-        B_pad = self._bucket(B)
         total = sum(n for _, _, _, n, _ in entries)
-        N_pad = self._bucket(total)
-        bt = np.full((B_pad, self.max_pages), self.scratch_page, np.int64)
-        toks = np.zeros(N_pad, np.int64)
-        tseq = np.zeros(N_pad, np.int64)      # pad rows: masked via tok_pos
-        tpos = np.full(N_pad, -1, np.int64)   # -1 marks a padded token row
-        qlast = np.zeros(B_pad, np.int64)
-        off = 0
-        for b, (req, st, start, n, _) in enumerate(entries):
-            ids = self._device_page_ids(st, len(st.pages))
-            bt[b, :len(ids)] = ids
-            toks[off:off + n] = st.tokens[start:start + n]
-            tseq[off:off + n] = b
-            tpos[off:off + n] = np.arange(start, start + n)
-            qlast[b] = off + n - 1
-            off += n
-
-        toks_j = jnp.asarray(toks, jnp.int32)
-        if self.cfg.n_codebooks:
-            toks_j = jnp.broadcast_to(toks_j[:, None],
-                                      (N_pad, self.cfg.n_codebooks))
-        samp = self._sampling_rows([e[0] for e in entries], B_pad)
-        sampled, _logits, self.pools = self._mixed_jit(
-            self.params, toks_j, jnp.asarray(tseq, jnp.int32),
-            jnp.asarray(tpos, jnp.int32), jnp.asarray(qlast, jnp.int32),
-            self.pools, jnp.asarray(bt, jnp.int32), samp)
-
         n_chunk = sum(n for _, _, _, n, c in entries if c)
         n_dec = B - len(plan.chunks)
-        # MLA latents have no ragged kernel: the mixed dispatch gathers
-        # the whole latent table once per flat row — chunk, decode, and
+        # MLA latents have no ragged kernel: the fused step gathers the
+        # whole latent table once per flat row — chunk, decode, and
         # bucket-padding rows alike (zero for GQA-only models). Chunk
         # rows charge prefill, decode rows charge decode, and padding
-        # follows the decode bucket when one exists (the unfused decode
-        # counts its padded batch the same way), else prefill.
+        # follows the decode rows when there are any, else prefill.
         mla_gather = self.max_pages * self.page * self.kv_mla_token_bytes
         pad_tokens = N_pad - total
         self.counters["prefill_bytes"] += n_chunk * self.kv_token_bytes \
@@ -1720,7 +1505,11 @@ class Engine:
             + (n_dec + (pad_tokens if n_dec else 0)) * mla_gather
         self.counters["decode_tokens"] += n_dec
         self.counters["device_dispatches"] += 1
-        self._count_padding(total, N_pad, B, B_pad)
+        # the step's bucket padding: the token rows it computes, those
+        # that are padding, and its padded sequence rows
+        self.counters["step_tokens"] += N_pad
+        self.counters["pad_tokens"] += pad_tokens
+        self.counters["pad_rows"] += B_pad - B
         if self._attn_launch is not None:
             grid, live = grid_steps(
                 [(start, n) for _, _, start, n, _ in entries], N_pad, B_pad,
@@ -1887,68 +1676,31 @@ class Engine:
         return True
 
     def _spec_advance(self, fork: SpecFork) -> bool:
-        """One speculative step. The first call prefills the predicted
-        returned tokens and emits the fork's first sampled token — exactly
-        the chunk-end emit the real resume path would produce; later calls
-        decode one token each. Sampling is keyed by (seed, position) only,
-        so an ACCEPTED fork's tokens are bit-identical to what the
-        baseline would decode after the real resume: speculation moves
-        them earlier in virtual time, it cannot change them."""
+        """One speculative step through the fused dispatch, as a one-entry
+        batch. The first call prefills the predicted returned tokens
+        (positions [base, base + P)) and emits the fork's first sampled
+        token — exactly the chunk-end emit the real resume path would
+        produce; later calls decode one token each. Sampling is keyed by
+        (seed, position) only, so an ACCEPTED fork's tokens are
+        bit-identical to what the baseline would decode after the real
+        resume: speculation moves them earlier in virtual time, it cannot
+        change them. The step's counters are left alone: a fork adds to
+        spec_prefill_tokens / spec_decode_tokens only."""
         if fork.dead or fork.emitted >= fork.max_emit:
             return False
-        req, st = fork.req, fork.st
-        if fork.emitted == 0:
-            # predicted-return prefill: positions [base, base + P)
-            start, n = st.computed, len(fork.predicted)
-            if not self._spec_pages(fork, start + n) \
-                    or not self._spec_cow(fork, start):
-                self._spec_kill(fork, "pages")
-                return False
-            n_pad = max(n, min(self._bucket(n),
-                               self.max_pages * self.page - start))
-            bt = np.full((1, self.max_pages), self.scratch_page, np.int64)
-            ids = self._device_page_ids(st, len(st.pages))
-            bt[0, :len(ids)] = ids
-            ids_list = st.tokens[start:start + n] + [0] * (n_pad - n)
-            chunk_ids = jnp.asarray([ids_list], jnp.int32)
-            if self.cfg.n_codebooks:
-                chunk_ids = jnp.broadcast_to(
-                    chunk_ids[..., None], (1, n_pad, self.cfg.n_codebooks))
-            logits, self.pools = self._extend_paged_jit(
-                self.params, chunk_ids, jnp.asarray([start], jnp.int32),
-                jnp.asarray([n], jnp.int32), self.pools,
-                jnp.asarray(bt, jnp.int32),
-                jnp.asarray([n - 1], jnp.int32))
-            st.computed = start + n
-            row = np.asarray(jax.device_get(logits[0]))
-            tid = self._sample_row(
-                req, row.reshape(-1, self.cfg.vocab_size)[-1], st.computed)
-            st.tokens.append(tid)
-            fork.emitted = 1
-            self.counters["spec_prefill_tokens"] += n
-            return True
-        pos = st.computed
-        if not self._spec_pages(fork, pos + 1) \
-                or not self._spec_cow(fork, pos):
+        st = fork.st
+        start = st.computed
+        n = len(fork.predicted) if fork.emitted == 0 else 1
+        if not self._spec_pages(fork, start + n) \
+                or not self._spec_cow(fork, start):
             self._spec_kill(fork, "pages")
             return False
-        bt = np.full((1, self.max_pages), self.scratch_page, np.int64)
-        ids = self._device_page_ids(st, len(st.pages))
-        bt[0, :len(ids)] = ids
-        toks = jnp.asarray([st.tokens[pos]], jnp.int32)
-        if self.cfg.n_codebooks:
-            toks = jnp.broadcast_to(toks[:, None],
-                                    (1, self.cfg.n_codebooks))
-        logits, self.pools = self._decode_paged_jit(
-            self.params, toks, jnp.asarray([pos + 1], jnp.int32),
-            self.pools, jnp.asarray(bt, jnp.int32))
-        st.computed = pos + 1
-        arr = np.asarray(jax.device_get(logits))
-        tid = self._sample_row(
-            req, arr[0].reshape(-1, self.cfg.vocab_size)[-1], pos + 1)
-        st.tokens.append(tid)
+        sampled, _, _ = self._launch([(fork.req, st, start, n)])
+        st.computed = start + n
+        st.tokens.append(int(jax.device_get(sampled)[0]))
+        self.counters["spec_prefill_tokens" if fork.emitted == 0
+                      else "spec_decode_tokens"] += n
         fork.emitted += 1
-        self.counters["spec_decode_tokens"] += 1
         return True
 
     def _spec_step_forks(self, iter_time: float):
@@ -2246,48 +1998,11 @@ class Engine:
             self.host_clock.wait_end(req.rid)
         if plan.chunks or plan.decode:
             self.counters["mixed_iterations"] += 1
-        if self.fused:
-            inflight.mixed = self._dispatch_mixed(plan)
-            if not self.overlap and inflight.mixed is not None:
-                self._commit_mixed(*inflight.mixed)  # lint: allow(dispatch-host-sync): serial oracle (overlap=False) syncs inline
-                inflight.mixed = None
-        else:
-            # per-call oracle paths sample host-side: their logits fetch
-            # is inherent, but staged swap-out DMA still drains behind
-            # the model calls under overlap
-            for req, n in plan.chunks:
-                self._exec_chunk(req, n)
-            self._exec_decode(plan.decode)
+        inflight.mixed = self._dispatch_mixed(plan)
+        if not self.overlap and inflight.mixed is not None:
+            self._commit_mixed(*inflight.mixed)  # lint: allow(dispatch-host-sync): serial order (overlap=False) syncs inline
+            inflight.mixed = None
         return inflight
-
-    def _commit_oracle(self):
-        """Resolve the unfused paths' deferred logits fetches at the
-        commit sync point, in dispatch order (chunks before decode —
-        a request never has both in one plan), reproducing the values,
-        sampling positions, and logit_bytes accounting of the legacy
-        inline fetches bit-for-bit."""
-        pending, self._pending_oracle = self._pending_oracle, []
-        for entry in pending:
-            if entry[0] == "chunk":
-                _, req, st, logits = entry
-                with self.host_clock.span("engine.sync"):
-                    row = np.asarray(jax.device_get(logits[0]))
-                self.counters["logit_bytes"] += row.nbytes
-                tid = self._sample_row(
-                    req, row.reshape(-1, self.cfg.vocab_size)[-1],
-                    st.computed)
-                st.tokens.append(tid)
-                self._prefill_emits.append((req, tid))
-            else:
-                _, reqs, logits, pos = entry
-                with self.host_clock.span("engine.sync"):
-                    arr = np.asarray(jax.device_get(logits))
-                self.counters["logit_bytes"] += arr.nbytes
-                self._decode_ids = [
-                    self._sample_row(
-                        r, arr[b].reshape(-1, self.cfg.vocab_size)[-1],
-                        pos[b] + 1)
-                    for b, r in enumerate(reqs)]
 
     def _commit_phase(self, plan, inflight: StepInflight):
         """COMMIT: the single host-sync point of the step. Fetch the fused
@@ -2300,8 +2015,6 @@ class Engine:
         two paths are bit-identical."""
         if inflight.mixed is not None:
             self._commit_mixed(*inflight.mixed)
-        if self._pending_oracle:
-            self._commit_oracle()
         for req, ticket in inflight.swap_out:
             self._complete_swap_out(req, ticket)
 
@@ -2489,9 +2202,10 @@ class Engine:
         return list(self.kv[req.rid].tokens)
 
     def kv_bytes_per_decode_token(self) -> float:
-        """KV bytes copied between buffers per generated token — O(1) page
-        writes on the paged path, O(context) round-trips on the gather
-        oracle (the measurable form of the §3.2 scatter-cost claim)."""
+        """KV bytes copied between buffers per generated token: one page
+        slot write across every layer's pool (O(1) — the measurable form
+        of the §3.2 scatter-cost claim), plus the latent-table gather MLA
+        blocks pay for want of a ragged kernel."""
         return (self.counters["decode_bytes"]
                 / max(1, self.counters["decode_tokens"]))
 

@@ -6,7 +6,7 @@ Two families:
     ownership or a request's lifecycle and assert the sanitizer reports
     exactly that corruption class with rid/page/site context;
   * identity tests — sanitize=True is observation-only: a chaos soak
-    across policies × fused × overlap runs with ZERO findings and
+    across policies × overlap × speculation runs with ZERO findings and
     streams bit-identical to sanitize=False, and the default engine
     carries no sanitizer state at all.
 
@@ -24,6 +24,7 @@ from repro.configs import get_config
 from repro.core import POLICIES, CostModel
 from repro.core.request import Phase, Request, SamplingParams, Segment
 from repro.serving.api_executor import (ChaosToolExecutor,
+                                        OracleToolResultPredictor,
                                         VirtualTimeToolExecutor)
 from repro.serving.engine import Engine
 from repro.serving.session import InferCeptClient
@@ -203,10 +204,10 @@ def test_transition_table_shape():
 # sanitize=True is observation-only: clean runs, identical streams
 # ---------------------------------------------------------------------------
 
-def _soak(policy, *, fused=True, overlap=True, sanitize=False,
+def _soak(policy, *, overlap=True, sanitize=False,
           failure_rate=0.2, timeout_rate=0.1, n=6, **engine_kw):
     cfg = get_config("llama3.2-1b", tiny=True)
-    eng = _engine(policy, cfg=cfg, fused=fused, overlap=overlap,
+    eng = _engine(policy, cfg=cfg, overlap=overlap,
                   sanitize=sanitize, **engine_kw)
     cl = InferCeptClient(eng)
     tools = ChaosToolExecutor(
@@ -250,9 +251,28 @@ def test_sanitized_soak_matrix(policy):
     _assert_sanitized_identity(policy)
 
 
-@pytest.mark.slow
-def test_sanitized_soak_unfused():
-    _assert_sanitized_identity("swap", fused=False)
+class _SoakOracle(OracleToolResultPredictor):
+    """The soak's tool returns 4 ids, the same pure function of (rid,
+    segment) the oracle predicts; a caller-owned intercept gives no
+    count, so it is supplied here."""
+
+    def predict(self, rid, kind, seg_idx, n_hint):
+        return super().predict(rid, kind, seg_idx, n_hint or 4)
+
+
+def test_sanitized_soak_speculative():
+    """Speculative forks under chaos: every fork prefill runs through the
+    fused dispatch on pages the sanitizer audits at each plan phase, with
+    retries, timeouts and failed sessions tearing forks down — zero
+    findings, and sanitize=True stays observation-only."""
+    cfg = get_config("llama3.2-1b", tiny=True)
+    _assert_sanitized_identity(
+        "infercept", speculate=True,
+        predictor=_SoakOracle(cfg.vocab_size))
+    eng, _, _ = _soak("infercept", sanitize=True, speculate=True,
+                      predictor=_SoakOracle(cfg.vocab_size))
+    assert eng.counters["spec_forks"] > 0
+    assert eng.counters["spec_prefill_tokens"] > 0
 
 
 @pytest.mark.slow

@@ -47,7 +47,10 @@ def _engine(policy, **kw):
 
 
 def _leak_free(eng):
-    return eng.blocks.num_free == eng.blocks.n_pages - 1
+    # every page is free but the scratch page and those the prefix cache
+    # holds
+    held = eng.cache.n_pages if eng.cache is not None else 0
+    return eng.blocks.num_free == eng.blocks.n_pages - 1 - held
 
 
 def _ledger_balanced(eng):
@@ -218,10 +221,11 @@ def test_chaos_draws_are_deterministic():
 # chaos soak: blast radius under injected faults
 # ---------------------------------------------------------------------------
 
-def _soak(policy, *, fused=True, overlap=True, failure_rate=0.0,
-          timeout_rate=0.0, n=6, seed_chaos=7):
+def _soak(policy, *, overlap=True, failure_rate=0.0,
+          timeout_rate=0.0, n=6, seed_chaos=7, prefix_cache=False):
     cfg = get_config("llama3.2-1b", tiny=True)
-    eng = _engine(policy, cfg=cfg, fused=fused, overlap=overlap)
+    eng = _engine(policy, cfg=cfg, overlap=overlap,
+                  prefix_cache=prefix_cache)
     cl = InferCeptClient(eng)
     tools = ChaosToolExecutor(
         VirtualTimeToolExecutor(cfg.vocab_size, n_tokens=4, duration=0.05),
@@ -265,11 +269,12 @@ def test_chaos_soak_quick(rate):
 
 @pytest.mark.slow
 @pytest.mark.parametrize("policy", ALL_POLICIES)
-@pytest.mark.parametrize("fused", [True, False])
-def test_chaos_soak_matrix(policy, fused):
-    _, _, clean = _soak(policy, fused=fused, failure_rate=0.0)
-    eng, hs, streams = _soak(policy, fused=fused, failure_rate=0.2,
-                             timeout_rate=0.1)
+@pytest.mark.parametrize("prefix_cache", [False, True])
+def test_chaos_soak_matrix(policy, prefix_cache):
+    _, _, clean = _soak(policy, prefix_cache=prefix_cache,
+                        failure_rate=0.0)
+    eng, hs, streams = _soak(policy, prefix_cache=prefix_cache,
+                             failure_rate=0.2, timeout_rate=0.1)
     _assert_soak_invariants(eng, hs, streams, clean)
 
 

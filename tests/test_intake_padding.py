@@ -1,20 +1,18 @@
 """Engine intake refuses prompts longer than max_model_len, and every
 model dispatch counts its bucket padding (step_tokens, pad_tokens,
 pad_rows) from the plan's rows and tokens."""
-import copy
-
 import pytest
 
 from repro.configs import get_config
 from repro.core import POLICIES
-from repro.core.request import Request, Segment
+from repro.core.request import Interception, Request, Segment
 from repro.serving.engine import Engine
 from repro.serving.session import InferCeptClient, RejectedEvent
 
 
-def _engine(**kw):
+def _engine(policy="infercept", **kw):
     cfg = get_config("llama3.2-1b", tiny=True)
-    return Engine(cfg, POLICIES["infercept"], page_size=16, n_pages=64,
+    return Engine(cfg, POLICIES[policy], page_size=16, n_pages=64,
                   max_model_len=256, seed=0, **kw)
 
 
@@ -41,9 +39,16 @@ def test_prompt_over_max_model_len_is_refused():
     assert eng.counters["rejected_too_long"] == 1
 
 
-def _requests(prompts, gen=3):
-    return [Request(rid=i, arrival=0.0, prompt_len=n,
-                    segments=[Segment(gen, None)])
+def _requests(prompts, gen=3, intercept=False):
+    """Requests arriving together; with ``intercept`` each pauses for a
+    tool call after ``gen`` tokens and then generates ``gen`` more."""
+    def segments():
+        if not intercept:
+            return [Segment(gen, None)]
+        return [Segment(gen, Interception(kind="math", duration=0.5,
+                                          returned_tokens=5)),
+                Segment(gen, None)]
+    return [Request(rid=i, arrival=0.0, prompt_len=n, segments=segments())
             for i, n in enumerate(prompts)]
 
 
@@ -51,8 +56,12 @@ def _bucket(n):
     return 1 << (n - 1).bit_length()
 
 
-def test_fused_step_counts_bucket_padding(monkeypatch):
-    eng = _engine()
+@pytest.mark.parametrize("policy", ["infercept", "vllm"])
+def test_fused_step_counts_bucket_padding(monkeypatch, policy):
+    """Every fused step adds its token and row bucket padding. The vllm
+    case pauses every request on a tool call and discards its context, so
+    its recompute chunks are padded in the same steps as the decodes."""
+    eng = _engine(policy=policy)
     seen = []
     real = eng._dispatch_mixed
 
@@ -71,33 +80,18 @@ def test_fused_step_counts_bucket_padding(monkeypatch):
         return out
 
     monkeypatch.setattr(eng, "_dispatch_mixed", counted)
-    for r in _requests([20, 30, 7]):
+    for r in _requests([20, 30, 7], intercept=policy == "vllm"):
         eng.add_request(r)
     eng.run()
     # the first step prefills all three prompts: B 3 -> 4, N 57 -> 64;
-    # then three decode steps of B 3 -> 4, N 3 -> 4
+    # then decode steps of B 3 -> 4, N 3 -> 4
     assert seen[0] == (3, 57)
     assert eng.counters["step_tokens"] == sum(_bucket(n) for _, n in seen)
-    assert eng.counters["pad_tokens"] == 7 + sum(
-        _bucket(n) - n for _, n in seen[1:])
-    assert eng.counters["pad_rows"] == len(seen)
-
-
-@pytest.mark.parametrize("policy", ["infercept", "vllm"])
-def test_unfused_paths_count_bucket_padding(policy):
-    cfg = get_config("llama3.2-1b", tiny=True)
-    eng = Engine(cfg, POLICIES[policy], page_size=16, n_pages=64,
-                 max_model_len=256, seed=0, fused=False)
-    for r in copy.deepcopy(_requests([20, 30, 7], gen=3)):
-        eng.add_request(r)
-    eng.run()
-    # chunks pad to min(bucket, room left in the block table) alone in a
-    # batch of one: 20 -> 32, 30 -> 32, 7 -> 8; then decode batches of 3
-    # -> 4 rows
-    chunk = (32 - 20) + (32 - 30) + (8 - 7)
-    decode_steps = eng.counters["device_dispatches"] - 3
-    assert decode_steps > 0
-    assert eng.counters["decode_tokens"] == 3 * decode_steps
-    assert eng.counters["step_tokens"] == 32 + 32 + 8 + 4 * decode_steps
-    assert eng.counters["pad_tokens"] == chunk + 1 * decode_steps
-    assert eng.counters["pad_rows"] == 1 * decode_steps
+    assert eng.counters["pad_tokens"] == sum(
+        _bucket(n) - n for _, n in seen)
+    assert eng.counters["pad_rows"] == sum(_bucket(b) - b for b, _ in seen)
+    if policy == "vllm":
+        # discard on every intercept: each context came back as a padded
+        # recompute chunk
+        assert eng.sched.stats.recompute_tokens > 0
+        assert any(n > b for b, n in seen[1:])

@@ -8,7 +8,6 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.kv_append import kv_append
-from repro.kernels.paged_attention import paged_attention
 from repro.kernels.ragged_paged_attention import ragged_paged_attention
 from repro.kernels.swap_pack import swap_pack, swap_unpack
 
@@ -57,6 +56,22 @@ def test_flash_attention_masking(window, softcap, causal):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
+def _decode_rows(lens):
+    """tok_seq / tok_pos of a decode-only launch: row b is sequence b's one
+    new token, at its context length minus one."""
+    return jnp.arange(len(lens), dtype=jnp.int32), lens - 1
+
+
+def _decode(q, kp, vp, bt, lens, **kw):
+    """The ragged kernel at a decode-only launch, and its oracle."""
+    ts, tp = _decode_rows(lens)
+    out = ragged_paged_attention(q, kp[None], vp[None], 0, bt, ts, tp,
+                                 interpret=True, **kw)
+    want = ref.ragged_paged_attention_ref(q, kp[None], vp[None], 0, bt, ts,
+                                          tp, **kw)
+    return out, want
+
+
 @pytest.mark.parametrize("B,Hkv,G,hd,page,max_pages,n_pages", [
     (2, 2, 4, 64, 16, 8, 32),
     (4, 1, 8, 128, 8, 16, 64),
@@ -73,16 +88,15 @@ def test_paged_attention(B, Hkv, G, hd, page, max_pages, n_pages, dtype):
     bt = jnp.asarray(rng.integers(0, n_pages, (B, max_pages)), jnp.int32)
     lens = jnp.asarray(rng.integers(1, page * max_pages + 1, (B,)),
                        jnp.int32)
-    out = paged_attention(q, kp[None], vp[None], 0, bt, lens,
-                          interpret=True)
-    want = ref.paged_attention_ref(q, kp[None], vp[None], 0, bt, lens)
+    out, want = _decode(q, kp, vp, bt, lens)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), atol=tol)
 
 
 def test_paged_attention_matches_dense_decode():
-    """Paged decode == flash over the gathered dense cache (cross-oracle)."""
+    """A decode-only launch == softmax attention over the gathered dense
+    cache (cross-oracle)."""
     rng = np.random.default_rng(3)
     B, Hkv, G, hd, page, max_pages, n_pages = 2, 2, 2, 32, 8, 6, 24
     ks = jax.random.split(KEY, 3)
@@ -91,8 +105,9 @@ def test_paged_attention_matches_dense_decode():
     vp = jax.random.normal(ks[2], (n_pages, page, Hkv, hd))
     bt = jnp.asarray(rng.integers(0, n_pages, (B, max_pages)), jnp.int32)
     lens = jnp.asarray([page * max_pages, 17], jnp.int32)
-    out = paged_attention(q, kp[None], vp[None], 0, bt, lens,
-                          interpret=True)
+    ts, tp = _decode_rows(lens)
+    out = ragged_paged_attention(q, kp[None], vp[None], 0, bt, ts, tp,
+                                 interpret=True)
     k = kp[bt].reshape(B, max_pages * page, Hkv, hd)
     v = vp[bt].reshape(B, max_pages * page, Hkv, hd)
     s = jnp.einsum("bhgd,bshd->bhgs", q, k) / np.sqrt(hd)
@@ -105,7 +120,8 @@ def test_paged_attention_matches_dense_decode():
 @pytest.mark.parametrize("G", [1, 4])
 @pytest.mark.parametrize("ctx", [1, 8, 16, 23, 32])
 def test_paged_attention_ragged_edges(G, ctx):
-    """Explicit ragged ctx_lens edge cases per GQA group size: ctx=1,
+    """Explicit ragged context-length edge cases of a decode-only launch
+    per GQA group size: ctx=1,
     ctx exactly on a page boundary (8, 16), mid-page (23), and the full
     page-table width (32 = page * max_pages)."""
     rng = np.random.default_rng(G * 100 + ctx)
@@ -118,9 +134,7 @@ def test_paged_attention_ragged_edges(G, ctx):
     # one row at the edge case, the others ragged around it
     lens = jnp.asarray([ctx, max(1, ctx - 1), min(page * max_pages, ctx + 1)],
                        jnp.int32)
-    out = paged_attention(q, kp[None], vp[None], 0, bt, lens,
-                          interpret=True)
-    want = ref.paged_attention_ref(q, kp[None], vp[None], 0, bt, lens)
+    out, want = _decode(q, kp, vp, bt, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
@@ -133,10 +147,7 @@ def test_paged_attention_sliding_window(window):
     vp = jax.random.normal(ks[2], (16, 8, 2, 32))
     bt = jnp.asarray(rng.integers(0, 16, (2, 4)), jnp.int32)
     lens = jnp.asarray([29, 5], jnp.int32)
-    out = paged_attention(q, kp[None], vp[None], 0, bt, lens,
-                          window=window, interpret=True)
-    want = ref.paged_attention_ref(q, kp[None], vp[None], 0, bt, lens,
-                                   window=window)
+    out, want = _decode(q, kp, vp, bt, lens, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
@@ -276,26 +287,6 @@ def test_ragged_paged_attention_window_softcap(window, softcap, tiled):
                                           softcap=softcap)
     real = np.asarray(tok_pos) >= 0
     np.testing.assert_allclose(np.asarray(out)[real], np.asarray(want)[real],
-                               atol=2e-5)
-
-
-def test_ragged_degenerates_to_paged_attention():
-    """One token per sequence at position ctx_lens-1 IS the decode kernel:
-    both kernels must agree (cross-oracle, padded rows excluded)."""
-    rng = np.random.default_rng(3)
-    B, Hkv, G, hd, page, max_pages, n_pages = 4, 2, 2, 32, 8, 6, 24
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (B, Hkv, G, hd))
-    kp = jax.random.normal(ks[1], (n_pages, page, Hkv, hd))
-    vp = jax.random.normal(ks[2], (n_pages, page, Hkv, hd))
-    bt = jnp.asarray(rng.integers(0, n_pages, (B, max_pages)), jnp.int32)
-    lens = jnp.asarray([page * max_pages, 17, 1, 0], jnp.int32)  # 0 = pad
-    got = ragged_paged_attention(q, kp[None], vp[None], 0, bt,
-                                 jnp.arange(B, dtype=jnp.int32),
-                                 lens - 1, interpret=True)
-    want = paged_attention(q, kp[None], vp[None], 0, bt, lens,
-                           interpret=True)
-    np.testing.assert_allclose(np.asarray(got)[:3], np.asarray(want)[:3],
                                atol=2e-5)
 
 

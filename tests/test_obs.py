@@ -22,6 +22,7 @@ from repro.obs.export import (format_stats_line, format_summary,
 from repro.obs.ledger import WASTE_CAUSES, WasteLedger, waste_report
 from repro.obs.metrics import CounterView, Histogram, MetricsRegistry
 from repro.obs.trace import NullTracer, SpanTracer
+from repro.serving.api_executor import OracleToolResultPredictor
 from repro.serving.engine import Engine
 from repro.serving.workloads import make_workload
 from repro.sim import simulate
@@ -230,13 +231,15 @@ def test_check_breakdown_catches_tampering(tmp_path):
 # engine integration: the identity contract + the sim mirror
 # ---------------------------------------------------------------------------
 
-# four policies spread across the engine variants (§9 fused, §8 prefix
-# cache, §12 overlap) so the identity pin covers every code path that
-# gained emission sites
+# four policies spread across the engine variants (§14 speculation, §8
+# prefix cache, §12 overlap) so the identity pin covers every code path
+# that gained emission sites
 CONFIGS = [
     ("vllm", {}),                           # discard + full recompute
-    ("preserve", {"overlap": False}),       # serial step (§12 oracle)
-    ("swap", {"fused": False}),             # unfused mixed batches
+    ("preserve", {"overlap": False}),       # serial step (§12)
+    ("swap", {"speculate": True,            # speculative forks
+              "predictor": OracleToolResultPredictor(
+                  get_config("llama3.2-1b", tiny=True).vocab_size)}),
     ("infercept", {"prefix_cache": True}),  # min-waste + prefix cache
 ]
 

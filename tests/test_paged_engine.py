@@ -1,17 +1,15 @@
-"""Differential harness for the paged and fused execution paths
-(DESIGN.md §9 / §10).
+"""The served step against the plain reference (DESIGN.md §9 / §10).
 
-Two oracle layers, mirroring how the engine grew:
-
-  * gather oracle (``paged=False``) — materializes a contiguous cache view
-    per decode step / prefill chunk; the in-place paged path must emit its
-    exact greedy token streams (§9).
-  * unfused oracle (``paged=True, fused=False``) — one jitted call per
-    chunk plus one per decode batch; the fused mixed-batch path (one
-    dispatch per iteration, on-device sampling) must emit ITS exact
-    streams too (§10), across every scheduling policy with the prefix
-    cache on and off, while reporting exactly one device dispatch per
-    non-empty iteration and an O(B)-ids logit transfer.
+The engine runs the model one way: the fused step (LM.forward_mixed_paged
+— every chunk and decode of an iteration in one dispatch over the paged
+pools, sampling on device). Its reference is the plain contiguous
+``LM.forward``, independent of paging, scheduling and the prefix cache:
+every run of the differential fixture — each scheduling policy, prefix
+cache on and off — is teacher-forced through it, and every generated
+token must be the reference's best within float tolerance. The runs must
+also agree with each other bit for bit, report exactly one device
+dispatch per non-empty iteration, and move O(B) int32 ids across the
+sampling boundary.
 """
 import copy
 
@@ -39,133 +37,169 @@ def _agent_workload(cfg, n_sessions=2):
         final_gen=(8, 3), ret_tokens=(6, 2), max_tool_calls=2, max_ctx=240)
 
 
-def _run(cfg, reqs, policy, *, paged, fused=True, prefix_cache=False,
-         overlap=True):
+def _run(cfg, reqs, policy, *, prefix_cache=False, overlap=True):
     eng = Engine(cfg, POLICIES[policy], page_size=16, n_pages=128,
-                 max_model_len=256, seed=0, paged=paged, fused=fused,
-                 prefix_cache=prefix_cache, overlap=overlap)
+                 max_model_len=256, seed=0, prefix_cache=prefix_cache,
+                 overlap=overlap)
     for r in copy.deepcopy(reqs):
         eng.add_request(r)
     fin = eng.run()
-    assert len(fin) == len(reqs), (policy, paged, fused, prefix_cache)
+    assert len(fin) == len(reqs), (policy, prefix_cache, overlap)
     return {r.rid: eng.generated_text(r) for r in fin}, eng
+
+
+# the served token's reference logit may trail the reference's best by at
+# most this much (float32 rounding between the fused step and LM.forward;
+# the logits spread over ~8 units on the tiny model)
+REF_TOL = 1e-3
+
+
+def _generated_positions(req):
+    """Positions of the tokens the model generated in a finished request's
+    token list, and the list's length: after the prompt, each segment's
+    ``gen_tokens`` generated tokens, then its interception's
+    ``returned_tokens`` tool tokens, which the model did not generate."""
+    pos, gen = req.prompt_len, []
+    for seg in req.segments:
+        gen.extend(range(pos, pos + seg.gen_tokens))
+        pos += seg.gen_tokens
+        if seg.interception is not None:
+            pos += seg.interception.returned_tokens
+    return gen, pos
+
+
+def _plain_reference(cfg, params, max_len):
+    """The plain reference's verdict on finished streams: ``gaps(req,
+    tokens)`` teacher-forces the contiguous LM.forward over the request's
+    whole token list and returns, for every generated position, how far
+    the served token's logit trails the reference's best there."""
+    model = LM(cfg)
+    logits_of = jax.jit(
+        lambda p, x: model.logits(p, model.forward(p, x).hidden)[0])
+
+    def gaps(req, tokens):
+        gen, end = _generated_positions(req)
+        assert end == len(tokens), (req.rid, end, len(tokens))
+        x = np.zeros((1, max_len), np.int32)      # causal: tail is inert
+        x[0, :len(tokens)] = tokens
+        lg = np.asarray(logits_of(params, jnp.asarray(x)))
+        return {p: float(lg[p - 1].max() - lg[p - 1][tokens[p]])
+                for p in gen}
+    return gaps
 
 
 @pytest.fixture(scope="module")
 def diff():
     cfg = get_config("llama3.2-1b", tiny=True)
     reqs = _agent_workload(cfg)
-    oracle = _run(cfg, reqs, "vllm", paged=False, fused=False)
-    fused, unfused = {}, {}
-    for name in ALL_POLICIES:
-        for cache_on in (False, True):
-            fused[(name, cache_on)] = _run(cfg, reqs, name, paged=True,
-                                           fused=True,
-                                           prefix_cache=cache_on)
-            unfused[(name, cache_on)] = _run(cfg, reqs, name, paged=True,
-                                             fused=False,
-                                             prefix_cache=cache_on)
-    return cfg, oracle, fused, unfused
+    runs = {(name, cache_on): _run(cfg, reqs, name, prefix_cache=cache_on)
+            for name in ALL_POLICIES for cache_on in (False, True)}
+    eng = runs[("vllm", False)][1]
+    return cfg, runs, _plain_reference(cfg, eng.params, eng.max_model_len)
 
 
 @pytest.mark.slow
-def test_paged_streams_match_gather_oracle(diff):
-    """The headline differential property: every paged run — any policy,
-    cache on or off, fused or not — emits the gather oracle's exact token
-    streams."""
-    _, (oracle_streams, _), fused, unfused = diff
-    for key, (streams, _) in fused.items():
-        assert streams == oracle_streams, \
-            f"fused {key} diverged from the gather oracle"
-    for key, (streams, _) in unfused.items():
-        assert streams == oracle_streams, \
-            f"unfused {key} diverged from the gather oracle"
+def test_fused_streams_match_plain_reference(diff):
+    """The headline property: every fused run — any policy, cache on or
+    off — generated, at each generated position, the token the plain
+    contiguous forward ranks best there."""
+    _, runs, gaps = diff
+    for key, (streams, eng) in runs.items():
+        for req in eng.finished:
+            bad = {p: g for p, g in gaps(req, streams[req.rid]).items()
+                   if g > REF_TOL}
+            assert not bad, f"fused {key} rid {req.rid} off the reference " \
+                f"at {bad}"
 
 
-def test_fused_streams_match_unfused(diff):
-    """§10's differential pin: the fused mixed-batch path reproduces the
-    unfused per-call path's greedy streams bit-for-bit on every policy,
-    prefix cache on and off."""
-    _, _, fused, unfused = diff
-    for key in fused:
-        assert fused[key][0] == unfused[key][0], \
-            f"fused {key} diverged from the unfused oracle"
+def test_plain_reference_rejects_a_changed_token(diff):
+    """The reference check can fail: one generated token changed in a
+    served stream is flagged at exactly its position."""
+    cfg, runs, gaps = diff
+    streams, eng = runs[("vllm", False)]
+    req = eng.finished[0]
+    gen, _ = _generated_positions(req)
+    p = gen[len(gen) // 2]
+    tokens = list(streams[req.rid])
+    assert max(gaps(req, tokens).values()) <= REF_TOL
+    tokens[p] = (tokens[p] + 1) % cfg.vocab_size
+    got = gaps(req, tokens)
+    assert got[p] > REF_TOL
+    assert all(g <= REF_TOL for q, g in got.items() if q < p)
+
+
+def test_fused_streams_identical_across_policies(diff):
+    """Scheduling decides when tokens are computed, never which: every
+    policy, prefix cache on and off, emits the same streams bit for
+    bit."""
+    _, runs, _ = diff
+    want = runs[("vllm", False)][0]
+    for key, (streams, _) in runs.items():
+        assert streams == want, f"fused {key} diverged from vllm"
 
 
 def test_fused_single_dispatch_per_iteration(diff):
-    """Every non-empty iteration of a fused run is exactly ONE jitted
-    model call; unfused runs pay one per chunk plus one per decode batch
-    (>= fused, strictly more whenever an iteration mixes work)."""
-    _, _, fused, unfused = diff
-    for key, (_, eng) in fused.items():
+    """Every non-empty iteration is exactly ONE jitted model call."""
+    _, runs, _ = diff
+    for key, (_, eng) in runs.items():
         assert eng.counters["mixed_iterations"] > 0
         assert eng.counters["device_dispatches"] == \
-            eng.counters["mixed_iterations"], key
-    for key, (_, eng) in unfused.items():
-        assert eng.counters["device_dispatches"] >= \
             eng.counters["mixed_iterations"], key
 
 
 def test_fused_transfers_ids_not_logits(diff):
-    """On-device sampling boundary: a fused run moves at most
-    bucket(B) * 4 bytes of sampled int32 ids per iteration device->host;
-    the unfused oracle fetches the full B x vocab float32 logits every
-    decode step."""
-    cfg, _, fused, unfused = diff
-    for key, (_, eng) in fused.items():
+    """On-device sampling boundary: a run moves at most bucket(B) * 4
+    bytes of sampled int32 ids per iteration device->host, never the
+    B x vocab float logits."""
+    _, runs, _ = diff
+    for key, (_, eng) in runs.items():
         b_pad = Engine._bucket(len(eng.finished))      # max batch bound
         assert eng.counters["logit_bytes"] <= \
             4 * b_pad * eng.counters["mixed_iterations"], key
-    for key, (_, eng) in unfused.items():
-        assert eng.counters["logit_bytes"] >= \
-            eng.counters["decode_tokens"] * cfg.vocab_size * 4, key
-        ratio = (eng.counters["logit_bytes"]
-                 / max(1, fused[key][1].counters["logit_bytes"]))
-        assert ratio >= cfg.vocab_size / 2, \
-            f"fused logit transfer only {ratio:.0f}x smaller for {key}"
 
 
 def test_paged_mechanisms_actually_exercised(diff):
-    """The equality above must not be vacuous: recompute, swap, and cache
-    hits all really happened on the paged path."""
-    _, _, fused, _ = diff
-    assert fused[("vllm", False)][1].sched.stats.recompute_tokens > 0
-    swap_eng = fused[("swap", False)][1]
+    """The checks above must not be vacuous: recompute, swap, and cache
+    hits all really happened."""
+    _, runs, _ = diff
+    assert runs[("vllm", False)][1].sched.stats.recompute_tokens > 0
+    swap_eng = runs[("swap", False)][1]
     assert swap_eng.sched.stats.swapped_out_tokens > 0
     assert (swap_eng.sched.stats.swapped_in_tokens
             == swap_eng.sched.stats.swapped_out_tokens)
-    assert fused[("vllm", True)][1].sched.stats.cache_hit_tokens > 0
+    assert runs[("vllm", True)][1].sched.stats.cache_hit_tokens > 0
 
 
 def test_no_page_leaks_on_paged_path(diff):
-    _, _, fused, unfused = diff
-    for runs in (fused, unfused):
-        for key, (_, eng) in runs.items():
-            held = eng.cache.n_pages if eng.cache is not None else 0
-            assert eng.blocks.num_free == \
-                eng.blocks.n_pages - 1 - held, key
+    _, runs, _ = diff
+    for key, (_, eng) in runs.items():
+        held = eng.cache.n_pages if eng.cache is not None else 0
+        assert eng.blocks.num_free == \
+            eng.blocks.n_pages - 1 - held, key
 
 
 def test_paged_decode_moves_o1_bytes_per_token(diff):
-    """The measurable form of the §9 claim: the paged path (fused or not)
-    writes exactly one token's K/V per generated token; the gather oracle
-    round-trips the whole block-table view (O(context))."""
-    _, (_, gather_eng), fused, unfused = diff
-    for runs in (fused, unfused):
-        for key in [("vllm", False), ("infercept", True)]:
-            eng = runs[key][1]
-            assert eng.counters["decode_tokens"] > 0
-            assert eng.counters["decode_bytes"] == \
-                eng.counters["decode_tokens"] * eng.kv_token_bytes, key
-            assert eng.counters["prefill_bytes"] == \
-                eng.counters["prefill_tokens"] * eng.kv_token_bytes, key
-    # gather decode: >= one full table gather per token => O(context)
-    table_tokens = gather_eng.max_pages * gather_eng.page
-    assert gather_eng.kv_bytes_per_decode_token() >= \
-        table_tokens * gather_eng.kv_token_bytes
-    ratio = (gather_eng.kv_bytes_per_decode_token()
-             / fused[("vllm", False)][1].kv_bytes_per_decode_token())
-    assert ratio >= 10.0, f"paged decode only {ratio:.1f}x cheaper"
+    """The measurable form of the §9 claim: the fused step writes exactly
+    one token's K/V per generated (and per prefilled) token — O(1), never
+    a copy of the block-table view."""
+    _, runs, _ = diff
+    for key in [("vllm", False), ("infercept", True)]:
+        eng = runs[key][1]
+        assert eng.counters["decode_tokens"] > 0
+        assert eng.counters["decode_bytes"] == \
+            eng.counters["decode_tokens"] * eng.kv_token_bytes, key
+        assert eng.kv_bytes_per_decode_token() == eng.kv_token_bytes
+        assert eng.counters["prefill_bytes"] == \
+            eng.counters["prefill_tokens"] * eng.kv_token_bytes, key
+
+
+def test_engine_refuses_unfused():
+    """The fused step is the only execution path: asking for another is
+    an error, not a silent switch."""
+    cfg = get_config("llama3.2-1b", tiny=True)
+    with pytest.raises(ValueError, match="fused"):
+        Engine(cfg, POLICIES["vllm"], page_size=16, n_pages=32,
+               max_model_len=64, fused=False)
 
 
 # ---------------------------------------------------------------------------
@@ -173,28 +207,26 @@ def test_paged_decode_moves_o1_bytes_per_token(diff):
 # ---------------------------------------------------------------------------
 @pytest.mark.slow
 def test_overlap_off_streams_match_across_policies(diff):
-    """The §12 differential pin: the serial engine (overlap=False, the
-    execute-then-sync oracle) emits the exact token streams of the
-    pipelined default across all 4 policies × fused on/off × prefix-cache
-    on/off — commit-phase reconciliation keeps every host-visible state
-    transition in the serial order. Serial runs must also charge zero
-    overlap counters (nothing was hidden), while the fixture's pipelined
-    runs hide real swap DMA on the swap-traffic policies."""
-    cfg, (oracle_streams, _), fused, _ = diff
+    """The §12 differential pin: the serial engine (overlap=False,
+    execute-then-sync) emits the exact token streams of the pipelined
+    default across all 4 policies × prefix-cache on/off — commit-phase
+    reconciliation keeps every host-visible state transition in the
+    serial order. Serial runs must also charge zero overlap counters
+    (nothing was hidden), while the fixture's pipelined runs hide real
+    swap DMA on the swap-traffic policies."""
+    cfg, runs, _ = diff
     for name in ALL_POLICIES:
-        for fus in (True, False):
-            for cache_on in (False, True):
-                streams, eng = _run(cfg, _agent_workload(cfg), name,
-                                    paged=True, fused=fus,
-                                    prefix_cache=cache_on, overlap=False)
-                assert streams == oracle_streams, \
-                    f"serial {(name, fus, cache_on)} diverged from the " \
-                    "pipelined oracle streams"
-                assert eng.counters["swap_overlap_bytes"] == 0
-                assert eng.counters["pipeline_bubbles"] == 0
+        for cache_on in (False, True):
+            streams, eng = _run(cfg, _agent_workload(cfg), name,
+                                prefix_cache=cache_on, overlap=False)
+            assert streams == runs[(name, cache_on)][0], \
+                f"serial {(name, cache_on)} diverged from the pipelined " \
+                "streams"
+            assert eng.counters["swap_overlap_bytes"] == 0
+            assert eng.counters["pipeline_bubbles"] == 0
     # the pipelined runs really hid swap DMA under the model window
     for key in [("swap", False), ("infercept", False)]:
-        pipe = fused[key][1]
+        pipe = runs[key][1]
         assert pipe.sched.stats.swapped_out_tokens > 0, key
         assert pipe.counters["swap_overlap_bytes"] > 0, key
         assert pipe.counters["swap_overlap_bytes"] <= \
@@ -227,8 +259,8 @@ def test_overlap_uses_double_buffered_stager(diff):
     """Pipelined swap-out really routes through the SwapStager: every
     packed page is eventually collected (no slab leaks), and staging never
     holds more than its double-buffer depth."""
-    _, _, fused, _ = diff
-    eng = fused[("swap", False)][1]
+    _, runs, _ = diff
+    eng = runs[("swap", False)][1]
     assert eng.stager.packed_pages > 0
     assert eng.stager.packed_pages == eng.stager.collected_pages
     assert eng.stager.inflight == 0
@@ -312,38 +344,41 @@ def test_swap_in_page_exhaustion_requeues_instead_of_crashing():
 # ---------------------------------------------------------------------------
 def test_fused_one_dispatch_on_concurrent_prefill_and_decode():
     """A near-simultaneous burst forces iterations that carry prefill
-    chunks AND a decode batch at once. The unfused engine pays
-    1 + len(chunks) dispatches there; the fused engine must still report
-    exactly one per non-empty iteration, with identical streams and an
-    O(B)-ids logit transfer."""
+    chunks AND a decode batch at once: each is still exactly one
+    dispatch with an O(B)-ids logit transfer, and every stream is the
+    plain reference's."""
     cfg = get_config("llama3.2-1b", tiny=True)
     reqs = make_agent_workload(
         seed=7, n_sessions=4, rate_rps=500.0, vocab=cfg.vocab_size,
         n_templates=2, system_prompt_len=50, turns=(2, 2), turn_gap_s=0.01,
         hist_per_turn=12, prefix_share=0.75, gen_tokens=(10, 3),
         final_gen=(10, 3), ret_tokens=(6, 2), max_tool_calls=2, max_ctx=240)
+    eng = Engine(cfg, POLICIES["vllm"], page_size=16, n_pages=256,
+                 max_model_len=256, seed=0)
+    mixed = []
+    real = eng._dispatch_mixed
 
-    def burst(fused):
-        eng = Engine(cfg, POLICIES["vllm"], page_size=16, n_pages=256,
-                     max_model_len=256, seed=0, paged=True, fused=fused)
-        for r in copy.deepcopy(reqs):
-            eng.add_request(r)
-        fin = eng.run()
-        assert len(fin) == len(reqs)
-        return {r.rid: eng.generated_text(r) for r in fin}, eng
+    def watched(plan):
+        out = real(plan)
+        if out is not None:
+            kinds = {is_chunk for *_, is_chunk in out[0]}
+            mixed.append(kinds == {True, False})
+        return out
 
-    sf, ef = burst(True)
-    su, eu = burst(False)
-    assert sf == su
-    # the scenario is real: some unfused iteration ran chunk(s) + decode
-    assert eu.counters["device_dispatches"] > \
-        eu.counters["mixed_iterations"], "no mixed iteration occurred"
-    assert ef.counters["device_dispatches"] == \
-        ef.counters["mixed_iterations"]
-    assert ef.counters["logit_bytes"] <= \
-        4 * Engine._bucket(len(reqs)) * ef.counters["mixed_iterations"]
-    assert eu.counters["logit_bytes"] >= \
-        eu.counters["decode_tokens"] * cfg.vocab_size * 4
+    eng._dispatch_mixed = watched
+    for r in copy.deepcopy(reqs):
+        eng.add_request(r)
+    fin = eng.run()
+    assert len(fin) == len(reqs)
+    # the scenario is real: some iteration ran chunk(s) + decode together
+    assert any(mixed), "no mixed iteration occurred"
+    assert eng.counters["device_dispatches"] == \
+        eng.counters["mixed_iterations"] == len(mixed)
+    assert eng.counters["logit_bytes"] <= \
+        4 * Engine._bucket(len(reqs)) * eng.counters["mixed_iterations"]
+    gaps = _plain_reference(cfg, eng.params, eng.max_model_len)
+    for req in fin:
+        assert max(gaps(req, eng.generated_text(req)).values()) <= REF_TOL
 
 
 # ---------------------------------------------------------------------------
@@ -394,9 +429,10 @@ def test_ensure_pages_allocates_shortfall_in_one_call(monkeypatch):
 # pad rows must never corrupt live pages
 # ---------------------------------------------------------------------------
 def test_paged_decode_pad_rows_never_touch_pages():
-    """Two padded rows deliberately alias the same block-table page: with
-    masked appends neither may write anywhere — every pool slot except the
-    two live targets keeps its sentinel."""
+    """A decode-only fused batch whose two padded rows deliberately alias
+    the same block-table page: with masked appends neither may write
+    anywhere — every pool slot except the two live targets keeps its
+    sentinel."""
     cfg = get_config("llama3.2-1b", tiny=True)
     m = LM(cfg)
     params = m.init(jax.random.PRNGKey(0), dtype=jnp.float32)
@@ -404,44 +440,24 @@ def test_paged_decode_pad_rows_never_touch_pages():
     pools = m.init_cache(n_pages, page, dtype=jnp.float32)
     pools = jax.tree.map(lambda l: jnp.full_like(l, 7.5), pools)
     bt = np.zeros((4, max_pages), np.int64)
-    bt[0, :2] = [3, 4]          # live: ctx 9 -> writes (page 4, slot 0)
+    bt[0, :2] = [3, 4]          # live: position 8 -> writes (page 4, slot 0)
     bt[1, :] = 5                # pad rows 1 and 2 alias page 5 on purpose
     bt[2, :] = 5
-    bt[3, :1] = [7]             # live: ctx 1 -> writes (page 7, slot 0)
-    cl = jnp.asarray([9, 0, 0, 1], jnp.int32)
+    bt[3, :1] = [7]             # live: position 0 -> writes (page 7, slot 0)
+    # one token per sequence: row b is sequence b at its context length
+    # minus one, -1 on the padded rows
+    tseq = jnp.arange(4, dtype=jnp.int32)
+    tpos = jnp.asarray([8, -1, -1, 0], jnp.int32)
     toks = jnp.asarray([5, 6, 7, 8], jnp.int32)
-    _, new_pools = m.decode_step_paged(params, toks, cl, pools,
-                                       jnp.asarray(bt, jnp.int32),
-                                       discard_pid=n_pages - 1)
+    _, _, new_pools = m.forward_mixed_paged(
+        params, toks, tseq, tpos, tseq, pools, jnp.asarray(bt, jnp.int32),
+        discard_pid=n_pages - 1)
     live = np.zeros((n_pages, page), bool)
     live[4, 0] = live[7, 0] = True
     for leaf in jax.tree.leaves(new_pools):
         arr = np.asarray(leaf)              # (periods, n_pages, page, ...)
         assert np.all(arr[:, ~live] == 7.5), "pad row wrote a pool page"
         assert not np.any(arr[:, live] == 7.5), "live row write missing"
-
-
-def test_gather_scatter_pad_rows_never_touch_pages():
-    """White-box regression for the gather oracle: _scatter_tokens used to
-    route padded rows into the shared scratch page — two pad rows aliasing
-    one physical page in a single unordered scatter. Padded entries must
-    now be dropped outright."""
-    cfg = get_config("llama3.2-1b", tiny=True)
-    eng = Engine(cfg, POLICIES["vllm"], page_size=8, n_pages=16,
-                 max_model_len=64, paged=False)
-    eng.pools = jax.tree.map(lambda l: jnp.full_like(l, 3.25), eng.pools)
-    bt = np.asarray([[1, 2] + [eng.scratch_page] * (eng.max_pages - 2)])
-    cache = jax.tree.map(lambda l: jnp.full_like(l, 9.0),
-                         eng._gather_cache(bt))
-    eng._scatter_tokens(cache, bt, np.zeros(1, np.int64),
-                        np.asarray([5]), pad_to=4)      # 3 pad entries
-    target = np.zeros((16, 8), bool)
-    target[1, 5] = True                                  # pos 5 -> page 1
-    for leaf in jax.tree.leaves(eng.pools):
-        arr = np.asarray(leaf)
-        assert np.all(arr[:, ~target] == 3.25), \
-            "pad scatter entry wrote a pool page (scratch included)"
-        assert np.all(arr[:, target] == 9.0)
 
 
 def test_mixed_pad_rows_never_touch_pages():
@@ -483,7 +499,7 @@ def _check_attention_grid_counts(monkeypatch, arch):
     from attn_grid import brute_force_steps
     cfg = get_config(arch, tiny=True)
     eng = Engine(cfg, POLICIES["infercept"], page_size=16, n_pages=128,
-                 max_model_len=256, seed=0, paged=True, fused=True)
+                 max_model_len=256, seed=0)
     layers = [b.attn for g in cfg.groups for b in g.period if b.attn]
     full = [a for a in layers if a.sliding_window is None]
     a = (full or layers)[0]

@@ -7,16 +7,16 @@ Three layers of pinning:
     duplicate pages within one call and invalid (dropped) rows; the
     dequantized pool recovers the fp32 rows within each dtype's
     precision envelope.
-  * model parity — teacher-forced paged decode over a quantized pool
-    tracks the fp32 pool's logits within a calibrated bound at every
-    matched position.
+  * model parity — teacher-forced decode through the fused step over a
+    quantized pool tracks the fp32 pool's logits within a calibrated bound
+    at every matched position.
   * engine bounded-divergence harness — a quantized engine finishes the
-    same workload across policies × fused × cache × overlap with greedy
+    same workload across policies × speculation × cache × overlap with greedy
     token streams agreeing with the fp32 baseline above a per-dtype
     threshold (exact equality is impossible: the requant history is
     scheduling-order-dependent), while ``kv_dtype=None`` stays
     structurally identical to the historical pools (no scale leaves,
-    same kv_token_bytes) so the existing oracle tests keep pinning
+    same kv_token_bytes) so the existing identity tests keep pinning
     bit-identity.
 
 Thresholds are calibrated empirically on the tiny random-init config —
@@ -38,6 +38,7 @@ from repro.kernels.kv_quant import (KV_QUANT_DTYPES, kv_append_quant,
                                     kv_quant_jnp_dtype, kv_quant_qmax,
                                     quantize_rows)
 from repro.models import LM
+from repro.serving.api_executor import OracleToolResultPredictor
 from repro.serving.engine import Engine
 from repro.serving.workloads import make_agent_workload
 from repro.utils.hw import TPU_V5E, dtype_bytes
@@ -182,9 +183,11 @@ def test_scale_update_is_monotone_and_requant_preserves_old_rows():
 
 @pytest.mark.parametrize("name", ALL_KV_DTYPES)
 def test_quant_paged_attention_matches_ref(name):
-    """Scale-aware paged attention: Pallas vs the dequantize-then-attend
-    reference, and both near the fp32 attention over the pre-quant pool."""
-    from repro.kernels.ops import paged_attention_op
+    """Scale-aware attention at a decode-only launch (one token per
+    sequence, at its context length minus one): Pallas vs the
+    dequantize-then-attend reference, and both near the fp32 attention
+    over the pre-quant pool."""
+    from repro.kernels.ops import ragged_paged_attention_op
     qdtype = kv_quant_jnp_dtype(name)
     B, Hkv, G, hd, page, n_pages, max_pages = 2, 2, 2, 16, 4, 16, 3
     ks = jax.random.split(KEY, 4)
@@ -197,16 +200,18 @@ def test_quant_paged_attention_matches_ref(name):
     vq = quantize_rows(vf, vscale[:, None], qdtype)
     bt = jnp.asarray([[0, 1, 2], [3, 4, 5]], jnp.int32)
     ctx = jnp.asarray([7, 12], jnp.int32)
-    pal = paged_attention_op(q, kq[None], vq[None], 0, bt, ctx,
-                             k_scale=scale[None], v_scale=vscale[None],
-                             use_pallas=True, interpret=True)
-    rf = ref.paged_attention_quant_ref(q, kq[None], vq[None], scale[None],
-                                       vscale[None], 0, bt, ctx,
-                                       softcap=None, scale=None,
-                                       window=None)
+    tok_seq, tok_pos = jnp.arange(B, dtype=jnp.int32), ctx - 1
+    pal = ragged_paged_attention_op(q, kq[None], vq[None], 0, bt, tok_seq,
+                                    tok_pos, k_scale=scale[None],
+                                    v_scale=vscale[None], use_pallas=True,
+                                    interpret=True)
+    rf = ref.ragged_paged_attention_quant_ref(
+        q, kq[None], vq[None], scale[None], vscale[None], 0, bt, tok_seq,
+        tok_pos, softcap=None, scale=None, window=None)
     np.testing.assert_allclose(np.asarray(pal), np.asarray(rf), atol=1e-5)
-    exact = ref.paged_attention_ref(q, kf[None], vf[None], 0, bt, ctx,
-                                    softcap=None, scale=None, window=None)
+    exact = ref.ragged_paged_attention_ref(q, kf[None], vf[None], 0, bt,
+                                           tok_seq, tok_pos, softcap=None,
+                                           scale=None, window=None)
     tol = {"int8": 0.05, "float8_e4m3": 0.2, "float8_e5m2": 0.5}[name]
     np.testing.assert_allclose(np.asarray(pal), np.asarray(exact),
                                atol=tol)
@@ -329,10 +334,7 @@ def test_engine_kv_dtype_validation():
     cfg = get_config("llama3.2-1b", tiny=True)
     with pytest.raises(ValueError, match="unsupported kv_dtype"):
         Engine(cfg, POLICIES["infercept"], page_size=16, n_pages=32,
-               max_model_len=64, paged=True, kv_dtype="int4")
-    with pytest.raises(ValueError, match="requires the paged engine"):
-        Engine(cfg, POLICIES["infercept"], page_size=16, n_pages=32,
-               max_model_len=64, paged=False, kv_dtype="int8")
+               max_model_len=64, kv_dtype="int4")
 
 
 def test_costmodel_kv_dtype_shifts_m_bytes():
@@ -361,8 +363,6 @@ def _workload(cfg):
 
 
 def _run(cfg, reqs, policy, **kw):
-    kw.setdefault("paged", True)
-    kw.setdefault("fused", True)
     eng = Engine(cfg, POLICIES[policy], page_size=16, n_pages=128,
                  max_model_len=256, seed=0, **kw)
     for r in copy.deepcopy(reqs):
@@ -419,22 +419,28 @@ def test_fp8_streams_bounded_divergence(quant_diff, name):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("fused,cache,overlap", [
-    (False, True, True), (True, False, True), (True, True, False),
-    (False, False, False),
+@pytest.mark.parametrize("spec,cache,overlap", [
+    (True, True, True), (False, False, True), (False, True, False),
+    (True, False, False),
 ])
-def test_quantized_toggle_corners(quant_diff, fused, cache, overlap):
-    """The unfused, cache-off, and serial corners hold the same floor —
-    quantization composes with every execution toggle."""
+def test_quantized_toggle_corners(quant_diff, spec, cache, overlap):
+    """The speculative, cache-off, and serial corners hold the same floor
+    — quantization composes with every execution toggle, speculative
+    forks' COW-copied scales included."""
     cfg, reqs, baseline = quant_diff
-    streams, eng = _run(cfg, reqs, "infercept", fused=fused,
-                        prefix_cache=cache, overlap=overlap,
-                        kv_dtype="int8", sanitize=True)
+    kw = dict(speculate=True,
+              predictor=OracleToolResultPredictor(cfg.vocab_size)) \
+        if spec else {}
+    streams, eng = _run(cfg, reqs, "infercept", prefix_cache=cache,
+                        overlap=overlap, kv_dtype="int8", sanitize=True,
+                        **kw)
     eng.sanitizer.audit("final")
     assert eng.sanitizer.findings == []
+    if spec:
+        assert eng.counters["spec_accepted"] > 0
     rate = _agreement(streams, baseline)
     assert rate >= STREAM_AGREEMENT_FLOOR["int8"], \
-        (fused, cache, overlap, rate)
+        (spec, cache, overlap, rate)
 
 
 def test_quantized_engine_halves_kv_bytes(quant_diff):
@@ -443,9 +449,9 @@ def test_quantized_engine_halves_kv_bytes(quant_diff):
     shrink by the same factor (swap_bytes follows kv_token_bytes)."""
     cfg, reqs, _ = quant_diff
     base = Engine(cfg, POLICIES["infercept"], page_size=16, n_pages=32,
-                  max_model_len=256, paged=True)
+                  max_model_len=256)
     q8 = Engine(cfg, POLICIES["infercept"], page_size=16, n_pages=32,
-                max_model_len=256, paged=True, kv_dtype="int8")
+                max_model_len=256, kv_dtype="int8")
     assert 2 * q8.kv_token_bytes <= base.kv_token_bytes
     # per-page slab bytes as the SwapStager stages them
     slab = lambda eng: sum(  # noqa: E731
@@ -468,9 +474,10 @@ def test_quant_counters_fire(quant_diff):
 # ---------------------------------------------------------------------------
 
 def test_teacher_forced_paged_decode_logit_error_bounded():
-    """Same token fed at every step (no sampling feedback): the quantized
-    pool's logits stay within a calibrated bound of the fp32 pool's at
-    every matched position."""
+    """Same token fed at every step (no sampling feedback), one token per
+    sequence through the fused step: the quantized pool's logits stay
+    within a calibrated bound of the fp32 pool's at every matched
+    position."""
     cfg = get_config("llama3.2-1b", tiny=True)
     lm = LM(cfg)
     params = lm.init(jax.random.PRNGKey(0))
@@ -481,13 +488,14 @@ def test_teacher_forced_paged_decode_logit_error_bounded():
     rng = np.random.default_rng(0)
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, T)), jnp.int32)
     worst = 0.0
+    seq = jnp.arange(B, dtype=jnp.int32)
+    # page 0 is in no block table: the write-discard page
+    step = jax.jit(lambda tok, pos, pools: lm.forward_mixed_paged(
+        params, tok, seq, pos, seq, pools, bt, discard_pid=0)[1:])
     for t in range(T):
-        ctx = jnp.full((B,), t + 1, jnp.int32)
-        # page 0 is in no block table: the write-discard page
-        lf, pools_f = lm.decode_step_paged(params, toks[:, t], ctx,
-                                           pools_f, bt, discard_pid=0)
-        lq, pools_q = lm.decode_step_paged(params, toks[:, t], ctx,
-                                           pools_q, bt, discard_pid=0)
+        pos = jnp.full((B,), t, jnp.int32)
+        lf, pools_f = step(toks[:, t], pos, pools_f)
+        lq, pools_q = step(toks[:, t], pos, pools_q)
         err = float(jnp.max(jnp.abs(lf - lq)))
         spread = float(jnp.max(lf) - jnp.min(lf))
         worst = max(worst, err / max(spread, 1e-6))

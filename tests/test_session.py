@@ -6,13 +6,12 @@ The headline pins:
   * scripted-over-session equivalence — replaying the scripted Table-1
     workloads through InferCeptClient/ScriptedClient produces token
     streams bit-identical to the legacy closed-loop Engine.run(), across
-    all four scheduling policies × fused on/off;
+    all four scheduling policies;
   * caller-driven resume — an out-of-band resume with caller-chosen
     returned tokens lands verbatim in the context and generation
     continues;
   * sampling determinism — temperature/top-k streams under a fixed
-    per-request seed are identical across policies and across the
-    fused / unfused / gather execution paths (noise is keyed by
+    per-request seed are identical across policies (noise is keyed by
     (seed, position) only), and SamplingParams(temperature=0) equals the
     legacy argmax streams.
 """
@@ -65,8 +64,8 @@ def sess_diff():
     cfg = get_config("llama3.2-1b", tiny=True)
     reqs = _mixed_workload(cfg)
     # legacy closed loop: one run suffices as the oracle — cross-policy
-    # and fused/unfused identity of the legacy engine is already pinned
-    # by tests/test_engine.py and tests/test_paged_engine.py
+    # identity of the legacy engine is already pinned by
+    # tests/test_engine.py and tests/test_paged_engine.py
     eng = _engine(cfg, "vllm")
     for r in copy.deepcopy(reqs):
         eng.add_request(r)
@@ -76,11 +75,9 @@ def sess_diff():
 
     session, engines = {}, {}
     for name in ALL_POLICIES:
-        for fused in (True, False):
-            e = _engine(cfg, name, fused=fused)
-            session[(name, fused)] = ScriptedClient(e).replay(
-                copy.deepcopy(reqs))
-            engines[(name, fused)] = e
+        e = _engine(cfg, name)
+        session[name] = ScriptedClient(e).replay(copy.deepcopy(reqs))
+        engines[name] = e
     return cfg, reqs, oracle, session, engines
 
 
@@ -88,7 +85,7 @@ def sess_diff():
 def test_scripted_sessions_match_legacy_streams(sess_diff):
     """The §11 equivalence pin: the scripted workloads replayed through
     the session API emit the legacy closed-loop engine's exact token
-    streams — every policy, fused and unfused."""
+    streams — every policy."""
     _, _, oracle, session, _ = sess_diff
     for key, streams in session.items():
         assert streams == oracle, \
@@ -97,21 +94,20 @@ def test_scripted_sessions_match_legacy_streams(sess_diff):
 
 def test_session_interceptions_really_happened(sess_diff):
     """The equivalence must not be vacuous: the replay exercised real
-    interceptions, and the fused runs kept the 1-dispatch/O(B)-ids
-    properties with the session lifecycle in the loop."""
+    interceptions, and the runs kept the one-dispatch-per-iteration
+    property with the session lifecycle in the loop."""
     _, reqs, _, _, engines = sess_diff
     n_int = sum(1 for r in reqs for s in r.segments if s.interception)
     assert n_int > 0
-    for (name, fused), eng in engines.items():
+    for name, eng in engines.items():
         assert eng.sched.stats.decode_tokens > 0
         done = {r.rid: r for r in eng.finished}
         assert sum(sum(1 for s in done[r.rid].segments if s.interception)
-                   for r in reqs) == n_int, (name, fused)
-        if fused:
-            assert eng.counters["device_dispatches"] == \
-                eng.counters["mixed_iterations"], (name, fused)
+                   for r in reqs) == n_int, name
+        assert eng.counters["device_dispatches"] == \
+            eng.counters["mixed_iterations"], name
     # per-request latency metrics flow through the session path
-    for r in engines[("infercept", True)].finished:
+    for r in engines["infercept"].finished:
         m = r.latency_metrics()
         assert m["output_tokens"] > 0 and m["ttft"] is not None
 
@@ -328,9 +324,8 @@ def test_resume_and_rid_guardrails():
     assert h.finished
 
 
-def _sampled_run(cfg, policy, *, fused=True, paged=True, seed=11,
-                 temp=0.8, top_k=6, top_p=1.0):
-    eng = _engine(cfg, policy, n_pages=96, fused=fused, paged=paged)
+def _sampled_run(cfg, policy, *, seed=11, temp=0.8, top_k=6, top_p=1.0):
+    eng = _engine(cfg, policy, n_pages=96)
     cl = InferCeptClient(eng)
     tool = VirtualTimeToolExecutor(cfg.vocab_size, n_tokens=5, duration=0.3)
 
@@ -353,9 +348,8 @@ def _sampled_run(cfg, policy, *, fused=True, paged=True, seed=11,
 def test_sampling_deterministic_across_policies_and_paths():
     """Temperature/top-k sampling under a fixed per-request seed: noise is
     keyed by (seed, position) only, so streams are bit-identical across
-    every scheduling policy AND across the fused / unfused / gather
-    execution paths — the §6 equivalence property survives stochastic
-    sampling. A different seed moves the stream."""
+    every scheduling policy — the §6 equivalence property survives
+    stochastic sampling. A different seed moves the stream."""
     cfg = get_config("llama3.2-1b", tiny=True)
     base, eng = _sampled_run(cfg, "vllm")
     # sampling stayed on device on the fused path: one dispatch per
@@ -367,10 +361,6 @@ def test_sampling_deterministic_across_policies_and_paths():
     for policy in ["infercept", "swap", "preserve"]:
         streams, _ = _sampled_run(cfg, policy)
         assert streams == base, f"sampled stream diverged under {policy}"
-    unfused, _ = _sampled_run(cfg, "vllm", fused=False)
-    assert unfused == base, "unfused sampled stream diverged"
-    gather, _ = _sampled_run(cfg, "vllm", fused=False, paged=False)
-    assert gather == base, "gather-oracle sampled stream diverged"
     other, _ = _sampled_run(cfg, "vllm", seed=999)
     assert other != base, "per-request seed had no effect"
 
@@ -378,20 +368,14 @@ def test_sampling_deterministic_across_policies_and_paths():
 @pytest.mark.slow
 def test_top_p_deterministic_across_policies_and_paths():
     """Nucleus sampling rides the same (seed, position)-keyed seam: top-p
-    streams are bit-identical across scheduling policies and across the
-    fused / unfused / gather execution paths, and a binding threshold
-    really changes the stream (vs top-k-only sampling with the same
-    seed)."""
+    streams are bit-identical across scheduling policies, and a binding
+    threshold really changes the stream (vs top-k-only sampling with the
+    same seed)."""
     cfg = get_config("llama3.2-1b", tiny=True)
     base, _ = _sampled_run(cfg, "vllm", top_k=0, top_p=0.3)
     for policy in ["infercept", "swap", "preserve"]:
         streams, _ = _sampled_run(cfg, policy, top_k=0, top_p=0.3)
         assert streams == base, f"top-p stream diverged under {policy}"
-    unfused, _ = _sampled_run(cfg, "vllm", fused=False, top_k=0, top_p=0.3)
-    assert unfused == base, "unfused top-p stream diverged"
-    gather, _ = _sampled_run(cfg, "vllm", fused=False, paged=False,
-                             top_k=0, top_p=0.3)
-    assert gather == base, "gather-oracle top-p stream diverged"
     full, _ = _sampled_run(cfg, "vllm", top_k=0, top_p=1.0)
     assert full != base, "top_p=0.3 did not bind (same stream as full)"
 

@@ -12,12 +12,17 @@ The differential pins:
   * speculation ON with a wrong predictor rejects every fork: the
     baseline resume path runs bit-identically and the fork's pinned
     bytes land in the ledger's ``speculation_wasted`` cause;
+  * a fork runs through the fused dispatch as a one-entry batch: it
+    writes only its own pages and leaves the served step's counters
+    alone;
   * the session API surfaces per-intercept outcomes
     (``SessionHandle.speculation``), and the analytic simulator mirrors
     the same accept/reject accounting.
 """
 import copy
 
+import jax
+import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -42,7 +47,7 @@ def _workload(cfg):
 
 def _run(cfg, reqs, policy, **kw):
     eng = Engine(cfg, POLICIES[policy], page_size=16, n_pages=128,
-                 max_model_len=256, seed=0, paged=True, fused=True, **kw)
+                 max_model_len=256, seed=0, **kw)
     for r in copy.deepcopy(reqs):
         eng.add_request(r)
     fin = eng.run()
@@ -72,13 +77,9 @@ def test_speculation_disabled_without_predictor():
     eng = Engine(cfg, POLICIES["infercept"], page_size=16, n_pages=64,
                  max_model_len=256)
     assert eng.speculate is False
-    # opting in without a predictor (or without paging) stays off
+    # opting in without a predictor stays off
     eng = Engine(cfg, POLICIES["infercept"], page_size=16, n_pages=64,
                  max_model_len=256, speculate=True)
-    assert eng.speculate is False
-    eng = Engine(cfg, POLICIES["infercept"], page_size=16, n_pages=64,
-                 max_model_len=256, paged=False, speculate=True,
-                 predictor=OracleToolResultPredictor(cfg.vocab_size))
     assert eng.speculate is False
 
 
@@ -126,6 +127,64 @@ def test_accepted_forks_skip_reprefill(spec_diff):
         assert ea.sched.stats.recompute_tokens <= \
             eb.sched.stats.recompute_tokens, name
         assert ea.ledger.causes["speculation_wasted"] == 0.0, name
+
+
+# the served step's counters, which a fork's dispatch must leave alone
+STEP_COUNTERS = ("device_dispatches", "mixed_iterations", "step_tokens",
+                 "pad_tokens", "pad_rows", "prefill_tokens", "decode_tokens",
+                 "prefill_bytes", "decode_bytes", "logit_bytes",
+                 "attn_grid_steps", "attn_live_steps")
+
+
+def test_fork_dispatch_writes_own_pages_and_no_step_counters():
+    """A fork step is a one-entry fused dispatch: its prefill and its
+    decodes add only to spec_prefill_tokens / spec_decode_tokens, and the
+    only pool pages it changes are pages the fork holds alone."""
+    cfg = get_config("llama3.2-1b", tiny=True)
+    reqs = _workload(cfg)
+    eng = Engine(cfg, POLICIES["preserve"], page_size=16, n_pages=128,
+                 max_model_len=256, seed=0, speculate=True, spec_tokens=4,
+                 predictor=OracleToolResultPredictor(cfg.vocab_size))
+    for r in copy.deepcopy(reqs):
+        eng.add_request(r)
+    seen = {"prefill": 0, "decode": 0}
+    real = eng._spec_advance
+
+    def checked(fork):
+        before = {k: eng.counters[k] for k in STEP_COUNTERS}
+        spec0 = (eng.counters["spec_prefill_tokens"],
+                 eng.counters["spec_decode_tokens"])
+        pools0 = [np.asarray(x).copy() for x in jax.tree.leaves(eng.pools)]
+        emitted, n_tok = fork.emitted, len(fork.st.tokens)
+        n = len(fork.predicted) if emitted == 0 else 1
+        ok = real(fork)
+        assert {k: eng.counters[k] for k in STEP_COUNTERS} == before
+        if not ok:
+            return ok
+        assert fork.emitted == emitted + 1
+        assert len(fork.st.tokens) == n_tok + 1
+        kind = "prefill" if emitted == 0 else "decode"
+        assert (eng.counters["spec_prefill_tokens"] - spec0[0],
+                eng.counters["spec_decode_tokens"] - spec0[1]) == \
+            ((n, 0) if kind == "prefill" else (0, 1))
+        seen[kind] += 1
+        others = {e[1] for rid, st in eng.kv.items() for e in st.pages
+                  if e is not None and e[0] == "dev"}
+        own = {pid for _, pid in fork.st.pages} - others
+        changed = set()
+        for old, new in zip(pools0, jax.tree.leaves(eng.pools)):
+            diff = np.asarray(new) != old      # (layers, n_pages, ...)
+            changed |= set(np.nonzero(diff.reshape(
+                diff.shape[0], diff.shape[1], -1).any(axis=(0, 2)))[0])
+        assert changed and changed <= own, (changed, own)
+        return ok
+
+    eng._spec_advance = checked
+    fin = eng.run()
+    assert len(fin) == len(reqs)
+    assert seen["prefill"] > 0 and seen["decode"] > 0, seen
+    assert eng.counters["device_dispatches"] == \
+        eng.counters["mixed_iterations"]
 
 
 def test_rejected_forks_charge_speculation_waste(spec_diff):

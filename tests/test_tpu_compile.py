@@ -26,7 +26,6 @@ from repro.configs.base import simple_dense
 from repro.kernels import ops
 from repro.kernels.kv_append import kv_append
 from repro.kernels.kv_quant import page_requant
-from repro.kernels.paged_attention import paged_attention
 from repro.kernels.ragged_paged_attention import ragged_paged_attention
 from repro.models import LM, attention
 
@@ -132,13 +131,16 @@ def test_ragged_paged_attention_compiles(one_chip, widths, pool_dtype):
 
 
 def test_paged_attention_compiles(one_chip):
+    """The ragged kernel at a decode-only launch: one token per sequence,
+    N = B rows."""
     s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
     pool = s((LAYERS, N_PAGES, PAGE, HKV, HD), jnp.bfloat16)
+    rows = s((N_SEQ,), jnp.int32)
     hlo = _compile(
-        lambda q, k, v, lyr, bt, cl: paged_attention(q, k, v, lyr, bt, cl,
-                                                     interpret=False),
+        lambda q, k, v, lyr, bt, ts, tp: ragged_paged_attention(
+            q, k, v, lyr, bt, ts, tp, interpret=False),
         s((N_SEQ, HKV, G, HD), jnp.bfloat16), pool, pool, s((), jnp.int32),
-        s((N_SEQ, MAX_PAGES), jnp.int32), s((N_SEQ,), jnp.int32))
+        s((N_SEQ, MAX_PAGES), jnp.int32), rows, rows)
     _assert_kernel(hlo, "ragged_paged_attention")
 
 
